@@ -26,6 +26,12 @@
 #                  counts + wall time, dispatches-per-epoch) and the
 #                  8-device multichip dry-run compile.
 #   make bench   — the full benchmark set (one JSON line per metric).
+#   make chip-smoke — chip_smoke.py: one process drives trainer.SGD and
+#                  ServingEngine at the NMT flagship's full width (plus
+#                  ResNet-50, the AOT cache, the Pallas flash kernels,
+#                  data parallelism when several chips are visible) on the
+#                  TPU; fails at once where jax sees no TPU.  No CPU_ENV:
+#                  it never sets a platform itself.
 #   make tier1-check / tier1-update — diff (or re-snapshot) the tier-1
 #                  failing-test SET against tests/tier1_failures_baseline.txt
 #                  (scripts/tier1_failset.py), so CI catches a newly broken
@@ -79,7 +85,7 @@
 PY ?= python
 CPU_ENV = XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu
 
-.PHONY: test verify bench test-all lint tier1-check tier1-update chaos serve-bench scenarios trace-demo
+.PHONY: test verify bench chip-smoke test-all lint tier1-check tier1-update chaos serve-bench scenarios trace-demo
 
 lint:
 	$(CPU_ENV) $(PY) -m paddle_tpu lint --extra bench.py
@@ -178,3 +184,6 @@ verify: test-all
 
 bench:
 	$(PY) bench.py
+
+chip-smoke:
+	$(PY) chip_smoke.py

@@ -15,7 +15,7 @@ through a small interpreter on the captured batch:
 * the first eqn whose output is non-finite is named, with layer
   provenance from the named-scope stack (the T100 note plane's
   vocabulary) and source provenance from ``eqn.source_info``;
-* call-like eqns (pjit / custom-vjp), ``scan`` (stepped iteration by
+* call-like eqns (jit / custom-vjp), ``scan`` (stepped iteration by
   iteration) and ``cond`` (the taken branch) are descended into, so the
   record points at a primitive, not at "the scan";
 * every input of the offending eqn gets max-abs / non-finite-count
@@ -147,7 +147,7 @@ def _eval_jaxpr(jaxpr, consts, args, path: str) -> List[Any]:
     """Evaluate ``jaxpr`` eqn by eqn; raises :class:`_Found` at the first
     eqn whose output holds a NaN/inf, after localizing INTO call-like /
     scan / cond eqns so the record names a primitive, not a region."""
-    from jax.core import Literal
+    from jax.extend.core import Literal
 
     env: Dict[Any, Any] = {}
 

@@ -8,7 +8,7 @@ aggressive low-precision work (ROADMAP item 2: quantized collectives,
 bf16 master-weight training, int8 weight-only serving; EQuARX,
 arXiv:2506.17615) cheap — a bad precision config is a lint finding, not a
 burned convergence run.  Like ``trace_lint`` it sees the whole compiled
-step as one static dataflow graph, recursing scan/cond/pjit sub-jaxprs.
+step as one static dataflow graph, recursing scan/cond/jit sub-jaxprs.
 
 Rules (``N###``):
 
@@ -84,11 +84,10 @@ ACCUM_EXTENT_THRESHOLD = 32
 
 # call-like primitives we inline (operand substitution keeps constants
 # and guard facts flowing through — jnp.where wraps its fill literal in a
-# pjit, and the -1e9-under-f16 check (N404) must see through it)
+# nested jit, and the -1e9-under-f16 check (N404) must see through it)
 _INLINE_PRIMS = frozenset({
-    "pjit", "closed_call", "core_call", "remat", "checkpoint",
+    "jit", "closed_call", "call", "remat2",
     "custom_jvp_call", "custom_vjp_call", "custom_jvp_call_jaxpr",
-    "custom_vjp_call_jaxpr", "custom_vjp_call_jaxpr_p",
 })
 # ops a guard/constant fact flows through unchanged
 _TRANSPARENT = frozenset({
@@ -180,7 +179,7 @@ def _scalar_const(v) -> Optional[float]:
 
 def _sub_jaxprs(params: Dict[str, Any]):
     """Every ClosedJaxpr reachable from an eqn's params."""
-    from jax.core import Jaxpr
+    from jax.extend.core import Jaxpr
 
     def walk(v):
         if hasattr(v, "jaxpr") or isinstance(v, Jaxpr):
@@ -223,7 +222,7 @@ class _Walker:
         return [self._read(env, v) for v in jaxpr.outvars]
 
     def _read(self, env, var) -> _Val:
-        from jax.core import Literal
+        from jax.extend.core import Literal
 
         if isinstance(var, Literal):
             return _Val("const", _aval_dtype(var), const=_scalar_const(var.val))
@@ -293,7 +292,7 @@ class _Walker:
     def _const_out(self, prim, eqn, invals, outvar) -> Optional[float]:
         """Propagate known scalar constants through shape-transparent ops
         and converts — the -1e9 literal must still be known when the
-        convert to f16 happens inside the inlined `_where` pjit."""
+        convert to f16 happens inside the inlined `_where` jit."""
         if prim in _TRANSPARENT and invals and invals[0].const is not None:
             return invals[0].const
         if prim == "neg" and invals and invals[0].const is not None:
@@ -361,7 +360,7 @@ def _eqn_site(eqn) -> Tuple[Optional[str], Optional[int]]:
     try:
         from jax._src import source_info_util as siu
 
-        frame = siu.user_frame(eqn.source_info)
+        frame = siu.user_frame(eqn.source_info.traceback)
         if frame is None:
             return None, None
         return frame.file_name, int(frame.start_line)

@@ -87,7 +87,7 @@ def _walk_jaxprs(jaxpr) -> Iterable[Tuple[Any, List]]:
 
 
 def _iter_jaxpr_params(v):
-    from jax.core import Jaxpr
+    from jax.extend.core import Jaxpr
 
     if hasattr(v, "jaxpr") or isinstance(v, Jaxpr):
         yield v
@@ -215,7 +215,7 @@ def donation_audit(
     buffer that will be double-buffered.
 
     ``fn`` may be a jitted function — its own ``donate_argnums`` are read
-    back out of the traced pjit equation, so the audit checks what jit
+    back out of the traced jit equation, so the audit checks what jit
     will actually honor; for a plain function pass ``donate_argnums``
     explicitly (the jit spelling the builder intends)."""
     closed = trace_step(fn, *example_args)
@@ -232,11 +232,11 @@ def donation_audit(
     eqns = jaxpr.eqns
     if (
         len(eqns) == 1
-        and eqns[0].primitive.name == "pjit"
+        and eqns[0].primitive.name == "jit"
         and "donated_invars" in eqns[0].params
         and list(eqns[0].invars) == list(jaxpr.invars)
     ):
-        # a jitted fn traces to one pjit eqn; its donated_invars are the
+        # a jitted fn traces to one jit eqn; its donated_invars are the
         # flags jit will compile with — the ground truth
         donated = list(eqns[0].params["donated_invars"])
     if donated is None:

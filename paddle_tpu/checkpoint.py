@@ -112,7 +112,10 @@ def _unflatten_into(template, arrays: Dict[str, np.ndarray]):
             raise ValueError(
                 f"leaf {key}: checkpoint shape {arr.shape} != template {want}"
             )
-        new_leaves.append(arr.astype(np.asarray(leaf).dtype))
+        # the template gives structure, shape and dtype only: its buffers
+        # may have been donated to a train step, so never read their values
+        dtype = leaf.dtype if hasattr(leaf, "dtype") else np.asarray(leaf).dtype
+        new_leaves.append(arr.astype(dtype))
     return jax.tree_util.tree_unflatten(treedef, new_leaves)
 
 

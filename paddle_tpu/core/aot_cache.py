@@ -26,11 +26,12 @@ Safety model — a wrong executable must be impossible to load:
 * **Integrity**: the pickled executable blob carries a CRC32 and its byte
   length in the header; truncation or corruption is a **corrupt** entry —
   counted, warned once, retraced, overwritten.  Loads never raise.
-* **Version shim**: jax builds without ``serialize_executable`` (or
-  backends whose executables refuse to serialize) degrade to today's
-  retrace path — ``available()`` is False, every ``get_or_compile`` is a
-  plain ``lower().compile()`` and nothing touches disk (the
-  ``parallel/mesh.py`` shard_map-shim pattern).
+* **Devices**: the header records the ids of the devices the program was
+  compiled for, in assignment order, and the load hands exactly those to
+  ``deserialize_and_load(execution_devices=...)`` — without them jax
+  spreads the loaded program over every local device, so a one-device
+  executable fails on a host with several.  An id this process cannot
+  see is a **stale** entry.
 
 Counters ride the StatSet plane (``aot_cache/{hit,miss,stale,corrupt}``) so
 the per-pass stats table says whether a boot was warm.
@@ -52,7 +53,6 @@ _log = logging.getLogger("paddle_tpu.aot_cache")
 
 __all__ = [
     "AOTCache",
-    "serialization_available",
     "optimizer_fingerprint",
     "topology_fingerprint",
     "mesh_fingerprint",
@@ -60,18 +60,6 @@ __all__ = [
 
 _MAGIC = b"PTAOT1\n"
 _SUFFIX = ".aotx"
-
-
-def serialization_available() -> bool:
-    """True when this jax build can serialize compiled executables (the
-    version-compat shim: older/newer jax without the module simply keeps
-    the retrace path — behavior degrades, never breaks)."""
-    try:
-        from jax.experimental import serialize_executable as se
-
-        return hasattr(se, "serialize") and hasattr(se, "deserialize_and_load")
-    except Exception:  # pragma: no cover - import-time variance across jax
-        return False
 
 
 def topology_fingerprint(network) -> str:
@@ -261,11 +249,26 @@ class AOTCache:
                 "retracing", path, diff,
             )
             return None
+        import jax
+
+        by_id = {d.id: d for d in jax.devices()}
+        ids = header.get("device_ids")
+        if not ids or any(i not in by_id for i in ids):
+            self._stats.incr("aot_cache/stale")
+            self._warn_once(
+                "stale",
+                "aot cache entry %s was compiled for device ids %s, which "
+                "this process does not have; retracing", path, ids,
+            )
+            return None
         try:
             from jax.experimental import serialize_executable as se
 
             payload, in_tree, out_tree = pickle.loads(blob)  # wire: allow[A206] local CRC32-verified AOT cache blob under the operator's cache_dir, never network input; serialized XLA executables are not expressible in the restricted wire codec
-            exe = se.deserialize_and_load(payload, in_tree, out_tree)
+            exe = se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in ids],
+            )
         except Exception as e:
             self._stats.incr("aot_cache/corrupt")
             self._warn_once(
@@ -280,21 +283,16 @@ class AOTCache:
 
     def store(self, identity: Dict[str, Any], compiled,
               meta: Optional[Dict] = None) -> bool:
-        """Serialize one compiled executable; False (warn once) when this
-        jax/backend cannot serialize it — the retrace path stays correct."""
-        if not serialization_available():
-            self._warn_once(
-                "unsupported",
-                "this jax build has no executable serialization; aot cache "
-                "%s stays empty (warm boots will retrace)", self.dir,
-            )
-            return False
+        """Serialize one compiled executable; False (warn once, counted
+        as ``aot_cache/unsupported``) when the backend refuses to serialize
+        it — the retrace path stays correct."""
         try:
             from jax.experimental import serialize_executable as se
 
             payload, in_tree, out_tree = se.serialize(compiled)
             blob = pickle.dumps((payload, in_tree, out_tree))
         except Exception as e:
+            self._stats.incr("aot_cache/unsupported")
             self._warn_once(
                 "unsupported",
                 "executable refused to serialize (%s); aot cache entry "
@@ -305,6 +303,9 @@ class AOTCache:
             "key": self.full_key(identity, meta),
             "created": time.time(),
             "blob_bytes": len(blob),
+            "device_ids": [
+                d.id for d in compiled.runtime_executable().local_devices()
+            ],
         }
         try:
             _write_entry(self.entry_path(identity), header, blob)
@@ -418,5 +419,5 @@ class AOTCache:
             "miss": self._stats.count("aot_cache/miss"),
             "stale": self._stats.count("aot_cache/stale"),
             "corrupt": self._stats.count("aot_cache/corrupt"),
-            "serialization": serialization_available(),
+            "unsupported": self._stats.count("aot_cache/unsupported"),
         }

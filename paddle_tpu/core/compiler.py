@@ -673,19 +673,7 @@ class CompiledNetwork:
                     "topology — most shape/arity mistakes are caught "
                     "before tracing",
                 ).format()
-                if hasattr(e, "add_note"):  # py3.11+
-                    e.add_note(note)
-                else:
-                    # py3.10: emulate PEP 678 — populate __notes__ for
-                    # introspection AND splice into args for display
-                    try:
-                        notes = list(getattr(e, "__notes__", ()) or ())
-                        notes.append(note)
-                        e.__notes__ = notes
-                    except (AttributeError, TypeError):  # pragma: no cover
-                        pass
-                    if e.args and isinstance(e.args[0], str):
-                        e.args = (f"{e.args[0]}\n{note}",) + e.args[1:]
+                e.add_note(note)
                 raise
             if mixed and not impl.full_precision:
                 # Enforce the compute dtype at every layer boundary —

@@ -20,7 +20,6 @@ import dataclasses
 import gzip
 import os
 import struct
-import subprocess
 import threading
 from typing import Iterator, List, Optional, Sequence, Tuple
 
@@ -197,12 +196,10 @@ def _parse_sample(buf: bytes) -> DataSample:
 # pure-Python decoder below.
 # ---------------------------------------------------------------------------
 
-_PKG_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_NATIVE_SRC = os.path.join(_PKG_ROOT, "native", "protodata.cc")
-_NATIVE_SO = os.path.join(_PKG_ROOT, "native", "build", "libpaddle_tpu_protodata.so")
 _native_lib = None
 _native_tried = False
 from paddle_tpu.analysis.lock_sanitizer import make_lock
+from paddle_tpu.io._native import build_native
 
 _native_lock = make_lock("io.protodata._native_lock")
 
@@ -213,28 +210,12 @@ def _load_native():
         if _native_tried:
             return _native_lib
         _native_tried = True
+        # one-time lazy native build: the lock exists to serialize exactly this
+        so = build_native("protodata.cc", "paddle_tpu_protodata")
+        if so is None:
+            return None
         try:
-            have_so = os.path.exists(_NATIVE_SO)
-            have_src = os.path.exists(_NATIVE_SRC)
-            stale = (
-                have_so and have_src
-                and os.path.getmtime(_NATIVE_SO) < os.path.getmtime(_NATIVE_SRC)
-            )
-            if (not have_so or stale) and have_src:
-                os.makedirs(os.path.dirname(_NATIVE_SO), exist_ok=True)
-                # build to a per-pid temp and rename: concurrent processes
-                # (pytest workers, multi-process launch) must never CDLL a
-                # half-written .so
-                tmp = f"{_NATIVE_SO}.{os.getpid()}.tmp"
-                subprocess.run(  # lock: allow[C304] one-time lazy native build; the lock exists to serialize exactly this compile
-                    ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                     _NATIVE_SRC, "-o", tmp],
-                    check=True, capture_output=True,
-                )
-                os.replace(tmp, _NATIVE_SO)
-            elif not have_so:
-                return None
-            lib = ctypes.CDLL(_NATIVE_SO)
+            lib = ctypes.CDLL(so)
             lib.pdx_scan.restype = ctypes.c_int
             lib.pdx_scan.argtypes = [
                 ctypes.c_char_p,

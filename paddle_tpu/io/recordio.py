@@ -19,7 +19,6 @@ import ctypes
 import dataclasses
 import os
 import struct
-import subprocess
 import threading
 import time
 import queue as _queue
@@ -28,16 +27,10 @@ from typing import Iterable, List, Optional, Sequence
 
 _MAGIC = 0x7061646C
 
-_PKG_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-# native source ships inside the package (paddle_tpu/native/) so installed
-# wheels can build it too
-_SRC = os.path.join(_PKG_ROOT, "native", "recordio.cc")
-_BUILD_DIR = os.path.join(_PKG_ROOT, "native", "build")
-_SO = os.path.join(_BUILD_DIR, "libpaddle_tpu_io.so")
-
 _lib = None
 _lib_tried = False
 from paddle_tpu.analysis.lock_sanitizer import make_lock
+from paddle_tpu.io._native import build_native
 from paddle_tpu.utils.queues import bounded_put as _bounded_put
 
 _lib_lock = make_lock("io.recordio._lib_lock")
@@ -49,28 +42,13 @@ def _load_native():
         if _lib_tried:
             return _lib
         _lib_tried = True
+        # one-time lazy native build: the lock exists to serialize exactly this
+        so = build_native("recordio.cc", "paddle_tpu_io", ["-pthread"])
+        if so is None:
+            return None
         try:
-            have_so = os.path.exists(_SO)
-            have_src = os.path.exists(_SRC)
-            stale = (
-                have_so and have_src
-                and os.path.getmtime(_SO) < os.path.getmtime(_SRC)
-            )
-            if (not have_so or stale) and have_src:
-                os.makedirs(_BUILD_DIR, exist_ok=True)
-                # per-pid temp + rename: concurrent processes must never
-                # CDLL a half-written .so
-                tmp = f"{_SO}.{os.getpid()}.tmp"
-                subprocess.run(  # lock: allow[C304] one-time lazy native build; the lock exists to serialize exactly this compile
-                    ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                     "-pthread", _SRC, "-o", tmp],
-                    check=True, capture_output=True,
-                )
-                os.replace(tmp, _SO)
-            elif not have_so:
-                return None  # neither a prebuilt .so nor source to build
-            lib = ctypes.CDLL(_SO)
-        except (OSError, subprocess.CalledProcessError, FileNotFoundError):
+            lib = ctypes.CDLL(so)
+        except OSError:
             return None
         lib.rio_writer_create.restype = ctypes.c_void_p
         lib.rio_writer_create.argtypes = [ctypes.c_char_p, ctypes.c_uint32, ctypes.c_uint32]

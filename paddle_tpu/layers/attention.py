@@ -156,25 +156,38 @@ def mha_apply(conf, params, inputs, ctx):
                 causal=causal,
             ).reshape(b, tq, d)
 
-    if out is None and tq == tk:
+    if out is None:
         # Fused flash-attention Pallas kernel (ops/pallas_attention.py):
         # streams k/v blocks through VMEM with an online softmax — no
-        # [T, T] score matrix in HBM.  TPU backend only; dense fallback
-        # keeps CPU tests and odd shapes exact.
+        # [T, T] score matrix in HBM.  TPU backend only.  Asked for and not
+        # usable, the layer computes dense and SAYS so at trace time: a
+        # silent dense path would be timed and costed as the kernel.
         from paddle_tpu.ops import pallas_attention as fa
         from paddle_tpu.utils.flags import get_flag
 
-        if (
-            get_flag("use_pallas_attention")
-            and jax.default_backend() == "tpu"
-            and fa.supported(tq, dh)
-        ):
-            bq, bk = fa.auto_blocks(tq)
-            out = fa.flash_attention_diff(
-                q, k, v,
-                kv_in.lengths if kv_in.is_seq else None,
-                causal, bq, bk, False,
-            ).reshape(b, tq, d)
+        if get_flag("use_pallas_attention"):
+            why = None
+            if jax.default_backend() != "tpu":
+                why = f"the backend is {jax.default_backend()!r}, not 'tpu'"
+            elif tq != tk:
+                why = f"query length {tq} != key length {tk}"
+            elif not fa.supported(tq, dh):
+                why = f"T={tq}, head dim {dh} is not a shape the kernel takes"
+            if why is None:
+                bq, bk = fa.auto_blocks(tq)
+                out = fa.flash_attention_diff(
+                    q, k, v,
+                    kv_in.lengths if kv_in.is_seq else None,
+                    causal, bq, bk, False,
+                ).reshape(b, tq, d)
+            else:
+                import warnings
+
+                warnings.warn(
+                    f"{conf.name}: use_pallas_attention is on but {why}; "
+                    "computing dense O(T^2) attention",
+                    stacklevel=2,
+                )
 
     if out is None:  # dense path
         # Explicit [B, h, T, dh] operands with LEADING batch dims: the

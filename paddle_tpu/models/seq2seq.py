@@ -326,17 +326,20 @@ class Seq2SeqGenerator:
 
         return weight_bundle_bytes(w)
 
+    def _fused(self) -> bool:
+        from paddle_tpu.utils.flags import get_flag
+
+        return self._match is not None and bool(get_flag("fused_attention_gru"))
+
     def _step_fn(self, statics, gp):
         """Build step_fn(ids, carry) for beam/greedy: embeds ids with the
         trained trg_emb table, runs the decoder sub-network once — through
         the fused attention-GRU step when the topology matched."""
-        from paddle_tpu.utils.flags import get_flag
-
         emb_w = gp["trg_emb"]["w"]
         sub_params = gp["decoder"]
         m0 = self._memories[0] if self._memories else None
 
-        if self._match is not None and get_flag("fused_attention_gru"):
+        if self._fused():
             mt = self._match
             w = self.fused_decode_weights(gp)
             enc_t = statics[mt.enc_name]
@@ -386,6 +389,13 @@ class Seq2SeqGenerator:
             val = outs[lname]
             statics[pname] = val if is_seq else SeqTensor(val.data)
         boot = outs["dec_boot"].data
+        if self._fused():
+            # the fused chain runs on the master weights, so its state is
+            # carried in their dtype — the encoder (under a bfloat16
+            # compute dtype) hands over a narrower boot, and a loop carry
+            # may not change type.  The serving engine's slot plane holds
+            # the state the same way.
+            boot = boot.astype(gp["trg_emb"]["w"].dtype)
         carry = {m.name: boot for m in self._memories}
         b = boot.shape[0]
         return statics, carry, b, gp

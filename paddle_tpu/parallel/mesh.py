@@ -30,30 +30,6 @@ DATA_AXIS = "data"
 MODEL_AXIS = "model"
 
 
-def shard_map(*args, **kwargs):
-    """``jax.shard_map`` across jax versions: the top-level name (jax >=
-    0.5) with a fallback to ``jax.experimental.shard_map`` — call sites
-    (ring attention, the GPipe pipeline, the allreduce bench) stay one
-    spelling."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:  # older jax: experimental namespace only
-        from jax.experimental.shard_map import shard_map as fn
-    if "check_vma" in kwargs:
-        # the replication-check kwarg was renamed check_rep -> check_vma;
-        # mid-window jax exposes the top-level name but still takes
-        # check_rep, so translate by the actual signature, not the lookup
-        # path
-        try:
-            import inspect
-
-            sig_params = inspect.signature(fn).parameters
-        except (TypeError, ValueError):
-            sig_params = {"check_vma": None}
-        if "check_vma" not in sig_params and "check_rep" in sig_params:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-    return fn(*args, **kwargs)
-
-
 @dataclasses.dataclass(frozen=True)
 class MeshConfig:
     data: int = -1  # -1 = all remaining devices
@@ -62,8 +38,7 @@ class MeshConfig:
 
 # ---------------------------------------------------------------------------
 # Multi-process process group — jax.distributed with a coordination-service
-# fallback shim (the shard_map-shim pattern: one spelling across jax
-# versions/backends).
+# fallback shim.
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
@@ -74,8 +49,8 @@ class ProcessGroup:
       * ``"jax-distributed"`` — a real jax.distributed runtime was formed;
         jax.devices() is the GLOBAL device set and in-program collectives
         cross processes over ICI/DCN.
-      * ``"shim"`` — the dev-container fallback (older jax, or CPU-only
-        platforms where jax.distributed cannot form a backend): each
+      * ``"shim"`` — the dev-container fallback (CPU-only platforms
+        where jax.distributed cannot form a backend): each
         process keeps its local devices and cross-process reduction rides
         the master coordination service instead (trainer/elastic.py's
         pass-fence + task-result reduce).
@@ -139,8 +114,8 @@ def init_process_group(
             )
             backend = "jax-distributed"
         except (AttributeError, NotImplementedError, RuntimeError, ValueError) as exc:
-            # older jax / no distributable backend: fall back to the shim —
-            # but the operator EXPLICITLY asked for the real runtime, so
+            # no distributable backend: fall back to the shim — but the
+            # operator EXPLICITLY asked for the real runtime, so
             # say loudly that they are not getting it (a silent shim on a
             # pod means N unsynchronized replicas, not one job)
             logging.getLogger("paddle_tpu.parallel").warning(

@@ -97,9 +97,8 @@ def pipeline_apply(
         jax.tree_util.tree_map(lambda _: P(axis), stage_params),
         P(),
     )
-    from paddle_tpu.parallel.mesh import shard_map as _shard_map
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         spmd, mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False
     )
     return fn(stage_params, microbatches)

@@ -108,8 +108,7 @@ def sequence_parallel_attention(
         def mapped(q_, k_, v_):
             return fn(q_, k_, v_)
 
-    from paddle_tpu.parallel.mesh import shard_map as _shard_map
 
-    shmapped = _shard_map(mapped, mesh=mesh, in_specs=in_specs, out_specs=spec)
+    shmapped = jax.shard_map(mapped, mesh=mesh, in_specs=in_specs, out_specs=spec)
     args = (q, k, v) + ((lengths,) if lengths is not None else ())
     return shmapped(*args)

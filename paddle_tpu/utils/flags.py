@@ -81,7 +81,10 @@ def reset_flags() -> None:
 #  seed/beam_size...; pserver networking flags are obsolete — the mesh
 #  replaces them.)
 define_flag("use_tpu", True, "accepted for surface compat; platform comes from jax")
-define_flag("trainer_count", 1, "accepted for surface compat; parallelism comes from the mesh")
+define_flag("trainer_count", 1,
+            "data-parallel width: `paddle-tpu train --trainer_count N` builds "
+            "an N-way data mesh (or fails); library callers pass mesh= to "
+            "trainer.SGD instead")
 define_flag("seed", 0, "global RNG seed")
 define_flag("log_period", 100, "log training stats every N batches")
 define_flag("show_parameter_stats_period", 0, "log per-parameter stats every N batches (0=off)")
@@ -148,8 +151,7 @@ define_flag("aot_cache_dir", "",
             "jax+backend version — stale or foreign entries are detected "
             "and retraced, never loaded wrong.  Prewarm the full rung set "
             "offline with `paddle-tpu cache warm`; empty = off (today's "
-            "retrace path).  jax builds without executable serialization "
-            "degrade gracefully to retracing")
+            "retrace path)")
 define_flag("whole_pass_program", False,
             "whole-pass on-device epoch program: when the device-resident "
             "pass cache holds a sealed single-bucket pass, epochs >= 2 run "

@@ -27,14 +27,6 @@ def _clean_flags():
     reset_flags()
 
 
-# the version-compat shim path (no executable serialization) degrades to
-# retracing — everything that asserts on real disk entries skips there
-needs_ser = pytest.mark.skipif(
-    not ac.serialization_available(),
-    reason="jax build has no executable serialization (shim no-op path)",
-)
-
-
 def _jitted():
     import jax
     import jax.numpy as jnp
@@ -55,7 +47,6 @@ def _identity(n=None):
 # ---------------------------------------------------------------------------
 
 
-@needs_ser
 def test_miss_then_hit_roundtrip(tmp_path):
     import jax.numpy as jnp
 
@@ -77,7 +68,6 @@ def test_miss_then_hit_roundtrip(tmp_path):
     np.testing.assert_array_equal(np.asarray(out2["w"]), np.asarray(out["w"]))
 
 
-@needs_ser
 def test_distinct_identities_are_distinct_entries(tmp_path):
     cache = ac.AOTCache(str(tmp_path), stats=StatSet())
     fn, args = _jitted()
@@ -87,7 +77,6 @@ def test_distinct_identities_are_distinct_entries(tmp_path):
     assert cache.compiles == 2
 
 
-@needs_ser
 def test_serialization_writes_real_entries(tmp_path):
     cache = ac.AOTCache(str(tmp_path), stats=StatSet())
     fn, args = _jitted()
@@ -110,7 +99,6 @@ def _entry_paths(tmp_path):
     ]
 
 
-@needs_ser
 def test_truncated_entry_falls_back_to_retrace(tmp_path, caplog):
     stats = StatSet()
     cache = ac.AOTCache(str(tmp_path), stats=stats)
@@ -134,7 +122,6 @@ def test_truncated_entry_falls_back_to_retrace(tmp_path, caplog):
     np.testing.assert_allclose(np.asarray(out["b"]), 2.0)
 
 
-@needs_ser
 def test_header_level_truncation_falls_back_to_retrace(tmp_path):
     """Truncation INSIDE the fixed-size framing fields (magic + partial
     length u32, or cut before the CRC) must be a corrupt entry, not an
@@ -160,7 +147,6 @@ def test_header_level_truncation_falls_back_to_retrace(tmp_path):
     np.testing.assert_allclose(np.asarray(out["b"]), 2.0)
 
 
-@needs_ser
 def test_mismatched_jax_version_key_is_stale(tmp_path, caplog, monkeypatch):
     """An entry written by a different jax (or backend) must be detected
     and retraced — simulated by rewriting the header's env fields, the
@@ -188,7 +174,6 @@ def test_mismatched_jax_version_key_is_stale(tmp_path, caplog, monkeypatch):
     np.testing.assert_allclose(np.asarray(out["b"]), 2.0)
 
 
-@needs_ser
 def test_foreign_topology_entry_never_loads(tmp_path):
     """A valid entry for a DIFFERENT program renamed into this identity's
     path (hash collision stand-in): the full-key comparison rejects it —
@@ -210,7 +195,6 @@ def test_foreign_topology_entry_never_loads(tmp_path):
     np.testing.assert_allclose(np.asarray(out["b"]), 2.0)
 
 
-@needs_ser
 def test_meta_mismatch_is_stale(tmp_path):
     """Same program identity, different hyperparameters (the optimizer
     fingerprint): the old executable bakes the old constants — stale."""
@@ -243,7 +227,6 @@ def test_optimizer_fingerprint_distinguishes_hyperparams():
 # ---------------------------------------------------------------------------
 
 
-@needs_ser
 def test_prune_drops_oldest_until_fit(tmp_path):
     cache = ac.AOTCache(str(tmp_path), stats=StatSet())
     fn, args = _jitted()
@@ -256,12 +239,9 @@ def test_prune_drops_oldest_until_fit(tmp_path):
     removed = cache.prune(max_bytes=sizes[os.path.basename(keep_newest)])
     assert len(removed) == 2
     assert os.path.exists(keep_newest)
-    assert cache.load(_identity(n=2)) is not None or not (
-        ac.serialization_available()
-    )
+    assert cache.load(_identity(n=2)) is not None
 
 
-@needs_ser
 def test_prune_and_clear_sweep_orphaned_tmp_files(tmp_path):
     """A writer SIGKILLed mid-_write_entry leaves <hash>.aotx.tmp.<pid>;
     the maintenance commands must reclaim it even though it is not a
@@ -282,7 +262,6 @@ def test_prune_and_clear_sweep_orphaned_tmp_files(tmp_path):
     assert os.listdir(str(tmp_path)) == []
 
 
-@needs_ser
 def test_clear_empties_store(tmp_path):
     cache = ac.AOTCache(str(tmp_path), stats=StatSet())
     fn, args = _jitted()
@@ -331,7 +310,6 @@ def _train(num_passes=2, seed=0):
     return tr
 
 
-@needs_ser
 def test_sgd_aot_dispatch_cold_then_warm_trainer(tmp_path):
     """Two trainers sharing one cache dir: the second resolves every shape
     by deserializing — zero compiles — and trains to bit-identical
@@ -458,7 +436,6 @@ def _boot(tmp_path, cache_dir):
     return json.loads(r.stdout.strip().splitlines()[-1])
 
 
-@needs_ser
 def test_subprocess_warm_boot_zero_retraces(tmp_path):
     """Acceptance: a second process against a populated cache performs
     ZERO full retraces for the rungs (train-step shapes + the whole-pass
@@ -525,7 +502,6 @@ def _write_v1_config(tmp_path):
 
 
 @pytest.mark.slow
-@needs_ser
 def test_cache_cli_warm_ls_prune_clear(tmp_path):
     cfg = _write_v1_config(tmp_path)
     d = str(tmp_path / "cache")

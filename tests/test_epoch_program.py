@@ -150,14 +150,11 @@ def test_sentinel_skipped_step_parity():
 
 
 def test_whole_pass_composes_with_aot_cache(tmp_path):
-    from paddle_tpu.core.aot_cache import serialization_available
-
     set_flag("aot_cache_dir", str(tmp_path))
     tr = _train(True)
     assert global_stats.count("epoch_program/dispatches") == 2
-    if serialization_available():
-        kinds = {e["key"]["kind"] for e in tr._aot_cache.entries()}
-        assert kinds == {"train_step", "epoch_program"}
+    kinds = {e["key"]["kind"] for e in tr._aot_cache.entries()}
+    assert kinds == {"train_step", "epoch_program"}
 
 
 # ---------------------------------------------------------------------------

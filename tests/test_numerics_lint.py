@@ -296,7 +296,6 @@ def test_n405_sees_through_shard_map():
     from jax.sharding import Mesh, PartitionSpec as P
 
     from paddle_tpu.ops.quantize import quantized_psum
-    from paddle_tpu.parallel.mesh import shard_map
 
     mesh = Mesh(np.array(jax.devices()), ("dp",))
     n = len(jax.devices())
@@ -306,14 +305,14 @@ def test_n405_sees_through_shard_map():
             q = t.astype(jnp.int8)
             return jax.lax.psum(q, "dp").astype(jnp.float32)
 
-        return shard_map(body, mesh=mesh, in_specs=(P("dp"),),
+        return jax.shard_map(body, mesh=mesh, in_specs=(P("dp"),),
                          out_specs=P("dp"), check_vma=False)(g)
 
     closed = jax.make_jaxpr(naked)(jnp.zeros((n, 32), jnp.float32))
     assert "N405" in rules(lint_numerics_jaxpr(closed, apply_pragmas=False))
 
     def paired(g):
-        return shard_map(
+        return jax.shard_map(
             lambda t: quantized_psum(t, "dp", mean=True), mesh=mesh,
             in_specs=(P("dp"),), out_specs=P("dp"), check_vma=False,
         )(g)

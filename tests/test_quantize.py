@@ -127,14 +127,13 @@ def _psum_ab(tree_parts, **kw):
     per-shard outputs (all identical) next to the exact f32 psum."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from paddle_tpu.parallel.mesh import shard_map
 
     mesh = Mesh(np.array(jax.devices()), ("dp",))
 
     def body(t):
         return bsq.quantized_psum(t, "dp", **kw)
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh, in_specs=(P("dp"),), out_specs=P("dp"),
         check_vma=False,
     )(tree_parts)
@@ -177,10 +176,9 @@ def test_quantized_psum_stochastic_rounding_unbiased_runs():
 
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from paddle_tpu.parallel.mesh import shard_map
 
     mesh = Mesh(np.array(jax.devices()), ("dp",))
-    out = shard_map(
+    out = jax.shard_map(
         lambda t, k: bsq.quantized_psum(t, "dp", stochastic=True, rng=k),
         mesh=mesh, in_specs=(P("dp"), P()), out_specs=P("dp"),
         check_vma=False,

@@ -29,9 +29,7 @@ def rules(diags):
 
 
 def test_t101_f64_leak_detected():
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         def leaky(x):
             return x * np.float64(2.0)
 
@@ -257,7 +255,7 @@ def test_t106_undonated_carry_fires():
 
 def test_t106_explicit_donate_argnums_on_plain_fn():
     """For an un-jitted fn the audit takes the donation the builder intends
-    as an argument — same rule, no pjit eqn to introspect."""
+    as an argument — same rule, no jit eqn to introspect."""
     from paddle_tpu.analysis import donation_audit
     from paddle_tpu.trainer.step import _train_step_body
 
