@@ -633,77 +633,85 @@ class CompiledNetwork:
                 continue
             conf = self.topology.layers[name]
             impl = self._impls[name]
+            # named_scope labels everything a layer costs in profiler traces
+            # under "type:name": a data slot's on-device feed transform
+            # (data:<slot>), a layer's body with the activation/dropout/clip
+            # this loop applies after it, and beside it (cast:<layer>, NOT
+            # inside: readers that time a layer by its scope keep timing the
+            # layer) mixed precision's casts of its weights and inputs — no
+            # operation of the graph runs outside such a scope.
             if conf.type in ("data", "step_input", "memory"):
                 # data: user slots; step_input/memory: placeholders fed by an
                 # enclosing recurrent_group's scan body.
                 if name not in batch:
                     raise KeyError(f"batch is missing data slot {name!r}")
-                ctx.outputs[name] = _feed_transform(conf, batch[name])
+                with jax.named_scope(f"{conf.type}:{name}"):
+                    ctx.outputs[name] = _feed_transform(conf, batch[name])
                 continue
             ins = [ctx.outputs[i] for i in conf.inputs]
             pre_keys = set(ctx.outputs) if mixed else ()
-            p, ins = self.resolve_layer_call(name, params, ins)
-            # named_scope labels this layer's ops in profiler traces; the
-            # except-note is the CustomStackTrace equivalent (reference
-            # utils/CustomStackTrace.h:51 pushes layer names so a fatal
-            # error reports which layer it happened in).
-            try:
-                with jax.named_scope(f"{conf.type}:{name}"):
+            with jax.named_scope(f"cast:{name}"):
+                p, ins = self.resolve_layer_call(name, params, ins)
+            with jax.named_scope(f"{conf.type}:{name}"):
+                # the except-note is the CustomStackTrace equivalent (reference
+                # utils/CustomStackTrace.h:51 pushes layer names so a fatal
+                # error reports which layer it happened in).
+                try:
                     out = impl.apply(conf, p, ins, ctx)
-            except Exception as e:
-                # layer-provenance note in the shared diagnostic format
-                # (analysis.diagnostics) — trace-time shape errors read like
-                # the graph linter's config-time findings, naming the layer
-                from paddle_tpu.analysis.diagnostics import (
-                    Diagnostic,
-                    Severity,
-                )
+                except Exception as e:
+                    # layer-provenance note in the shared diagnostic format
+                    # (analysis.diagnostics) — trace-time shape errors read like
+                    # the graph linter's config-time findings, naming the layer
+                    from paddle_tpu.analysis.diagnostics import (
+                        Diagnostic,
+                        Severity,
+                    )
 
-                shapes = [getattr(t.data, "shape", None) for t in ins]
-                note = Diagnostic(
-                    rule="T100",
-                    severity=Severity.ERROR,
-                    layer=name,
-                    message=(
-                        f"failed while applying this layer (type={conf.type}, "
-                        f"size={conf.size}, inputs={list(conf.inputs)} with "
-                        f"shapes {shapes})"
-                    ),
-                    hint="run analysis.graph_lint.lint_topology on this "
-                    "topology — most shape/arity mistakes are caught "
-                    "before tracing",
-                ).format()
-                e.add_note(note)
-                raise
-            if mixed and not impl.full_precision:
-                # Enforce the compute dtype at every layer boundary —
-                # f32 constants/masks inside an impl would otherwise promote
-                # and leak float32 downstream (breaking e.g. scan carries).
-                out = _cast_floats(out, self.compute_dtype)
-                for k in set(ctx.outputs) - pre_keys:  # side outputs (@cell, …)
-                    ctx.outputs[k] = _cast_floats(
-                        ctx.outputs[k], self.compute_dtype
-                    )
-            if impl.auto_activation and conf.act not in ("identity", "linear", ""):
-                if conf.act == "softmax":
-                    # Stash pre-activation logits so downstream cross_entropy
-                    # fuses into log-softmax CE (numerically stable); XLA
-                    # dead-code-eliminates this when unused.
-                    ctx.outputs[name + "@logits"] = out
-                mask = out.mask() if (out.is_seq and conf.act == "sequence_softmax") else None
-                out = out.with_data(apply_activation(conf.act, out.data, mask))
-            if impl.auto_dropout and conf.drop_rate > 0.0 and train:
-                drop_rng = ctx.layer_rng(name + "/dropout")
-                if drop_rng is not None:
-                    keep = 1.0 - conf.drop_rate
-                    m = jax.random.bernoulli(drop_rng, keep, out.data.shape)
-                    out = out.with_data(
-                        jnp.where(m, out.data / keep, jnp.zeros_like(out.data))
-                    )
-            eclip = conf.attr("error_clip", 0.0)
-            if eclip and train:
-                out = out.with_data(_error_clip(out.data, eclip))
-            ctx.outputs[name] = out
+                    shapes = [getattr(t.data, "shape", None) for t in ins]
+                    note = Diagnostic(
+                        rule="T100",
+                        severity=Severity.ERROR,
+                        layer=name,
+                        message=(
+                            f"failed while applying this layer (type={conf.type}, "
+                            f"size={conf.size}, inputs={list(conf.inputs)} with "
+                            f"shapes {shapes})"
+                        ),
+                        hint="run analysis.graph_lint.lint_topology on this "
+                        "topology — most shape/arity mistakes are caught "
+                        "before tracing",
+                    ).format()
+                    e.add_note(note)
+                    raise
+                if mixed and not impl.full_precision:
+                    # Enforce the compute dtype at every layer boundary —
+                    # f32 constants/masks inside an impl would otherwise promote
+                    # and leak float32 downstream (breaking e.g. scan carries).
+                    out = _cast_floats(out, self.compute_dtype)
+                    for k in set(ctx.outputs) - pre_keys:  # side outputs (@cell, …)
+                        ctx.outputs[k] = _cast_floats(
+                            ctx.outputs[k], self.compute_dtype
+                        )
+                if impl.auto_activation and conf.act not in ("identity", "linear", ""):
+                    if conf.act == "softmax":
+                        # Stash pre-activation logits so downstream cross_entropy
+                        # fuses into log-softmax CE (numerically stable); XLA
+                        # dead-code-eliminates this when unused.
+                        ctx.outputs[name + "@logits"] = out
+                    mask = out.mask() if (out.is_seq and conf.act == "sequence_softmax") else None
+                    out = out.with_data(apply_activation(conf.act, out.data, mask))
+                if impl.auto_dropout and conf.drop_rate > 0.0 and train:
+                    drop_rng = ctx.layer_rng(name + "/dropout")
+                    if drop_rng is not None:
+                        keep = 1.0 - conf.drop_rate
+                        m = jax.random.bernoulli(drop_rng, keep, out.data.shape)
+                        out = out.with_data(
+                            jnp.where(m, out.data / keep, jnp.zeros_like(out.data))
+                        )
+                eclip = conf.attr("error_clip", 0.0)
+                if eclip and train:
+                    out = out.with_data(_error_clip(out.data, eclip))
+                ctx.outputs[name] = out
         new_state = dict(ctx.state)
         new_state.update(ctx.new_state)
         return ctx.outputs, new_state
@@ -737,7 +745,10 @@ class CompiledNetwork:
         out, outs, new_state = self.forward(
             params, batch, state=state, train=train, rng=rng
         )
-        return jnp.mean(out.data), (outs, new_state)
+        name = self.topology.output_names[0]
+        # the batch mean is the cost layer's last operation: its scope
+        with jax.named_scope(f"{self.topology.layers[name].type}:{name}"):
+            return jnp.mean(out.data), (outs, new_state)
 
 
 def count_params(params: Params) -> int:

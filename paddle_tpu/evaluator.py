@@ -717,7 +717,9 @@ def combined_update(evaluators: Sequence[Evaluator]):
     def update(outs) -> Accums:
         acc: Accums = {}
         for ev in evaluators:
-            for k, v in ev.update(outs).items():
+            with jax.named_scope(f"evaluator:{ev.name}"):
+                updates = ev.update(outs)
+            for k, v in updates.items():
                 acc[f"ev:{ev.name}:{k}"] = v
         return acc
 

@@ -210,10 +210,13 @@ class Tracer:
 
     # -- recording -------------------------------------------------------
     def _emit(self, ph: str, name: str, cat: str,
-              args: Optional[Dict[str, Any]]) -> None:
+              args: Optional[Dict[str, Any]]) -> Optional[float]:
+        """Records one event; returns the clock reading (seconds) it was
+        stamped with, None when disarmed."""
         if not self._recording:
-            return
-        ts_us = self._clock() * _US
+            return None
+        now = self._clock()
+        ts_us = now * _US
         tid = threading.get_ident()
         with self._lock:
             ring = self._rings.get(tid)
@@ -222,6 +225,7 @@ class Tracer:
                 self._rings[tid] = ring
                 self._thread_names[tid] = threading.current_thread().name
             ring.append((ph, ts_us, name, cat, args))
+        return now
 
     def instant(self, name: str, cat: str = "host", **args: Any) -> None:
         """One point-in-time event (ph 'i') — lifecycle transitions
@@ -235,23 +239,29 @@ class Tracer:
         self._emit("E", name, cat, None)
 
     @contextlib.contextmanager
-    def span(self, name: str, cat: str = "host", **args: Any) -> Iterator[None]:
-        """Scoped begin/end pair.  Disarmed cost: one attribute read and a
+    def span(
+        self, name: str, cat: str = "host", **args: Any
+    ) -> Iterator[Optional[List[Optional[float]]]]:
+        """Scoped begin/end pair.  Yields ``[begin, end]``, the clock
+        readings (seconds) the two events are stamped with — ``end`` is
+        filled in on exit — so a caller that does arithmetic on its own
+        spans (the trainer's slow-step record) reads no clock of its own.
+        Disarmed: yields None at the cost of one attribute read and a
         generator frame — cheap enough to leave on hot paths."""
         if not self._recording:
-            yield
+            yield None
             return
         ann = self._annotation_factory
         ctx = ann(name) if ann is not None else None
-        self._emit("B", name, cat, args or None)
+        times = [self._emit("B", name, cat, args or None), None]
         if ctx is not None:
             ctx.__enter__()
         try:
-            yield
+            yield times
         finally:
             if ctx is not None:
                 ctx.__exit__(None, None, None)
-            self._emit("E", name, cat, None)
+            times[1] = self._emit("E", name, cat, None)
 
     def reset(self) -> None:
         with self._lock:

@@ -471,6 +471,12 @@ def _cond_step(active, live_fn, carry, ys_struct):
     return lax.cond(active, live_fn, dead, carry)
 
 
+# "attgru_core" (forward here, backward below) sets the recurrence itself
+# apart, in a device trace, from the projections the decoder's
+# recurrent_group scope also holds.  No colon: a "type:name" scope would
+# become the operations' innermost LAYER scope and take them out of the
+# layer's own reading.
+@jax.named_scope("attgru_core")
 def _attgru_fwd_scan(opts, xg, enc, ep, emask, w1, v, w_ctx, w_c, h0, mask):
     acts, early = opts[:3], opts[3]
 
@@ -523,6 +529,7 @@ def _attgru_core_fwd(opts, xg, enc, ep, emask, w1, v, w_ctx, w_c, h0, mask):
     return (hs, h_last), res
 
 
+@jax.named_scope("attgru_core")
 def _attgru_core_bwd(opts, res, cts):
     acts, early = opts[:3], opts[3]
     (sp_seq, alpha_seq, ctx_seq, pu_seq, pr_seq, cpre_seq, hs,

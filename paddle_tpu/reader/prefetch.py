@@ -40,7 +40,10 @@ class DevicePrefetcher:
     shard_batch/device_put); the returned batches come out in order.
     ``wait_s`` accumulates main-thread time spent blocked on the queue —
     ~0 means the data plane fully hides behind compute; large means the
-    reader/transfer is the bottleneck (the number the bench reports).
+    reader/transfer is the bottleneck.  ``prefetch()`` hides this object,
+    so no trainer path reads the field (only ``bench.py`` does): what
+    ``trainer.SGD.train`` waits for its next batch is its ``feed_wait``
+    span (trainer/sgd.py), the same wait seen from the consumer's side.
     """
 
     def __init__(

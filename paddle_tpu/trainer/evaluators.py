@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from typing import Callable, Dict, Optional
 
+import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.batch import SeqTensor
@@ -33,30 +34,33 @@ def default_metrics_fn(topology: Topology) -> Optional[Callable]:
     def metrics(outs: Dict[str, SeqTensor]) -> Dict[str, jnp.ndarray]:
         m: Dict[str, jnp.ndarray] = {}
         for conf in cls:
-            pred_name, label_name = conf.inputs[0], conf.inputs[1]
-            pred, label = outs[pred_name], outs[label_name]
-            ids = label.data.astype(jnp.int32)
-            if ids.ndim >= 2 and ids.shape[-1] == 1:
-                ids = ids[..., 0]
-            # argmax(softmax(x)) == argmax(x): read the pre-activation aux
-            # when the producer exposed one, so the error metric never
-            # forces the [N, V] softmax to materialize (at a 32k MT vocab
-            # that softmax is ~1 GB per step and exists ONLY for this
-            # metric — the fused CE reads logits)
-            lg = outs.get(pred_name + "@logits")
-            scores = lg.data if lg is not None else pred.data
-            err = (jnp.argmax(scores, axis=-1) != ids).astype(jnp.float32)
-            if pred.is_seq and err.ndim == 2:
-                mask = pred.mask()
-                err = jnp.sum(err * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-            else:
-                err = jnp.mean(err)
-            key = (
-                "classification_error"
-                if len(cls) == 1
-                else f"classification_error/{conf.name}"
-            )
-            m[key] = err
+            # a scope of the layers' "type:name" form: the argmax over a
+            # 30k vocabulary is device time a trace should be able to name
+            with jax.named_scope(f"evaluator:classification_error.{conf.name}"):
+                pred_name, label_name = conf.inputs[0], conf.inputs[1]
+                pred, label = outs[pred_name], outs[label_name]
+                ids = label.data.astype(jnp.int32)
+                if ids.ndim >= 2 and ids.shape[-1] == 1:
+                    ids = ids[..., 0]
+                # argmax(softmax(x)) == argmax(x): read the pre-activation aux
+                # when the producer exposed one, so the error metric never
+                # forces the [N, V] softmax to materialize (at a 32k MT vocab
+                # that softmax is ~1 GB per step and exists ONLY for this
+                # metric — the fused CE reads logits)
+                lg = outs.get(pred_name + "@logits")
+                scores = lg.data if lg is not None else pred.data
+                err = (jnp.argmax(scores, axis=-1) != ids).astype(jnp.float32)
+                if pred.is_seq and err.ndim == 2:
+                    mask = pred.mask()
+                    err = jnp.sum(err * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+                else:
+                    err = jnp.mean(err)
+                key = (
+                    "classification_error"
+                    if len(cls) == 1
+                    else f"classification_error/{conf.name}"
+                )
+                m[key] = err
         return m
 
     return metrics

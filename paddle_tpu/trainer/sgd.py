@@ -10,8 +10,11 @@ but there is nothing remote to talk to.
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import logging
 import os
+import statistics
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
@@ -42,6 +45,63 @@ def _batch_rows(batch) -> int:
         data = t.data if hasattr(t, "data") else t
         return int(data.shape[0])
     return 1
+
+
+# the slow-step record: a step is slow when it took over _SLOW_STEP_RATIO
+# times the median of the trainer's last _SLOW_STEP_WINDOW steps AND at
+# least _SLOW_STEP_MIN_MS over it (a 3 ms step after 1 ms ones is noise)
+_SLOW_STEP_WINDOW = 64
+_SLOW_STEP_RATIO = 2.0
+_SLOW_STEP_MIN_MS = 50.0
+
+
+@contextlib.contextmanager
+def _step_span(pass_id: int, bid: int, recent: collections.deque):
+    """The parent span ``step`` of ONE iteration of the stepwise loop, from
+    before the batch is taken to after the step's last bookkeeping.
+
+    Yields a dict the loop hangs its three child spans on
+    (``with _obs.span(...) as phase["feed_wait"]`` / ``"train_step"`` /
+    ``"block_fetch"``); what of the step they do not cover — handlers,
+    judge_step, compile_cache.observe, recovery bookkeeping — is the step's
+    self time, so the four numbers partition the step by construction.
+
+    On exit (``continue``/``return`` included) the step's length joins
+    ``recent`` and, where it stands out of their median, one ``slow_step``
+    instant lands in the always-on ring with the four phases, beside a
+    ``slow_steps`` count and a log line — the record a ``--trace 0`` job
+    leaves of WHERE a stall sat.  The iteration that finds the pass
+    exhausted holds a ``feed_wait`` alone and is no step: it is skipped.
+    Rides the spans' own clock readings; disarmed, nothing is judged."""
+    phase: Dict[str, Any] = {}
+    with _obs.span("step", cat="trainer", p=pass_id, b=bid) as whole:
+        yield phase
+    times = [whole] + [
+        phase.get(k) for k in ("feed_wait", "train_step", "block_fetch")
+    ]
+    if any(t is None or t[1] is None for t in times):
+        return  # recorder off (or switched mid-step), or no step was run
+    ms, wait_ms, dispatch_ms, fetch_ms = ((t[1] - t[0]) * 1e3 for t in times)
+    median = statistics.median(recent) if recent else None
+    recent.append(ms)
+    if (
+        median is None
+        or ms <= _SLOW_STEP_RATIO * median
+        or ms - median < _SLOW_STEP_MIN_MS
+    ):
+        return
+    parts = dict(
+        ms=ms, feed_wait_ms=wait_ms, dispatch_ms=dispatch_ms,
+        fetch_ms=fetch_ms, self_ms=ms - wait_ms - dispatch_ms - fetch_ms,
+    )
+    _obs.instant("slow_step", cat="trainer", p=pass_id, b=bid, **parts)
+    global_stats.incr("slow_steps")
+    _log.warning(
+        "slow_step pass %d batch %d: %.1f ms against a median of %.1f "
+        "(feed_wait %.1f, dispatch %.1f, fetch %.1f, self %.1f)",
+        pass_id, bid, ms, median, wait_ms, dispatch_ms, fetch_ms,
+        parts["self_ms"],
+    )
 
 
 class SGD:
@@ -157,6 +217,10 @@ class SGD:
         self._opt_state = self.optimizer.init(self.parameters.params)
         self._rng = jax.random.PRNGKey(seed + 1)
         self._step_count = 0
+        # lengths (ms) of the last steps: the slow-step record's yardstick
+        self._step_ms: collections.deque = collections.deque(
+            maxlen=_SLOW_STEP_WINDOW
+        )
         self._pass_cache = None  # set per train() call when caching is on
         self._pass_cache_reader = None  # the reader the cache was built for
         # Per-bucket dispatch accounting: every train/eval batch's shape
@@ -465,9 +529,11 @@ class SGD:
         feeder = self._make_feeder(feeding)
 
         def _stage(data_batch):
-            # obs: the STAGE leg of the stage/dispatch/block triple — on the
-            # prefetch thread when async_load_data is on, so a merged
-            # timeline shows feed overlapping compute (or failing to)
+            # obs: the STAGE work behind the loop's five spans (step >
+            # feed_wait, train_step, block_fetch; see _step_span) — on the
+            # prefetch thread when async_load_data is on, so a timeline shows
+            # feed overlapping compute (or failing to: feed_wait grows);
+            # inside feed_wait on the trainer thread when it is off
             with stat_timer("feed"), _obs.span("feed", cat="trainer"):
                 fed = feeder(data_batch)
                 if _chaos.fire("nan_batch"):
@@ -783,180 +849,188 @@ class SGD:
             replay: deque = deque()
             batch_id = skip - 1
             while True:
-                if replay:
-                    _, bid, batch = replay.popleft()
-                    is_live = False
-                else:
-                    try:
-                        batch = next(live)
-                    except StopIteration:
-                        break
-                    batch_id += 1
-                    bid = batch_id
-                    is_live = True
-                if not self._width_resolved:
-                    # fc/matrix-projection weights over a whole-minibatch
-                    # trans have a batch-dependent height; the FIRST batch
-                    # this trainer sees pins it (resolve_dynamic_widths) —
-                    # any later batch-size change hits an XLA shape error
-                    # rather than silently re-drawing trained weights
-                    self._width_resolved = True
-                    params, chg = self.network.resolve_dynamic_widths(
-                        params, batch
-                    )
-                    if chg:  # weight shapes moved: optimizer slots follow
-                        opt_state = self.optimizer.init(params)
-                event_handler(v2_event.BeginIteration(pass_id, bid))
-                if self.compile_cache.observe(batch) and self._step_count:
-                    # a NEW batch shape after warmup = a jit recompile; say
-                    # so at debug level (the hit/miss counters aggregate in
-                    # the StatSet table either way)
-                    _log.debug(
-                        "train batch %d brings new shape (distinct shapes "
-                        "now %d)", bid, self.compile_cache.n_shapes,
-                    )
-                if is_live and recovery is not None:
-                    recovery.record(pass_id, bid, batch)
-                # obs: DISPATCH (issue the async jitted step) then BLOCK
-                # (the host sync on the fetched cost scalar) — the split
-                # that shows whether a slow step is compute or host-feed
-                with stat_timer("train_step"), _obs.span(
-                    "train_step", cat="trainer", p=pass_id, b=bid,
-                ):
-                    self._rng, step_rng = jax.random.split(self._rng)
-                    if num_san is not None:
-                        # the dispatch donates params/state/opt-state —
-                        # copy the step's inputs out first or there is
-                        # nothing left to re-execute when it goes bad
-                        num_san.capture(
-                            params, state, opt_state, batch, step_rng,
-                            where=f"pass {pass_id} batch {bid}",
+                bid = replay[0][1] if replay else batch_id + 1
+                with _step_span(pass_id, bid, self._step_ms) as phase:
+                    # obs: the trainer's wait for its next batch (with
+                    # async_load_data off the feed runs here, inside it)
+                    with _obs.span(
+                        "feed_wait", cat="trainer", b=bid
+                    ) as phase["feed_wait"]:
+                        if replay:
+                            _, _, batch = replay.popleft()
+                            is_live = False
+                        else:
+                            try:
+                                batch = next(live)
+                            except StopIteration:
+                                break
+                            batch_id = bid
+                            is_live = True
+                    if not self._width_resolved:
+                        # fc/matrix-projection weights over a whole-minibatch
+                        # trans have a batch-dependent height; the FIRST batch
+                        # this trainer sees pins it (resolve_dynamic_widths) —
+                        # any later batch-size change hits an XLA shape error
+                        # rather than silently re-drawing trained weights
+                        self._width_resolved = True
+                        params, chg = self.network.resolve_dynamic_widths(
+                            params, batch
                         )
-                    params, state, opt_state, metrics = self._run_train_step(
-                        params, state, opt_state, batch, step_rng
-                    )
-                self._step_count += 1
-                health = metrics.pop("health", None)
-                grad_norm = metrics.pop("grad_norm", None)
-                with _obs.span("block_fetch", cat="trainer", b=bid):
-                    cost = float(metrics["cost"])
-                if _chaos.fire("kill"):  # hard-preemption drill: no flush
-                    _chaos.kill_self()
-                if (
-                    show_parameter_stats_period
-                    and self._step_count % show_parameter_stats_period == 0
-                ):
-                    # reference TrainerInternal.cpp:83-110 per-param stats log
-                    from paddle_tpu.utils.debug import (
-                        format_parameter_stats,
-                        parameter_stats,
-                    )
+                        if chg:  # weight shapes moved: optimizer slots follow
+                            opt_state = self.optimizer.init(params)
+                    event_handler(v2_event.BeginIteration(pass_id, bid))
+                    if self.compile_cache.observe(batch) and self._step_count:
+                        # a NEW batch shape after warmup = a jit recompile; say
+                        # so at debug level (the hit/miss counters aggregate in
+                        # the StatSet table either way)
+                        _log.debug(
+                            "train batch %d brings new shape (distinct shapes "
+                            "now %d)", bid, self.compile_cache.n_shapes,
+                        )
+                    if is_live and recovery is not None:
+                        recovery.record(pass_id, bid, batch)
+                    # obs: DISPATCH (issue the async jitted step) then BLOCK
+                    # (the host sync on the fetched cost scalar) — the split
+                    # that shows whether a slow step is compute or host-feed
+                    with stat_timer("train_step"), _obs.span(
+                        "train_step", cat="trainer", p=pass_id, b=bid,
+                    ) as phase["train_step"]:
+                        self._rng, step_rng = jax.random.split(self._rng)
+                        if num_san is not None:
+                            # the dispatch donates params/state/opt-state —
+                            # copy the step's inputs out first or there is
+                            # nothing left to re-execute when it goes bad
+                            num_san.capture(
+                                params, state, opt_state, batch, step_rng,
+                                where=f"pass {pass_id} batch {bid}",
+                            )
+                        params, state, opt_state, metrics = self._run_train_step(
+                            params, state, opt_state, batch, step_rng
+                        )
+                    self._step_count += 1
+                    health = metrics.pop("health", None)
+                    grad_norm = metrics.pop("grad_norm", None)
+                    with _obs.span(
+                        "block_fetch", cat="trainer", b=bid
+                    ) as phase["block_fetch"]:
+                        cost = float(metrics["cost"])
+                    if _chaos.fire("kill"):  # hard-preemption drill: no flush
+                        _chaos.kill_self()
+                    if (
+                        show_parameter_stats_period
+                        and self._step_count % show_parameter_stats_period == 0
+                    ):
+                        # reference TrainerInternal.cpp:83-110 per-param stats log
+                        from paddle_tpu.utils.debug import (
+                            format_parameter_stats,
+                            parameter_stats,
+                        )
 
-                    _log.info(
-                        "parameter stats @ step %d:\n%s",
-                        self._step_count,
-                        format_parameter_stats(parameter_stats(params)),
-                    )
-                # judging every step costs no extra sync here: this loop
-                # fetches the cost scalar anyway (events need it) —
-                # sentinel_check_interval only matters for fetch-free
-                # multi-step dispatch loops (make_multi_train_step's folded
-                # health/skipped_steps)
-                verdict = judge_step(
-                    pass_id, bid, cost, health, grad_norm, metrics,
-                    _batch_rows(batch),
-                )
-                if num_san is not None and (
-                    verdict in ("skip", "diverged")
-                    or not np.isfinite(cost)
-                ):
-                    # name the op that went non-finite, not just the step
-                    num_san.postmortem(
-                        f"{verdict} at pass {pass_id} batch {bid}"
-                    )
-                if not is_live and not replay and recovery is not None:
-                    recovery.replay_done()  # window re-applied cleanly
-                if verdict == "diverged":
-                    if recovery is None:
-                        _log.error(
-                            "divergence detected at pass %d batch %d but no "
-                            "checkpoint_dir is set — cannot roll back",
-                            pass_id, bid,
+                        _log.info(
+                            "parameter stats @ step %d:\n%s",
+                            self._step_count,
+                            format_parameter_stats(parameter_stats(params)),
                         )
-                        if sentinel is not None:
-                            sentinel.reset()
-                    else:
-                        action, window = recovery.on_divergence()
-                        if action != "none":
-                            # restore_fn updated self.*; resync the loop's
-                            # working refs and drop the undone bookkeeping
-                            params = self.parameters.params
-                            state = self.parameters.state
-                            opt_state = self._opt_state
-                            del pass_costs[costs_mark:]
-                            del pass_weights[costs_mark:]
-                            pass_accums = {
-                                k: np.copy(v) for k, v in accums_mark.items()
-                            }
+                    # judging every step costs no extra sync here: this loop
+                    # fetches the cost scalar anyway (events need it) —
+                    # sentinel_check_interval only matters for fetch-free
+                    # multi-step dispatch loops (make_multi_train_step's folded
+                    # health/skipped_steps)
+                    verdict = judge_step(
+                        pass_id, bid, cost, health, grad_norm, metrics,
+                        _batch_rows(batch),
+                    )
+                    if num_san is not None and (
+                        verdict in ("skip", "diverged")
+                        or not np.isfinite(cost)
+                    ):
+                        # name the op that went non-finite, not just the step
+                        num_san.postmortem(
+                            f"{verdict} at pass {pass_id} batch {bid}"
+                        )
+                    if not is_live and not replay and recovery is not None:
+                        recovery.replay_done()  # window re-applied cleanly
+                    if verdict == "diverged":
+                        if recovery is None:
+                            _log.error(
+                                "divergence detected at pass %d batch %d but no "
+                                "checkpoint_dir is set — cannot roll back",
+                                pass_id, bid,
+                            )
                             if sentinel is not None:
                                 sentinel.reset()
-                            if action == "retry":
-                                replay = deque(window)
-                    continue
-                if (
-                    recovery is not None
-                    and verdict == "ok"
-                    and checkpoint_period_batches
-                    and not recovery.replaying
-                    and (sentinel is None or sentinel.steady)
-                    and self._step_count % checkpoint_period_batches == 0
-                ):
-                    self.parameters.params, self.parameters.state = params, state
-                    self._opt_state = opt_state
-                    recovery.checkpoint(
-                        self._step_count,
-                        {
+                        else:
+                            action, window = recovery.on_divergence()
+                            if action != "none":
+                                # restore_fn updated self.*; resync the loop's
+                                # working refs and drop the undone bookkeeping
+                                params = self.parameters.params
+                                state = self.parameters.state
+                                opt_state = self._opt_state
+                                del pass_costs[costs_mark:]
+                                del pass_weights[costs_mark:]
+                                pass_accums = {
+                                    k: np.copy(v) for k, v in accums_mark.items()
+                                }
+                                if sentinel is not None:
+                                    sentinel.reset()
+                                if action == "retry":
+                                    replay = deque(window)
+                        continue
+                    if (
+                        recovery is not None
+                        and verdict == "ok"
+                        and checkpoint_period_batches
+                        and not recovery.replaying
+                        and (sentinel is None or sentinel.steady)
+                        and self._step_count % checkpoint_period_batches == 0
+                    ):
+                        self.parameters.params, self.parameters.state = params, state
+                        self._opt_state = opt_state
+                        recovery.checkpoint(
+                            self._step_count,
+                            {
+                                "step_count": self._step_count,
+                                "pass_id": pass_id,
+                                "batch_id": bid,
+                            },
+                        )
+                        costs_mark = len(pass_costs)
+                        accums_mark = {
+                            k: np.copy(v) for k, v in pass_accums.items()
+                        }
+                    if (
+                        save_dir
+                        and saving_period_by_batches
+                        and (bid + 1) % saving_period_by_batches == 0
+                    ):
+                        self.parameters.params, self.parameters.state = params, state
+                        self._opt_state = opt_state
+                        self.save_pass(save_dir, pass_id, batch_id=bid + 1)
+                    if guard is not None and guard.triggered:
+                        # preemption: finish THIS step's bookkeeping, persist a
+                        # synchronous final checkpoint + marker, hand back
+                        self.parameters.params, self.parameters.state = params, state
+                        self._opt_state = opt_state
+                        extra = {
                             "step_count": self._step_count,
                             "pass_id": pass_id,
                             "batch_id": bid,
-                        },
-                    )
-                    costs_mark = len(pass_costs)
-                    accums_mark = {
-                        k: np.copy(v) for k, v in pass_accums.items()
-                    }
-                if (
-                    save_dir
-                    and saving_period_by_batches
-                    and (bid + 1) % saving_period_by_batches == 0
-                ):
-                    self.parameters.params, self.parameters.state = params, state
-                    self._opt_state = opt_state
-                    self.save_pass(save_dir, pass_id, batch_id=bid + 1)
-                if guard is not None and guard.triggered:
-                    # preemption: finish THIS step's bookkeeping, persist a
-                    # synchronous final checkpoint + marker, hand back
-                    self.parameters.params, self.parameters.state = params, state
-                    self._opt_state = opt_state
-                    extra = {
-                        "step_count": self._step_count,
-                        "pass_id": pass_id,
-                        "batch_id": bid,
-                        "preempted": True,
-                    }
-                    self.save_checkpoint(
-                        manager, step=self._step_count, extra=extra
-                    )
-                    write_marker(
-                        checkpoint_dir, {**extra, "signal": guard.signum}
-                    )
-                    self.preempted = True
-                    _log.warning(
-                        "preempted at pass %d batch %d (step %d): state "
-                        "checkpointed under %s; restart with resume=True",
-                        pass_id, bid, self._step_count, checkpoint_dir,
-                    )
-                    return
+                            "preempted": True,
+                        }
+                        self.save_checkpoint(
+                            manager, step=self._step_count, extra=extra
+                        )
+                        write_marker(
+                            checkpoint_dir, {**extra, "signal": guard.signum}
+                        )
+                        self.preempted = True
+                        _log.warning(
+                            "preempted at pass %d batch %d (step %d): state "
+                            "checkpointed under %s; restart with resume=True",
+                            pass_id, bid, self._step_count, checkpoint_dir,
+                        )
+                        return
             # persist latest values so checkpoints/test see them
             self.parameters.params, self.parameters.state = params, state
             self._opt_state = opt_state

@@ -99,6 +99,47 @@ def _quantized_enabled(quantized: Optional[bool]) -> bool:
     return bool(get_flag("quantized_allreduce"))
 
 
+def _update_and_guard(
+    optimizer: Optimizer, prune_masks, guard: bool, grads, cost,
+    params, state, new_state, opt_state,
+):
+    """The step's tail after the gradients, shared by the plain and the
+    quantized-allreduce bodies: optimizer update (+ prune masks), then the
+    divergence sentinel's finiteness test and per-leaf selects.  Each half
+    traces under a ``type:name`` scope of its own (``optimizer:<method>``,
+    ``guard:sentinel``), as every layer does, so a device trace can say
+    what the update and the guard cost.
+    -> (new_params, new_state, new_opt_state, metrics)"""
+    update_scope = f"optimizer:{type(optimizer).__name__.lower()}"
+    with jax.named_scope(update_scope):
+        new_params, new_opt_state = optimizer.update(grads, opt_state, params)
+        new_params = apply_prune_masks(new_params, prune_masks)
+    metrics = {"cost": cost}
+    if guard:
+        with jax.named_scope("guard:sentinel"):
+            grad_norm = _global_norm(grads)
+            healthy = jnp.isfinite(cost.astype(jnp.float32)) & jnp.isfinite(
+                grad_norm
+            )
+
+            def keep(new, old):
+                return jax.tree_util.tree_map(
+                    lambda n, o: jax.lax.select(healthy, n, o), new, old
+                )
+
+            new_state = keep(new_state, state)
+            # XLA names a fused kernel after its root operation, and the
+            # update's kernels end in these selects: they trace under the
+            # optimizer's scope too, or a device trace would lay the whole
+            # update at the guard's door
+            with jax.named_scope(update_scope):
+                new_params = keep(new_params, params)
+                new_opt_state = keep(new_opt_state, opt_state)
+            metrics["health"] = healthy.astype(jnp.float32)
+            metrics["grad_norm"] = grad_norm
+    return new_params, new_state, new_opt_state, metrics
+
+
 def _train_step_body(
     network: CompiledNetwork,
     optimizer: Optimizer,
@@ -124,25 +165,10 @@ def _train_step_body(
         (cost, (outs, new_state)), grads = jax.value_and_grad(
             loss_fn, has_aux=True
         )(params)
-        new_params, new_opt_state = optimizer.update(grads, opt_state, params)
-        new_params = apply_prune_masks(new_params, prune_masks)
-        metrics = {"cost": cost}
-        if guard:
-            grad_norm = _global_norm(grads)
-            healthy = jnp.isfinite(cost.astype(jnp.float32)) & jnp.isfinite(
-                grad_norm
-            )
-
-            def keep(new, old):
-                return jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(healthy, n, o), new, old
-                )
-
-            new_params = keep(new_params, params)
-            new_state = keep(new_state, state)
-            new_opt_state = keep(new_opt_state, opt_state)
-            metrics["health"] = healthy.astype(jnp.float32)
-            metrics["grad_norm"] = grad_norm
+        new_params, new_state, new_opt_state, metrics = _update_and_guard(
+            optimizer, prune_masks, guard, grads, cost,
+            params, state, new_state, opt_state,
+        )
         if extra_metrics is not None:
             metrics.update(extra_metrics(outs))
         return new_params, new_state, new_opt_state, metrics
@@ -222,23 +248,10 @@ def make_quantized_train_step(
 
     def step(params, state, opt_state, batch, rng):
         grads, cost, new_state, outs = smapped(params, state, batch, rng)
-        new_params, new_opt_state = optimizer.update(grads, opt_state, params)
-        new_params = apply_prune_masks(new_params, prune_masks)
-        metrics = {"cost": cost}
-        if guard:
-            grad_norm = _global_norm(grads)
-            healthy = jnp.isfinite(cost) & jnp.isfinite(grad_norm)
-
-            def keep(new, old):
-                return jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(healthy, n, o), new, old
-                )
-
-            new_params = keep(new_params, params)
-            new_state = keep(new_state, state)
-            new_opt_state = keep(new_opt_state, opt_state)
-            metrics["health"] = healthy.astype(jnp.float32)
-            metrics["grad_norm"] = grad_norm
+        new_params, new_state, new_opt_state, metrics = _update_and_guard(
+            optimizer, prune_masks, guard, grads, cost,
+            params, state, new_state, opt_state,
+        )
         if extra_metrics is not None:
             metrics.update(extra_metrics(outs))
         return new_params, new_state, new_opt_state, metrics

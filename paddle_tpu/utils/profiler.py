@@ -4,8 +4,15 @@
   under ``jax.named_scope("type:name")`` (core/compiler.py), so the
   resulting TensorBoard/Perfetto timeline attributes fused XLA ops back to
   layers — the device-side half of the reference's per-layer
-  REGISTER_TIMER_INFO (NeuralNetwork.cpp:247,288).  Host-side timers live
-  in utils/timers.py, eager per-layer timing in utils/debug.py.
+  REGISTER_TIMER_INFO (NeuralNetwork.cpp:247,288).  The step's tail has
+  scopes of the same form (``optimizer:<method>``, ``guard:sentinel``,
+  trainer/step.py), and ``attgru_core`` marks the decoder recurrence inside
+  its layer.  While the profile is active the trainer loop's five host
+  spans ride the same timeline: ``step`` (one whole iteration) over
+  ``feed_wait``, ``train_step`` (the dispatch) and ``block_fetch`` (the
+  cost's way back) on the trainer thread, and ``feed`` (the staging work)
+  on the prefetch thread.  Host-side timers live in utils/timers.py, eager
+  per-layer timing in utils/debug.py.
 
 * :func:`enable_nan_checks` is the FP-trap equivalent (the reference
   installs SIGFPE handlers / CHECKs on nan paths): jax re-runs any

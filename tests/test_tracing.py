@@ -676,3 +676,285 @@ def test_prometheus_per_class_ledger_series():
     assert samples[
         'paddle_tpu_serving_requests_total{class="p2",status="served"}'
     ] == 1.0
+
+
+# ---------------------------------------------------------------------------
+# step accounting (ISSUE 26): the trainer loop's five spans, the slow-step
+# record, and the scopes of the jitted step.  Counts and structure only: the
+# clock is a private Tracer's, so no timing is asserted.
+# ---------------------------------------------------------------------------
+
+_STEP_CHILDREN = ("feed_wait", "train_step", "block_fetch")
+_N_BATCHES = 6
+
+
+@pytest.fixture()
+def private_tracer(monkeypatch):
+    """trainer/sgd.py emits through ``obs.span`` / ``obs.instant``: point
+    both at a Tracer with a fake clock (1 ms a reading) that a test can
+    push forward."""
+    clock = FakeClock()
+    t = Tracer(clock=clock, ring_events=4096)
+    monkeypatch.setattr(obs, "span", t.span)
+    monkeypatch.setattr(obs, "instant", t.instant)
+    t.clock = clock
+    return t
+
+
+def _tiny_trainer(optimizer=None):
+    import paddle_tpu as paddle
+    from paddle_tpu.core.topology import reset_auto_names
+
+    reset_auto_names()
+    x = paddle.layer.data(name="x", type=paddle.data_type.dense_vector(4))
+    y = paddle.layer.data(name="y", type=paddle.data_type.dense_vector(1))
+    pred = paddle.layer.fc(input=x, size=1, act=paddle.activation.Linear())
+    cost = paddle.layer.square_error_cost(input=pred, label=y)
+    return paddle.trainer.SGD(
+        cost=cost,
+        parameters=paddle.parameters.create(cost, seed=0),
+        update_equation=optimizer or paddle.optimizer.Adam(learning_rate=0.05),
+    )
+
+
+def _tiny_reader(on_batch=None):
+    """_N_BATCHES batches of 4 rows; on_batch(i) runs as batch i is read."""
+    import numpy as np
+
+    import paddle_tpu as paddle
+
+    def samples():
+        rng = np.random.RandomState(0)
+        for i in range(_N_BATCHES * 4):
+            if on_batch is not None and i % 4 == 0:
+                on_batch(i // 4)
+            xv = rng.randn(4).astype(np.float32)
+            yield xv, np.array([xv.sum()], np.float32)
+
+    return paddle.batch(samples, 4)
+
+
+def _spans_by_thread(tracer):
+    """{tid: [(name, begin_us, end_us, args, depth, parent index)]} from the
+    ring's B/E events, in begin order."""
+    out = {}
+    for ev in tracer.events():
+        if ev["ph"] == "M":
+            continue
+        spans, stack = out.setdefault(ev["tid"], ([], []))
+        if ev["ph"] == "B":
+            stack.append(len(spans))
+            spans.append([ev["name"], ev["ts"], None, ev.get("args", {}),
+                          len(stack) - 1, stack[-2] if len(stack) > 1 else None])
+        elif ev["ph"] == "E":
+            top = spans[stack.pop()]
+            assert top[0] == ev["name"]  # properly nested
+            top[2] = ev["ts"]
+    assert all(not stack for _, stack in out.values())
+    return {tid: spans for tid, (spans, _) in out.items()}
+
+
+@pytest.mark.parametrize("async_load_data", [True, False])
+def test_step_span_partitions_every_iteration(private_tracer, async_load_data):
+    import paddle_tpu as paddle
+
+    ended = []
+    trainer = _tiny_trainer()
+    trainer.train(
+        _tiny_reader(), num_passes=1, async_load_data=async_load_data,
+        event_handler=lambda e: ended.append(e.batch_id)
+        if isinstance(e, paddle.event.EndIteration) else None,
+    )
+    assert ended == list(range(_N_BATCHES))
+    by_thread = _spans_by_thread(private_tracer)
+    loop = next(s for s in by_thread.values() if any(x[0] == "step" for x in s))
+    steps = [i for i, s in enumerate(loop) if s[0] == "step"]
+    # one `step` per iteration, top level, back to back in batch order; the
+    # last is the iteration that found the pass exhausted (a feed_wait alone)
+    assert [loop[i][3]["b"] for i in steps] == list(range(_N_BATCHES + 1))
+    assert all(loop[i][4] == 0 and loop[i][3]["p"] == 0 for i in steps)
+    for i in steps:
+        name, t0, t1, args, _, _ = loop[i]
+        kids = [s for s in loop if s[5] == i]
+        if args["b"] == _N_BATCHES:
+            assert [k[0] for k in kids] == ["feed_wait"]
+            continue
+        assert tuple(k[0] for k in kids) == _STEP_CHILDREN
+        assert all(k[3]["b"] == args["b"] for k in kids)  # one step, one b
+        assert kids[1][3]["p"] == args["p"]
+        # children lie inside the parent, in order, without overlap: what
+        # they leave uncovered is the step's self time, and the four add up
+        edges = [t0] + [t for k in kids for t in (k[1], k[2])] + [t1]
+        assert edges == sorted(edges)
+        covered = sum(k[2] - k[1] for k in kids)
+        self_us = sum(b - a for a, b in zip(edges[::2], edges[1::2]))
+        assert covered + self_us == pytest.approx(t1 - t0)
+        assert self_us > 0  # handlers and judge_step ran under no child
+    # the staging work: inside feed_wait on the trainer thread when the feed
+    # is synchronous, on the prefetch thread (no child of step) when not
+    feeds = [(tid, s) for tid, spans in by_thread.items() for s in spans if s[0] == "feed"]
+    assert len(feeds) == _N_BATCHES
+    for tid, s in feeds:
+        if async_load_data:
+            assert by_thread[tid] is not loop and s[5] is None
+        else:
+            assert by_thread[tid] is loop and loop[s[5]][0] == "feed_wait"
+
+
+@pytest.mark.parametrize("phase", ["feed_wait_ms", "self_ms"])
+def test_slow_step_emits_one_record_with_its_phases(private_tracer, caplog, phase):
+    import paddle_tpu as paddle
+    from paddle_tpu.utils.timers import global_stats
+
+    clock = private_tracer.clock
+    slow_batch = 4
+
+    def stall(i):
+        if i == slow_batch:
+            clock.t += 0.5  # half a second, inside whatever span is open
+
+    def handler(e):
+        if phase == "self_ms" and isinstance(e, paddle.event.EndIteration):
+            stall(e.batch_id)
+
+    before = global_stats.count("slow_steps")
+    with caplog.at_level("WARNING", logger="paddle_tpu.trainer"):
+        _tiny_trainer().train(
+            _tiny_reader(stall if phase == "feed_wait_ms" else None),
+            num_passes=1, async_load_data=False, event_handler=handler,
+        )
+    slow = [e for e in private_tracer.events() if e["name"] == "slow_step"]
+    assert len(slow) == 1 and slow[0]["ph"] == "i" and slow[0]["cat"] == "trainer"
+    args = slow[0]["args"]
+    assert set(args) == {"p", "b", "ms", "feed_wait_ms", "dispatch_ms", "fetch_ms", "self_ms"}
+    assert (args["p"], args["b"]) == (0, slow_batch)
+    parts = ("feed_wait_ms", "dispatch_ms", "fetch_ms", "self_ms")
+    assert sum(args[k] for k in parts) == pytest.approx(args["ms"])
+    assert args[phase] >= 500 and args["ms"] - args[phase] < 50  # the phase that held it
+    assert global_stats.count("slow_steps") == before + 1
+    lines = [r.getMessage() for r in caplog.records if "slow_step" in r.getMessage()]
+    assert len(lines) == 1 and f"batch {slow_batch}" in lines[0]
+
+
+def test_steady_run_emits_no_slow_step(private_tracer):
+    from paddle_tpu.utils.timers import global_stats
+
+    before = global_stats.count("slow_steps")
+    trainer = _tiny_trainer()
+    trainer.train(_tiny_reader(), num_passes=2)
+    assert not [e for e in private_tracer.events() if e["name"] == "slow_step"]
+    assert global_stats.count("slow_steps") == before
+    assert len(trainer._step_ms) == 2 * _N_BATCHES  # every step joined the history
+
+
+def test_slow_step_needs_the_recorder(private_tracer):
+    """Disarmed, the spans read no clock: nothing is judged or counted."""
+    private_tracer.set_recording(False)
+    trainer = _tiny_trainer()
+    trainer.train(_tiny_reader(), num_passes=1)
+    assert not trainer._step_ms
+    assert [e for e in private_tracer.events() if e["ph"] != "M"] == []
+
+
+def _scope_names(lowered):
+    import re
+
+    return set(re.findall(r'loc\("([^"]+)"', lowered.as_text(debug_info=True)))
+
+
+def _bare(names):
+    """The operations of the step that run outside every type:name scope of
+    the program (trace_reduce.SCOPE's form)."""
+    import re
+
+    return [n for n in names if n.startswith("jit(step)/")
+            and not re.search(r"[A-Za-z_0-9]+:[A-Za-z_0-9.@]+", n)]
+
+
+def test_jitted_step_runs_under_optimizer_guard_and_data_scopes():
+    import jax
+    import numpy as np
+
+    trainer = _tiny_trainer()
+    feeder = trainer._make_feeder(None)
+    batch = feeder([(np.ones(4, np.float32), np.ones(1, np.float32))] * 4)
+    # a dense slot on the narrow-dtype wire: the feed transform has work to do
+    batch["x"] = type(batch["x"])(
+        np.asarray(batch["x"].data, np.uint8), batch["x"].lengths)
+    p = trainer.parameters
+    names = _scope_names(trainer._train_step.lower(
+        p.params, p.state, trainer._opt_state, batch, jax.random.PRNGKey(0)))
+    for scope in ("optimizer:adam/", "guard:sentinel/", "data:x)", "fc:", "square_error:"):
+        assert any(scope in n for n in names), scope
+    # the selects that skip a bad step are the roots of the update's fused
+    # kernels: they carry the optimizer's scope inside the guard's
+    assert any("guard:sentinel/optimizer:adam/" in n for n in names)
+    assert not _bare(names)
+
+
+def test_classification_error_metric_runs_under_an_evaluator_scope():
+    """The default classification-error metric (an argmax over the whole
+    vocabulary) is device time too: it has a scope, and with it the whole
+    seq2seq step has no operation outside one."""
+    import jax
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.core.topology import reset_auto_names
+    from paddle_tpu.models.seq2seq import seq2seq_cost
+
+    reset_auto_names()
+    cost, _ = seq2seq_cost(13, 13, word_dim=5, hidden_dim=4)
+    trainer = paddle.trainer.SGD(
+        cost=cost, parameters=paddle.parameters.create(cost, seed=0),
+        update_equation=paddle.optimizer.Adam(learning_rate=1e-3))
+    rng = np.random.RandomState(0)
+    rows = [tuple(list(rng.randint(2, 13, n)) for n in (4, 5, 5)) for _ in range(4)]
+    batch = trainer._make_feeder({"src_word": 0, "trg_word": 1, "trg_next": 2})(rows)
+    p = trainer.parameters
+    names = _scope_names(trainer._train_step.lower(
+        p.params, p.state, trainer._opt_state, batch, jax.random.PRNGKey(0)))
+    assert any("evaluator:classification_error." in n for n in names)
+    assert any("/attgru_core/" in n for n in names)
+    assert not _bare(names)
+
+
+def test_attention_gru_core_scope_in_forward_and_backward():
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import paddle_tpu as paddle  # noqa: F401  (registers layers)
+    from paddle_tpu.core.batch import SeqTensor
+    from paddle_tpu.core.compiler import CompiledNetwork
+    from paddle_tpu.core.topology import Topology, reset_auto_names
+    from paddle_tpu.models.seq2seq import seq2seq_cost
+
+    reset_auto_names()
+    cost, _ = seq2seq_cost(13, 13, word_dim=5, hidden_dim=4)
+    net = CompiledNetwork(Topology([cost]), compute_dtype=jnp.bfloat16)
+    params, state = net.init(jax.random.PRNGKey(0))
+    rng = np.random.RandomState(0)
+    lens = jnp.asarray(rng.randint(2, 7, 4), jnp.int32)
+    batch = {k: SeqTensor(jnp.asarray(rng.randint(2, 13, (4, 6)), jnp.int32), lens)
+             for k in ("src_word", "trg_word", "trg_next")}
+
+    def loss(p):
+        return net.cost(p, batch, state=state, train=True)[0]
+
+    names = _scope_names(jax.jit(jax.grad(loss)).lower(params))
+    core = [n for n in names if "/attgru_core/" in n]
+    fwd = [n for n in core if "transpose(" not in n]
+    bwd = [n for n in core if "transpose(" in n]
+    assert fwd and bwd
+    # under the decoder's layer scope, and itself no type:name scope, so the
+    # layer stays the operations' innermost one
+    assert all("recurrent_group:decoder" in n.split("/attgru_core/")[0] for n in core)
+    # mixed precision's casts of a layer's weights sit BESIDE the layer's
+    # scope (cast:<layer>), not inside it: the readers that time a layer by
+    # its scope (attention_roofline matches the scope anywhere in the name)
+    # read the layer alone
+    casts = [n for n in names if "cast:" in n]
+    assert any("cast:decoder" in n for n in casts)
+    assert any("cast:enc_fw" in n and "transpose(" in n for n in casts)
+    assert not [n for n in casts if "recurrent_group:" in n or "gru:" in n or "fc:" in n]
