@@ -31,6 +31,7 @@ from paddle_tpu.core.batch import SeqTensor
 from paddle_tpu.core.topology import LayerConf, LayerOutput, Topology, auto_name
 from paddle_tpu.layers.base import ApplyContext, register_layer
 from paddle_tpu.ops import acc_einsum
+from paddle_tpu.parallel.mesh import DATA_AXIS
 
 
 class StaticInput:
@@ -474,9 +475,11 @@ def recurrent_group_apply(conf, params, inputs, ctx: ApplyContext) -> SeqTensor:
     t_iota = jnp.arange(t_max, dtype=jnp.uint32)
 
     # Epilogue hoisting: the maximal rowwise SUFFIX of the step graph that
-    # no memory depends on runs ONCE on the stacked [T*B] sequence instead
-    # of per scan step.  The canonical win is a per-step vocab projection
-    # (seq2seq dec_out: 50 latency-bound [B,512]x[512,30000] GEMMs + a
+    # no memory depends on runs ONCE on the stacked sequence, its [T, B]
+    # folded into T*B rows (_HoistRows: time-major, or shard-major under a
+    # data mesh so the rows stay sharded as B was), instead of per scan
+    # step.  The canonical win is a per-step vocab projection (seq2seq
+    # dec_out: 50 latency-bound [B,512]x[512,30000] GEMMs + a
     # [512,30000] grad accumulator carried through every backward step
     # become one batched GEMM) — the generalization of keeping input
     # projections outside the cell scans, and the TPU analogue of the
@@ -484,6 +487,7 @@ def recurrent_group_apply(conf, params, inputs, ctx: ApplyContext) -> SeqTensor:
     # Disabled for nested inputs and sequence-valued memories, whose step
     # outputs are not plain [B, D] rows.
     # both hoists assume plain [B, D] per-step rows and non-seq carries
+    rows = _HoistRows(t_max, b, ctx.mesh)
     rows_hoistable = not any(sub_scanned) and not any(
         m.attrs.get("is_seq") for m in memories
     )
@@ -499,7 +503,7 @@ def recurrent_group_apply(conf, params, inputs, ctx: ApplyContext) -> SeqTensor:
         # validate by a ONE-step abstract eval (shapes only) that every
         # frontier value really is a plain [B, D] row — a loop layer can
         # emit a sequence (expand over a static, sub-seq transforms) whose
-        # stacked form must not be time-flattened
+        # stacked form must not be folded into rows
         probe = dict(static_batch)
         for pname, x in zip(scan_names, xs):
             probe[pname] = jax.tree_util.tree_map(lambda v: v[0], x)
@@ -529,7 +533,8 @@ def recurrent_group_apply(conf, params, inputs, ctx: ApplyContext) -> SeqTensor:
     # Prologue hoisting (the prefix complement): rowwise layers fed only by
     # scanned/static placeholders — in-step input projections like
     # gru_unit/lstmemory_group's mixed 3H/4H GEMMs — compute once on the
-    # time-flattened inputs; the body reads their per-step slices.
+    # inputs folded into rows (_HoistRows); the body reads their per-step
+    # slices.
     _pro_producer = _producer_resolver(sub_topo.layers)
     prologue = set()
     if rows_hoistable:
@@ -542,13 +547,11 @@ def recurrent_group_apply(conf, params, inputs, ctx: ApplyContext) -> SeqTensor:
         pre_preset = {}
         for pname, x in zip(scan_names, xs):
             d = x.data  # [T, B, ...] (already flipped for reverse groups)
-            pre_preset[pname] = SeqTensor(
-                d.reshape((t_max * b,) + d.shape[2:])
-            )
+            pre_preset[pname] = SeqTensor(rows.fold(d))
         for (pname, is_seq) in static_info:
             if not is_seq:
                 pre_preset[pname] = SeqTensor(
-                    _tile_rows(static_batch[pname].data, t_max)
+                    rows.tile(static_batch[pname].data)
                 )
         pro_outs, _ = subnet.apply(
             params, {}, state=sub_state0, train=ctx.train, rng=None,
@@ -566,7 +569,7 @@ def recurrent_group_apply(conf, params, inputs, ctx: ApplyContext) -> SeqTensor:
     )
     # static frontier inputs are step-invariant (tiled into the epilogue
     # preset directly); prologue-produced frontier values are already
-    # available time-flattened — the scan emits neither
+    # available as rows — the scan emits neither
     frontier_scan = tuple(
         n for n in frontier
         if epilogue is None
@@ -576,10 +579,7 @@ def recurrent_group_apply(conf, params, inputs, ctx: ApplyContext) -> SeqTensor:
             and _pro_producer(n) not in prologue
         )
     )
-    pro_stacked = tuple(
-        pro_outs[n].data.reshape((t_max, b) + pro_outs[n].data.shape[1:])
-        for n in pro_sliced
-    )
+    pro_stacked = tuple(rows.unfold(pro_outs[n].data) for n in pro_sliced)
 
     # Fused attention-GRU lowering: when the whole remaining loop body IS
     # the v1 attention-decoder idiom (layers/attention.py
@@ -703,68 +703,54 @@ def recurrent_group_apply(conf, params, inputs, ctx: ApplyContext) -> SeqTensor:
         if sub_state0:
             ctx.new_state[conf.name] = sub_state_out
 
-    group_logits = None
     if epilogue is not None:
-        # run the hoisted suffix once over the whole stacked sequence,
-        # time flattened into the batch (rowwise layers only, so [T*B]
-        # rows are independent)
+        # run the hoisted suffix once over the whole stacked sequence, time
+        # folded into the batch in _HoistRows' order (rowwise layers only,
+        # so the [T*B] rows are independent)
         preset = {}
         for n, st in zip(frontier_scan, ys_stacked):
-            d = st.data  # [T, B, ...]
-            preset[n] = SeqTensor(d.reshape((t_max * b,) + d.shape[2:]))
+            preset[n] = SeqTensor(rows.fold(st.data))
         for n in frontier:
             if n in preset:
                 continue
             if _pro_producer(n) in prologue:
-                preset[n] = pro_outs[n]  # already time-flattened
+                preset[n] = pro_outs[n]  # already rows, in the same order
             elif n in scan_names:
                 # the scan input itself: already held time-major in xs
-                d = xs[scan_names.index(n)].data
                 preset[n] = SeqTensor(
-                    d.reshape((t_max * b,) + d.shape[2:])
+                    rows.fold(xs[scan_names.index(n)].data)
                 )
             else:  # step-invariant static: broadcast per step, don't stack
-                preset[n] = SeqTensor(
-                    _tile_rows(static_batch[n].data, t_max)
-                )
+                preset[n] = SeqTensor(rows.tile(static_batch[n].data))
         epi_outs, _ = subnet.apply(
             params, {}, state=sub_state0, train=ctx.train, rng=None,
             only=epilogue, preset=preset,
         )
-        eo = epi_outs[out_name]
-        ys = SeqTensor(
-            eo.data.reshape((t_max, b) + eo.data.shape[1:])
-        )
+        ys = rows.unfold_batch_major(epi_outs[out_name].data, reverse)
         lg = epi_outs.get(out_name + "@logits")
         if lg is not None:
-            group_logits = lg.data.reshape(
-                (t_max, b) + lg.data.shape[1:]
+            # expose the hoisted softmax's pre-activation at the GROUP level
+            # so a downstream cross_entropy fuses into log-softmax CE and
+            # the [B, T, vocab] probabilities dead-code-eliminate entirely
+            ctx.outputs[conf.name + "@logits"] = SeqTensor(
+                rows.unfold_batch_major(lg.data, reverse), lengths
             )
     else:
         ys = ys_stacked[0]
-    if ys.lengths is not None:
-        # step emitted sequences -> nested [B, S, T, ...] output
-        data, sub_len = ys.data, ys.lengths
+        if ys.lengths is not None:
+            # step emitted sequences -> nested [B, S, T, ...] output
+            data, sub_len = ys.data, ys.lengths
+            if reverse:
+                data = jnp.flip(data, axis=0)
+                sub_len = jnp.flip(sub_len, axis=0)
+            data = jnp.swapaxes(data, 0, 1)  # [B, S, T, ...]
+            out = SeqTensor(data, lengths, jnp.swapaxes(sub_len, 0, 1))
+            return out.with_data(out.masked_data())
+        ys = ys.data
         if reverse:
-            data = jnp.flip(data, axis=0)
-            sub_len = jnp.flip(sub_len, axis=0)
-        data = jnp.swapaxes(data, 0, 1)  # [B, S, T, ...]
-        out = SeqTensor(data, lengths, jnp.swapaxes(sub_len, 0, 1))
-        return out.with_data(out.masked_data())
-    ys = ys.data
-    if reverse:
-        ys = jnp.flip(ys, axis=0)
-    ys = jnp.swapaxes(ys, 0, 1)  # [B, T, D]
+            ys = jnp.flip(ys, axis=0)
+        ys = jnp.swapaxes(ys, 0, 1)  # [B, T, D]
     ys = ys * mask_like(ys, lengths)
-    if group_logits is not None:
-        # expose the hoisted softmax's pre-activation at the GROUP level so
-        # a downstream cross_entropy fuses into log-softmax CE and the
-        # [B, T, vocab] probabilities dead-code-eliminate entirely
-        if reverse:
-            group_logits = jnp.flip(group_logits, axis=0)
-        ctx.outputs[conf.name + "@logits"] = SeqTensor(
-            jnp.swapaxes(group_logits, 0, 1), lengths
-        )
     return SeqTensor(ys, lengths)
 
 
@@ -914,9 +900,10 @@ def _split_prologue(sub_topo, scan_names, static_info, epilogue):
     memory) compute identically at every scan step offset — the classic
     in-step input projection (gru_unit/lstmemory_group's mixed 3H/4H
     projections; reference SequenceToBatch feeds pre-projected frames).
-    They run ONCE on the time-flattened inputs before the scan; the body
-    receives their per-step slices as extra scan inputs.  Returns the set
-    of hoisted names (possibly empty)."""
+    They run ONCE before the scan on the inputs folded into T*B rows
+    (_HoistRows gives the row order); the body receives their per-step
+    slices as extra scan inputs.  Returns the set of hoisted names (possibly
+    empty)."""
     from paddle_tpu.layers.base import get_layer_impl
 
     layers = sub_topo.layers
@@ -946,11 +933,11 @@ def _split_epilogue(sub_topo, memories, out_name, static_seq):
 
     Returns (epilogue_names, frontier_names): `epilogue` is the maximal
     suffix reaching `out_name` whose layers are rowwise (independent per
-    [B] row, so time can fold into batch), stateless, dropout-free, and
-    not ancestors of any memory link; `frontier` is every non-epilogue
-    name the epilogue reads (loop layers, memory/step placeholders) —
-    the scan body emits exactly these.  (None, (out_name,)) when nothing
-    hoists."""
+    [B] row, so time can fold into batch, in _HoistRows' order), stateless,
+    dropout-free, and not ancestors of any memory link; `frontier` is every
+    non-epilogue name the epilogue reads (loop layers, memory/step
+    placeholders) — the scan body emits exactly these.  (None, (out_name,))
+    when nothing hoists."""
     from paddle_tpu.layers.base import get_layer_impl
 
     layers = sub_topo.layers
@@ -1083,16 +1070,80 @@ def _seq_memory_widths(
     )
 
 
-def _tile_rows(d: jnp.ndarray, t: int) -> jnp.ndarray:
-    """Step-invariant [B, ...] value expanded to the time-flattened
-    [t*B, ...] preset rows of the hoisted prologue/epilogue.  broadcast_to +
-    reshape instead of jnp.tile: XLA keeps the T× expansion a broadcast
-    fused into the consumer rather than a materialized copy (a wide static
-    — e.g. an encoder summary feeding the hoisted suffix — would otherwise
-    cost T× its footprint in HBM)."""
-    return jnp.broadcast_to(d[None], (t,) + d.shape).reshape(
-        (t * d.shape[0],) + d.shape[1:]
-    )
+class _HoistRows:
+    """Row order of the hoisted prologue/epilogue: how a stacked [T, B, ...]
+    value folds into the [T*B, ...] rows the rowwise layers run on, and back.
+
+    Without a data mesh the rows are time-major (row t*B + b): a reshape.
+    Under a mesh the batch axis is split over the ``data`` axis in n blocks
+    of B/n rows, and a time-major merge interleaves the chips' rows T times:
+    no sharding of the merged axis says "every B/n-th block of each
+    T-slice", so XLA's partitioner gathers the whole batch onto every chip
+    first and each chip runs the hoisted layers on all B rows.  Where n > 1
+    divides B the rows are therefore shard-major, time-major inside a shard
+    (row (s*T + t)*B/n + b'): a chip's rows are one contiguous block, the
+    merged axis is sharded as B was, and each chip runs the one-chip program
+    on its own B/n rows.  n = 1 (no mesh, a data axis of one, or a B that n
+    does not divide) is exactly the time-major reshape."""
+
+    def __init__(self, t: int, b: int, mesh) -> None:
+        n = 1 if mesh is None else mesh.shape.get(DATA_AXIS, 1)
+        self.t, self.b = t, b
+        self.n = n if b % n == 0 else 1
+
+    def fold(self, d: jnp.ndarray) -> jnp.ndarray:
+        """Stacked [T, B, ...] -> rows [T*B, ...]."""
+        t, b, n = self.t, self.b, self.n
+        rest = d.shape[2:]
+        if n > 1:
+            d = jnp.swapaxes(d.reshape((t, n, b // n) + rest), 0, 1)
+        return d.reshape((t * b,) + rest)
+
+    def tile(self, d: jnp.ndarray) -> jnp.ndarray:
+        """Step-invariant [B, ...] -> rows [T*B, ...], each step's copy of a
+        row where fold puts that step.  broadcast_to + reshape instead of
+        jnp.tile: XLA keeps the T-fold expansion a broadcast fused into the
+        consumer rather than a materialized copy (a wide static, e.g. an
+        encoder summary feeding the hoisted suffix, would otherwise cost T
+        times its footprint in HBM)."""
+        t, b, n = self.t, self.b, self.n
+        rest = d.shape[1:]
+        if n > 1:
+            d = jnp.broadcast_to(
+                d.reshape((n, 1, b // n) + rest), (n, t, b // n) + rest
+            )
+        else:
+            d = jnp.broadcast_to(d[None], (t, b) + rest)
+        return d.reshape((t * b,) + rest)
+
+    def unfold(self, r: jnp.ndarray) -> jnp.ndarray:
+        """Rows [T*B, ...] -> stacked [T, B, ...] (fold's inverse), the form
+        the scan slices per step."""
+        t, b, n = self.t, self.b, self.n
+        rest = r.shape[1:]
+        if n > 1:
+            r = jnp.swapaxes(r.reshape((n, t, b // n) + rest), 0, 1)
+        return r.reshape((t, b) + rest)
+
+    def unfold_batch_major(self, r: jnp.ndarray, reverse: bool) -> jnp.ndarray:
+        """Rows [T*B, ...] -> the group's [B, T, ...] output, time put back
+        in order for a reverse group.  Under a mesh this is ONE transpose
+        inside each shard, never unfold() and a second swap: with the two
+        chained XLA:TPU hoisted the cross-entropy's float32 upcast of the
+        logits into the GEMM and laid a float32 copy of them out as well
+        (benchmark/aot.py nmt-train-dp4: 9.15 GiB of temporaries against
+        4.38)."""
+        t, b, n = self.t, self.b, self.n
+        if n == 1:
+            r = self.unfold(r)
+            if reverse:
+                r = jnp.flip(r, axis=0)
+            return jnp.swapaxes(r, 0, 1)
+        rest = r.shape[1:]
+        r = r.reshape((n, t, b // n) + rest)
+        if reverse:
+            r = jnp.flip(r, axis=1)
+        return jnp.swapaxes(r, 1, 2).reshape((b, t) + rest)
 
 
 def mask_like(ys: jnp.ndarray, lengths: jnp.ndarray) -> jnp.ndarray:

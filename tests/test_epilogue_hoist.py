@@ -302,3 +302,153 @@ def test_epilogue_reads_step_input_directly():
     )
     assert epi == {"head5"}
     assert "g5@in0" in frontier
+
+
+# ---------------------------------------------------------------------------
+# Row order under a data mesh (rg._HoistRows): hoisted rows are shard-major
+# where the mesh's `data` axis divides B, so the values must not depend on
+# the mesh at all.
+# ---------------------------------------------------------------------------
+
+MESHES = [None, 2, 4]
+
+
+def _mesh(n):
+    from paddle_tpu.parallel.mesh import make_mesh
+
+    return None if n is None else make_mesh(data=n, devices=jax.devices()[:n])
+
+
+@pytest.mark.parametrize("n", [1, 2, 4])
+@pytest.mark.parametrize("reverse", [False, True])
+def test_hoist_rows_orders_agree(n, reverse):
+    """fold / tile / unfold / unfold_batch_major describe ONE row order:
+    whatever it is, a value folded and unfolded comes back where it was."""
+    t, b = 5, 8
+    rows = rg._HoistRows(t, b, _mesh(n))
+    assert rows.n == n
+    x = jnp.arange(t * b * 3, dtype=jnp.float32).reshape(t, b, 3)
+    r = rows.fold(x)
+    assert r.shape == (t * b, 3)
+    if n == 1:  # time-major, as without a mesh
+        np.testing.assert_array_equal(r, x.reshape(t * b, 3))
+    else:  # a shard's rows are one contiguous block
+        np.testing.assert_array_equal(
+            r[: t * b // n], x[:, : b // n].reshape(t * b // n, 3)
+        )
+    np.testing.assert_array_equal(rows.unfold(r), x)
+    want = jnp.swapaxes(jnp.flip(x, axis=0) if reverse else x, 0, 1)
+    np.testing.assert_array_equal(rows.unfold_batch_major(r, reverse), want)
+    s = jnp.arange(b * 2, dtype=jnp.float32).reshape(b, 2)
+    np.testing.assert_array_equal(
+        rows.unfold(rows.tile(s)), jnp.broadcast_to(s[None], (t, b, 2))
+    )
+
+
+def _mesh_group(reverse=False, static=False, vocab=29):
+    """Decoder-shaped group with BOTH hoists: an input projection (prologue),
+    a recurrence, a vocab head (epilogue) that can also read a non-sequence
+    static through the rows' tile()."""
+    reset_auto_names()
+    paddle.init(seed=21)
+    x = L.data("x", paddle.data_type.integer_value_sequence(vocab))
+    emb = L.embedding(x, size=12)
+    ins = [emb]
+    if static:
+        summary = L.pooling(input=emb, pooling_type=paddle.pooling.Avg())
+        ins.append(rg.StaticInput(summary))
+
+    def step(e_t, *stat):
+        proj = L.fc(e_t, size=10, act=A.Identity(), name="mg_proj")
+        state = L.memory("mg_st", 10)
+        h = L.fc([proj, state], size=10, act=A.Tanh(), name="mg_st")
+        return L.fc([h, *stat], size=vocab, act=A.Softmax(), name="mg_head")
+
+    dec = L.recurrent_group(step, input=ins, reverse=reverse, name="mg")
+    lab = L.data("y", paddle.data_type.integer_value_sequence(vocab))
+    return L.classification_cost(input=dec, label=lab)
+
+
+def _mesh_batch(b=8, t=6, vocab=29):
+    rng = np.random.RandomState(3)
+    lens = jnp.asarray(rng.randint(1, t + 1, b), jnp.int32).at[0].set(t)
+    ids = lambda: jnp.asarray(rng.randint(0, vocab, (b, t)), jnp.int32)  # noqa: E731
+    return {"x": SeqTensor(ids(), lens), "y": SeqTensor(ids(), lens)}
+
+
+def _value_grads_outs(cost, batch, n):
+    """Cost, gradients and layer outputs of `cost` with the network on an
+    n-way data mesh (None: no mesh), the batch sharded as the trainer's."""
+    from paddle_tpu.parallel.mesh import shard_batch
+
+    net = CompiledNetwork(Topology([cost]))
+    params, state = net.init(jax.random.PRNGKey(0))
+    net.mesh = _mesh(n)
+    if batch["x"].batch_size % (n or 1) == 0:
+        batch = shard_batch(batch, net.mesh)
+
+    def loss(p):
+        return net.cost(p, batch, state=state, rng=None, train=True)[0]
+
+    v, g = jax.jit(jax.value_and_grad(loss))(params)
+    outs, _ = jax.jit(
+        lambda p: net.apply(p, batch, state=state, train=True)
+    )(params)
+    return v, g, outs
+
+
+def _assert_same(got, want):
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for a, b in zip(
+        jax.tree_util.tree_leaves(got[1]), jax.tree_util.tree_leaves(want[1])
+    ):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("n", MESHES)
+@pytest.mark.parametrize("reverse", [False, True], ids=["forward", "reverse"])
+@pytest.mark.parametrize("static", [False, True], ids=["plain", "static"])
+def test_meshed_hoists_match_unhoisted(monkeypatch, n, reverse, static):
+    """Both hoists on an n-way data mesh against the per-step scan with no
+    mesh: forward and reverse groups, and a static (non-sequence) frontier
+    input tiled into the rows."""
+    batch = _mesh_batch()
+    got = _value_grads_outs(_mesh_group(reverse, static), batch, n)
+    monkeypatch.setattr(rg, "_split_epilogue", lambda *a, **k: (None, (a[2],)))
+    monkeypatch.setattr(rg, "_split_prologue", lambda *a, **k: set())
+    want = _value_grads_outs(_mesh_group(reverse, static), batch, None)
+    _assert_same(got, want)
+    np.testing.assert_allclose(
+        got[2]["mg"].data, want[2]["mg"].data, rtol=1e-5, atol=1e-6
+    )
+
+
+@pytest.mark.parametrize("n", MESHES)
+def test_meshed_group_exposes_batch_major_logits(n):
+    batch = _mesh_batch()
+    _, _, outs = _value_grads_outs(_mesh_group(), batch, n)
+    lg = outs["mg@logits"]
+    assert lg.data.shape == outs["mg"].data.shape == (8, 6, 29)
+    np.testing.assert_array_equal(lg.lengths, batch["x"].lengths)
+    valid = np.asarray(batch["x"].mask(bool))
+    np.testing.assert_allclose(
+        np.asarray(jax.nn.softmax(lg.data, axis=-1))[valid],
+        np.asarray(outs["mg"].data)[valid],
+        atol=1e-5,
+    )
+    _, _, plain = _value_grads_outs(_mesh_group(), batch, None)
+    np.testing.assert_allclose(
+        lg.data, plain["mg@logits"].data, rtol=1e-5, atol=1e-6
+    )
+
+
+@pytest.mark.parametrize("n", [2, 4])
+def test_indivisible_batch_falls_back_to_time_major(n):
+    """B=6 over 4 shards (or 3 over 2) cannot be split: the rows stay
+    time-major (gathered under a real sharding, and correct)."""
+    b = 6 if n == 4 else 3
+    assert rg._HoistRows(6, b, _mesh(n)).n == 1
+    batch = _mesh_batch(b=b)
+    got = _value_grads_outs(_mesh_group(), batch, n)
+    want = _value_grads_outs(_mesh_group(), batch, None)
+    _assert_same(got, want)
