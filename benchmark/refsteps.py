@@ -72,7 +72,15 @@ def _rows(batch, lo, hi):
 
 
 class ReferenceRun:
-    """Follows `steps` Adam steps of the reference on the given batches.
+    """Follows the reference through one Adam step for each of the batches.
+
+    What it holds on the device, in float32 copies of the parameter set: `w`,
+    the gradient that is being summed, and one transient (a block's gradient
+    before it is added; one leaf's moments during the update), plus one
+    block's activations.  Adam's `m` and `v` wait on the host while a
+    gradient is computed, the first step's gradient goes there as the
+    program's did, and the weights before the first step are not kept: they
+    are drawn again for the change.
 
     fault: None, or one of the faults a training cell can have, planted in
     the reference put in the program's place:
@@ -81,74 +89,106 @@ class ReferenceRun:
       "no_exchange"  (cells over several chips) the first chip's shard of
                      every batch alone, as a step without its all-reduce
                      leaves each chip
+
+    watch: called with no argument where the most is held (a block's gradient
+    just computed, a leaf just updated), for whoever counts the bytes.
     """
 
     def __init__(self, block_cost, opt, block_rows, precision="float32",
-                 fault=None, chips=1):
+                 fault=None, chips=1, cell="", watch=None):
         mm = MATMULS[precision]
         self._grad = jax.jit(jax.value_and_grad(
             lambda w, blk: block_cost(w, blk, mm)))
-        self._add = jax.jit(lambda a, b: jax.tree_util.tree_map(jnp.add, a, b))
+        self._add = jax.jit(lambda a, b: jax.tree_util.tree_map(jnp.add, a, b),
+                            donate_argnums=0)
         self._block_rows = block_rows
         self._fault = fault
         self._chips = chips
+        self._cell = cell
+        self._watch = watch or (lambda: None)
         b1, b2, eps, lr = (opt["beta1"], opt["beta2"], opt["epsilon"],
                            opt["learning_rate"])
 
-        def adam(w, m, v, g, t, inv_rows):
-            def leaf(w, m, v, g):
-                g = g * inv_rows
-                m = b1 * m + (1 - b1) * g
-                v = b2 * v + (1 - b2) * jnp.square(g)
-                w = w - lr * (m / (1 - b1 ** t)) / (jnp.sqrt(v / (1 - b2 ** t)) + eps)
-                return w, m, v, g
-            out = {k: leaf(w[k], m[k], v[k], g[k]) for k in w}
-            return tuple({k: o[i] for k, o in out.items()} for i in range(4))
+        def adam_leaf(w, m, v, g, t, inv_rows):
+            g = g * inv_rows
+            m = b1 * m + (1 - b1) * g
+            v = b2 * v + (1 - b2) * jnp.square(g)
+            w = w - lr * (m / (1 - b1 ** t)) / (jnp.sqrt(v / (1 - b2 ** t)) + eps)
+            return w, m, v, g
 
-        self._adam = jax.jit(adam, donate_argnums=(0, 1, 2))
+        self._adam_leaf = jax.jit(adam_leaf, donate_argnums=(0, 1, 2, 3))
 
     def _batch_grad(self, w, batch):
-        n = len(next(iter(batch.values())))
+        n = rows = len(next(iter(batch.values())))
         if self._fault == "half_batch":
             n //= 2
         elif self._fault == "no_exchange":
             n //= self._chips
+        if n == 0:
+            raise ValueError(f"{self._cell}: the fault {self._fault} leaves no row "
+                             f"of this mix's batch of {rows}")
         r = min(self._block_rows, n)
         if n % r:
-            raise ValueError(f"{n} rows do not divide into blocks of {r}")
+            raise ValueError(f"{self._cell}: {n} rows do not divide into blocks of {r}")
         total, grads = 0.0, None
         for lo in range(0, n, r):
             blk = {k: jnp.asarray(v) for k, v in _rows(batch, lo, lo + r).items()}
             c, g = self._grad(w, blk)
             total += float(c)
+            self._watch()
             grads = g if grads is None else self._add(grads, g)
+            del g  # or the next block's gradient would find this one still held
         return total / n, grads, n
 
-    def run(self, weights, batches):
-        """-> dict: losses [steps], grad (first step, per leaf, on the
-        device) with its grad_norms, change_norms (after the last step)."""
-        w0 = weights
-        w = {k: jnp.copy(x) for k, x in weights.items()}
-        m = {k: jnp.zeros_like(x) for k, x in w.items()}
-        v = {k: jnp.zeros_like(x) for k, x in w.items()}
-        losses, grad = [], None
+    def _update(self, w, m, v, g, t, n, first):
+        """Adam on every leaf in turn, in place in w, m, v; g is used up.
+        With `first`, the gradient as Adam got it goes there: name -> (its
+        norm, the leaf on the host)."""
+        for k in sorted(w):
+            w[k], mk, vk, gk = self._adam_leaf(w[k], m[k], v[k], g.pop(k), float(t), 1.0 / n)
+            self._watch()
+            # np.array copies: on the CPU np.asarray would be a view that
+            # keeps the device's buffer
+            m[k], v[k] = np.array(mk), np.array(vk)
+            if first is not None:
+                first[k] = (leaf_norm(gk), np.array(gk))
+
+    def run(self, draw, batches):
+        """draw() -> the weights, the same at every call.  -> dict: losses
+        [steps], grad (first step, per leaf, on the host) with its
+        grad_norms, change_norms (after the last step)."""
+        w = draw()
+        m = {k: np.zeros(x.shape, np.float32) for k, x in w.items()}
+        v = {k: np.zeros(x.shape, np.float32) for k, x in w.items()}
+        losses, first = [], {}
         for t, batch in enumerate(batches, start=1):
             loss, g, n = self._batch_grad(w, batch)
             losses.append(loss)
-            w, m, v, g = self._adam(w, m, v, g, float(t), 1.0 / n)
-            if t == 1:
-                grad = g
-        return {"losses": losses, "grad": grad, "grad_norms": leaf_norms(grad),
-                "change_norms": leaf_norms(w, w0)}
+            self._update(w, m, v, g, t, n, first if t == 1 else None)
+        return {"losses": losses, "grad": {k: x for k, (_, x) in first.items()},
+                "grad_norms": {k: norm for k, (norm, _) in first.items()},
+                "change_norms": leaf_norms(w, draw())}
+
+
+@jax.jit
+def _norm(a, b):
+    return jnp.sqrt(jnp.sum(jnp.square(a - b)))
 
 
 @jax.jit
 def _norms(a, b):
-    return {k: jnp.sqrt(jnp.sum(jnp.square(a[k] - b[k]))) for k in a}
+    return {k: _norm(a[k], b[k]) for k in a}
+
+
+def leaf_norm(a, b=None):
+    """One leaf's norm of a, or of a - b, as a Python float: sets that wait on
+    the host come to the device a leaf at a time."""
+    return float(_norm(a, jnp.zeros((), a.dtype) if b is None else b))
 
 
 def leaf_norms(a, b=None):
-    """Each leaf's norm of a, or of a - b, as Python floats."""
+    """Each leaf's norm of a, or of a - b, as Python floats, the whole set in
+    one call."""
     if b is None:
         b = {k: jnp.zeros((), x.dtype) for k, x in a.items()}
     return {k: float(x) for k, x in _norms(a, b).items()}
@@ -184,7 +224,7 @@ def compare(got, ref):
     moved = [k for k in sorted(ref["grad_norms"]) if ref["grad_norms"][k] >= 1e-3 * med]
     change_gap, change_leaf = worst_leaf_gap(
         got["change_norms"], ref["change_norms"], moved)
-    diff = leaf_norms(got["grad"], ref["grad"])
+    diff = {k: leaf_norm(got["grad"][k], ref["grad"][k]) for k in got["grad"]}
     diff_gap, diff_leaf = worst_leaf_gap(None, ref["grad_norms"], diff=diff)
     shares = sorted(diff[k] / max(ref["grad_norms"][k], med, 1e-30) for k in diff)
     return {

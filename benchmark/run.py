@@ -11,7 +11,9 @@ Order of a run: set-up (data, weights, trainer, the first steps through
 `trainer.train`, whose readings `correct` is decided from) -> the window
 (`trainer.train` again on the same trainer, until the time is up) -> read the
 device's memory -> free the program -> the plain reference follows the same
-first steps -> compare.
+first steps -> compare.  From the trainer's construction to the end of the
+window the device holds the program's arrays and none of the benchmark's; the
+first line printed says what it held (`bytes_in_use`).
 
 `--rehearsal 1` is for the CPU: toy widths from rehearsal/<config>.json and
 the toy mix, platform stamped "cpu", no time, rate or share printed.
@@ -47,7 +49,10 @@ def _args(argv):
 
 
 def load_cell(workload, rehearsal=False):
-    """-> (benchmark, cell, configuration, mix, limits) from the data files."""
+    """-> (benchmark, cell, configuration, mix, limits) from the data files.
+    A rehearsal reads toy widths from rehearsal/<config>.json, which may name
+    its toy mix under "mix" (a kind of traffic other than sentence pairs has
+    one of its own); `rehearsal-ragged` otherwise."""
     import traffic
 
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -59,9 +64,10 @@ def load_cell(workload, rehearsal=False):
         path = os.path.join(HERE, "rehearsal", os.path.basename(entry["file"]))
     with open(path) as f:
         cfg = json.load(f)
-    mix = traffic.load_mix("rehearsal-ragged" if rehearsal else cell["traffic"])
     if rehearsal:  # toy widths read otherwise than the cell's own
+        mix = traffic.load_mix(cfg.get("mix", "rehearsal-ragged"))
         return bench, cell, cfg, mix, cfg["limits"]
+    mix = traffic.load_mix(cell["traffic"])
     with open(os.path.join(HERE, "limits", workload + ".json")) as f:
         limits = json.load(f)["limits"]
     return bench, cell, cfg, mix, limits
@@ -81,7 +87,7 @@ class Program:
     topology with the benchmark's weights.  Set-up's first steps and the
     window both go through `self.train`, i.e. `trainer.train`."""
 
-    def __init__(self, cfg, weights, chips):
+    def __init__(self, cfg, draw, chips):
         import jax
         import paddle_tpu as paddle
         from paddle_tpu.core.topology import reset_auto_names
@@ -96,7 +102,7 @@ class Program:
         reset_auto_names()
         cost, self.feeding = refsteps.load_by_name("models", cfg["model"]).build(cfg)
         parameters = paddle.parameters.create(cost, seed=0)
-        tree = W.to_program_tree(weights, self.param_map)
+        tree = W.to_program_tree(draw(), self.param_map)
         have = jax.tree_util.tree_map(lambda x: x.shape, parameters.params)
         if jax.tree_util.tree_map(lambda x: x.shape, tree) != have:
             raise SystemExit("the configuration's param_map does not cover the program's parameters")
@@ -137,31 +143,37 @@ class Program:
         return W.from_program_tree(self.trainer.parameters.params, self.param_map)
 
 
-def first_steps(program, batches, weights, beta1):
+def first_steps(program, batches, draw, beta1):
     """Set-up's checked steps, through the window's own call and feed.
     -> the program's readings: each step's loss, the first gradient per
     leaf (Adam's first moment after one step / (1 - beta1)) with its norm,
-    and the norm of each leaf's change after the last step."""
+    and the norm of each leaf's change after the last step.
+
+    While the program steps the device holds nothing of the benchmark's: its
+    draw went into the program, the first gradient waits on the host, and the
+    weights the change is taken from are drawn again (`draw()`, the same
+    program, so the same bits) after the last step."""
     import jax
     import numpy as np
 
     import refsteps
 
-    # with a mesh the program's leaves are replicated over it; the benchmark's
-    # own copies sit on the first device
-    def mine(tree):
-        return {k: jax.device_put(v, weights[k].sharding) for k, v in tree.items()}
-
     losses = []
     program.train(lambda: iter(batches[:1]), losses.append)
-    # kept on the host until the reference has run: the window's memory is
-    # the program's own
-    grad = {k: jax.device_get(x) / np.float32(1.0 - beta1)
+    grad = {k: np.asarray(x) / np.float32(1.0 - beta1)
             for k, x in program.adam_first_moment().items()}
     if len(batches) > 1:
         program.train(lambda: iter(batches[1:]), losses.append)
-    return {"losses": losses, "grad": grad, "grad_norms": refsteps.leaf_norms(grad),
-            "change_norms": refsteps.leaf_norms(mine(program.parameters()), weights)}
+    # one set of the benchmark's on the device at a time: the first gradient
+    # for its norms, then the second draw for the change
+    grad_norms = refsteps.leaf_norms(grad)
+    weights = draw()
+    # with a mesh the program's leaves are replicated over it; the benchmark's
+    # own sit on the first device
+    after = {k: jax.device_put(v, weights[k].sharding)
+             for k, v in program.parameters().items()}
+    return {"losses": losses, "grad": grad, "grad_norms": grad_norms,
+            "change_norms": refsteps.leaf_norms(after, weights)}
 
 
 class GcWatch:
@@ -186,19 +198,27 @@ class GcWatch:
         gc.callbacks.remove(self)
 
 
-def program_readings(cell, cfg, mix, seed):
-    """Builds the program from the seed and drives it through the checked
-    first steps alone: what `calibrate.py` and the tests read."""
+def drawn_from(cfg, seed):
+    """-> draw(): the benchmark's weights for this configuration and seed,
+    made on the device at every call."""
     import refsteps
-    import traffic
     import weights as W
 
     ref_mod = refsteps.load_by_name("reference", cfg["reference"])
+    draw = W.drawer(ref_mod.param_shapes(cfg))
+    return lambda: draw(seed)
+
+
+def program_readings(cell, cfg, mix, seed):
+    """Builds the program from the seed and drives it through the checked
+    first steps alone: what `calibrate.py` and the tests read."""
+    import traffic
+
     corpus = traffic.kind(mix).make_corpus(
         dict(mix, corpus_batches=mix["checked_steps"]), cfg, seed)
-    weights = W.make_weights(ref_mod.param_shapes(cfg), seed)
-    program = Program(cfg, weights, cell["chips"])
-    return first_steps(program, corpus, weights, cfg["optimizer"]["beta1"])
+    draw = drawn_from(cfg, seed)
+    program = Program(cfg, draw, cell["chips"])
+    return first_steps(program, corpus, draw, cfg["optimizer"]["beta1"])
 
 
 def window(program, corpus, seconds):
@@ -233,6 +253,13 @@ def memory_peak_bytes(jax, chips):
     return max(peaks)
 
 
+def bytes_in_use(jax, chips):
+    """What the fullest chip holds in live arrays now (None where the backend
+    does not say, as the CPU's)."""
+    stats = [d.memory_stats() for d in jax.devices()[:chips]]
+    return max(int(s["bytes_in_use"]) for s in stats) if all(stats) else None
+
+
 def percentile(values, q):
     """Nearest-rank percentile over all the values."""
     ordered = sorted(values)
@@ -240,21 +267,19 @@ def percentile(values, q):
     return ordered[int(rank) - 1]
 
 
-def reference_readings(cell, cfg, mix, seed, precision="float32", fault=None):
+def reference_readings(cell, cfg, mix, seed, precision="float32", fault=None, watch=None):
     """The plain reference follows the same first steps from the same weights
     (made again from the seed); -> its losses, gradient norms and changes."""
     import refsteps
     import traffic
-    import weights as W
 
     ref_mod = refsteps.load_by_name("reference", cfg["reference"])
-    weights = W.make_weights(ref_mod.param_shapes(cfg), seed)
     kind = traffic.kind(mix)
     corpus = kind.make_corpus(dict(mix, corpus_batches=mix["checked_steps"]), cfg, seed)
     return refsteps.ReferenceRun(
         ref_mod.make_block_cost(cfg), cfg["optimizer"], mix["reference_block_rows"],
-        precision=precision, fault=fault, chips=cell["chips"],
-    ).run(weights, [kind.as_arrays(b) for b in corpus])
+        precision=precision, fault=fault, chips=cell["chips"], cell=cell["name"], watch=watch,
+    ).run(drawn_from(cfg, seed), [kind.as_arrays(b) for b in corpus])
 
 
 def decide_correct(got, ref, limits):
@@ -290,9 +315,7 @@ def main(argv=None):
         return EXIT_NO_DEVICE
 
     import metrics_loader
-    import refsteps
     import traffic
-    import weights as W
     from meter import CompileMeter
 
     meter = CompileMeter()
@@ -312,14 +335,17 @@ def main(argv=None):
     kind = traffic.kind(mix)
     corpus = kind.make_corpus(mix, cfg, args.seed)
     part("corpus")
-    ref_mod = refsteps.load_by_name("reference", cfg["reference"])
-    weights = W.make_weights(ref_mod.param_shapes(cfg), args.seed)
-    program = Program(cfg, weights, chips)
+    # what the fullest chip holds in live arrays, at five moments of the run
+    # and at the reference's fullest: the harness's budget (PERF.md section 4)
+    held = {}
+    draw = drawn_from(cfg, args.seed)
+    program = Program(cfg, draw, chips)
     part("weights_and_trainer")
+    held["after_program"] = bytes_in_use(jax, chips)
     checked = corpus[: mix["checked_steps"]]
-    got = first_steps(program, checked, weights, cfg["optimizer"]["beta1"])
+    got = first_steps(program, checked, draw, cfg["optimizer"]["beta1"])
     part("first_steps")
-    del weights
+    held["after_checked_steps"] = bytes_in_use(jax, chips)
     items_per_batch = [kind.items(b) for b in corpus]
     compiles_before = meter.snapshot()
     trace_dir = os.path.join(ROOT, ".bench_trace", cell["name"])
@@ -345,6 +371,7 @@ def main(argv=None):
         jax.profiler.stop_trace()
     compiles_in_window = meter.snapshot()[0] - compiles_before[0]
     peak_bytes = memory_peak_bytes(jax, chips)
+    held["after_window"] = bytes_in_use(jax, chips)
 
     attempted = len(costs)
     failed = sum(1 for c in costs if c != c or c in (float("inf"), float("-inf")))
@@ -355,9 +382,17 @@ def main(argv=None):
     # ---- free the program, then the reference -----------------------------
     del program
     gc.collect()
+    held["before_reference"] = held["reference_most"] = bytes_in_use(jax, chips)
+
+    def watch():
+        now = bytes_in_use(jax, chips)
+        if now is not None:
+            held["reference_most"] = max(held["reference_most"], now)
+
     t_ref = time.perf_counter()
-    ref = reference_readings(cell, cfg, mix, args.seed)
+    ref = reference_readings(cell, cfg, mix, args.seed, watch=watch)
     reference_s = time.perf_counter() - t_ref
+    held["after_reference"] = bytes_in_use(jax, chips)
     ok, compared, notes = decide_correct(got, ref, limits)
     ok = ok and failed == 0 and attempted > 0
 
@@ -383,7 +418,7 @@ def main(argv=None):
     line = {"workload": cell["name"], "seed": args.seed, "steps": attempted,
             "item": cfg["item"], "items": items, "compiles": meter.snapshot()[0],
             "cache_hits": meter.snapshot()[1], "compiles_in_window": compiles_in_window,
-            "notes": notes}
+            "bytes_in_use": held, "notes": notes}
     if not rehearsal:  # a CPU run prints no time
         ordered = sorted(gaps)
         line["gap_ms"] = {q: ordered[min(len(ordered) - 1, q * len(ordered) // 100)] * 1e3

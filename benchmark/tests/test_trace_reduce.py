@@ -26,6 +26,22 @@ def test_scopes_of_a_name_stack():
     assert T.scopes_of("jit(step)/mul") == []
 
 
+def test_idle_gaps_are_labelled_by_the_trainers_thread_first():
+    # two steps on the device with 30 ms between them; the trainer waits 18 ms
+    # for its batch, then dispatches for 10 ms; the prefetch thread's `feed`
+    # lies over the whole stretch, and is the label only where it is alone
+    ops = [(0.000, 0.100, "a"), (0.130, 0.230, "a"), (0.250, 0.300, "a")]
+    host = {"block_fetch": [(0.010, 0.101)], "feed_wait": [(0.101, 0.119)],
+            "train_step": [(0.119, 0.129)], "feed": [(0.095, 0.135), (0.225, 0.255)]}
+    trace = T.Trace({"/device:TPU:0": {"ops": ops, "modules": [(0.0, 0.3, "jit_step")]}}, {}, host)
+    labels = dict(trace.idle_gaps("/device:TPU:0"))
+    assert labels == {"feed_wait": pytest.approx(0.030), "feed": pytest.approx(0.020)}
+    # a trace from before `feed_wait` existed still gets its three labels
+    del host["feed_wait"]
+    assert dict(trace.idle_gaps("/device:TPU:0")) == {
+        "train_step": pytest.approx(0.030), "feed": pytest.approx(0.020)}
+
+
 def test_recorded_trace_matches_the_independent_answers():
     with open(os.path.join(DATA, "answers.json")) as f:
         want = json.load(f)

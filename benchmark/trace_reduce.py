@@ -273,18 +273,22 @@ class Trace:
 
     def idle_gaps(self, plane, n=10):
         """[(label, seconds)]: the longest idle stretches of the device, each
-        labelled with the host span (of the program's obs spans) that covers
-        most of it."""
+        labelled with what the host was doing: the span of the trainer's
+        thread (`feed_wait`, `train_step`, `block_fetch`: between them they
+        cover its `step`) that covers most of the stretch; where none of them
+        touches it, `feed`, the prefetch thread's, if that does."""
         lo, hi = self.window(plane)
         iv = [(s, e) for s, e, _ in self.devices[plane]["ops"]]
-        spans = {k: v for k, v in self.host.items()
-                 if k in ("feed", "train_step", "block_fetch")}
+
+        def most_cover(s, e, names):
+            cover = {k: sum(max(0.0, min(e, b) - max(s, a)) for a, b in self.host.get(k, ()))
+                     for k in names}
+            best = max(cover, key=cover.get)
+            return best if cover[best] > 0.0 else None
+
         acc = {}
         for s, e in gaps(iv, lo, hi):
-            best, cover = "no-span", 0.0
-            for name, ivs in spans.items():
-                c = sum(max(0.0, min(e, b) - max(s, a)) for a, b in ivs)
-                if c > cover:
-                    best, cover = name, c
-            acc[best] = acc.get(best, 0.0) + (e - s)
+            label = (most_cover(s, e, ("feed_wait", "train_step", "block_fetch"))
+                     or most_cover(s, e, ("feed",)) or "no-span")
+            acc[label] = acc.get(label, 0.0) + (e - s)
         return sorted(acc.items(), key=lambda kv: -kv[1])[:n]
