@@ -23,6 +23,7 @@ Sigmoid = _make("sigmoid")
 Softmax = _make("softmax")
 SequenceSoftmax = _make("sequence_softmax")
 Relu = _make("relu")
+Relu2 = _make("relu2")
 BRelu = _make("brelu")
 Tanh = _make("tanh")
 STanh = _make("stanh")

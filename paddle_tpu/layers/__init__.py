@@ -11,7 +11,7 @@ arithmetic, implicit flatten) mirrors config_parser.py cnn_output_size.
 from __future__ import annotations
 
 import math
-from typing import Optional, Sequence, Union
+from typing import Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -46,6 +46,7 @@ from paddle_tpu.layers import attention as _attention  # noqa: F401
 from paddle_tpu.layers import detection as _detection  # noqa: F401
 from paddle_tpu.layers import mdlstm as _mdlstm  # noqa: F401
 from paddle_tpu.layers import moe as _moe  # noqa: F401
+from paddle_tpu.layers import ssm as _ssm  # noqa: F401
 from paddle_tpu.layers import layer_math  # noqa: F401  (also patches LayerOutput operators)
 
 
@@ -986,6 +987,40 @@ def moe(
 
 
 moe_layer = moe
+
+
+def moe_topk(
+    input: LayerOutput,
+    expert_hidden: int,
+    num_experts: int,
+    top_k: int,
+    experts_held: Optional[Tuple[int, int]] = None,
+    shared_hidden: int = 0,
+    score_fn: str = "sigmoid",
+    scaling: float = 1.0,
+    size: Optional[int] = None,
+    act=None,
+    name: Optional[str] = None,
+) -> LayerOutput:
+    """Experts chosen top_k a token by `score_fn` ("sigmoid" or "softmax")
+    over all `num_experts` router outputs, weights normalised over the chosen
+    and times `scaling`, no capacity and no dropped token, plus a shared
+    expert `shared_hidden` wide (layers/moe.py).  `experts_held` = (lo, hi)
+    is the range of expert ids whose weights live here (all by default): the
+    layer returns the shared expert's output plus the held experts' part.
+    Counters ride the aux outputs ``<name>@rows_held`` and
+    ``<name>@rows_dropped``."""
+    if score_fn not in ("sigmoid", "softmax"):
+        raise ValueError(f"moe_topk: no score function {score_fn!r}")
+    lo, hi = experts_held or (0, num_experts)
+    if not 0 <= lo < hi <= num_experts or top_k > num_experts:
+        raise ValueError(
+            f"moe_topk: experts_held {(lo, hi)} / top_k {top_k} of {num_experts} experts")
+    return _unary(
+        "moe_topk", input, size=size, name=name, num_experts=num_experts,
+        expert_hidden=expert_hidden, top_k=top_k, experts_held=(int(lo), int(hi)),
+        shared_hidden=shared_hidden, score_fn=score_fn, scaling=float(scaling),
+        active_type=act_name(act if act is not None else _act_mod.Relu2()))
 
 
 def gated_unit(
@@ -2417,6 +2452,35 @@ def layer_norm(
     return _unary("layer_norm", input, name=name, epsilon=epsilon)
 
 
+def rms_norm(
+    input: LayerOutput, epsilon: float = 1e-5, name: Optional[str] = None
+) -> LayerOutput:
+    """x / sqrt(mean(x^2) + epsilon) with a learned gain: layer_norm without
+    the mean and the shift."""
+    return _unary("rms_norm", input, name=name, epsilon=epsilon)
+
+
+def mamba2(
+    input: LayerOutput,
+    n_heads: int,
+    head_dim: int,
+    n_groups: int,
+    state_size: int,
+    size: Optional[int] = None,
+    conv_kernel: int = 4,
+    chunk_size: int = 128,
+    epsilon: float = 1e-5,
+    name: Optional[str] = None,
+) -> LayerOutput:
+    """Mamba-2 mixer over a sequence (layers/ssm.py): n_heads heads of
+    head_dim channels, n_groups groups of B and C of state_size, a chunked
+    selective scan.  Output `size` wide (the input's by default)."""
+    return _unary(
+        "mamba2", input, size=size, name=name, n_heads=n_heads, head_dim=head_dim,
+        n_groups=n_groups, state_size=state_size, conv_kernel=conv_kernel,
+        chunk_size=chunk_size, epsilon=epsilon)
+
+
 def multi_head_attention(
     query: LayerOutput,
     key_value: Optional[LayerOutput] = None,
@@ -2426,11 +2490,16 @@ def multi_head_attention(
     bias_attr: bool = True,
     seq_parallel_axis: Optional[str] = None,
     name: Optional[str] = None,
+    n_kv_heads: Optional[int] = None,
+    head_dim: Optional[int] = None,
 ) -> LayerOutput:
     """Multi-head attention; omit key_value for self-attention.  `causal`
     masks future positions (decoder self-attention).  `seq_parallel_axis`
     names a mesh axis to shard the sequence over — self-attention then runs
-    as exact ring attention (long-context path, parallel/ring_attention)."""
+    as exact ring attention (long-context path, parallel/ring_attention).
+    `n_kv_heads` < `n_heads` gives grouped-query attention (each key/value
+    head serves n_heads / n_kv_heads query heads); `head_dim` sets the heads'
+    width apart from size / n_heads (the output stays `size` wide)."""
     kv = key_value or query
     conf = LayerConf(
         name=name or auto_name("mha"),
@@ -2442,6 +2511,8 @@ def multi_head_attention(
             "n_heads": n_heads,
             "causal": causal,
             "seq_parallel_axis": seq_parallel_axis,
+            "n_kv_heads": n_kv_heads,
+            "head_dim": head_dim,
         },
     )
     return LayerOutput(conf, [query, kv])

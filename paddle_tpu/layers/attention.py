@@ -56,6 +56,24 @@ def layer_norm_apply(conf, params, inputs, ctx):
     return x.with_data(y.astype(x.data.dtype))
 
 
+def rms_normalize(x32, eps):
+    """x / sqrt(mean(x^2) + eps) over the last axis, x float32."""
+    return x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+
+
+def rms_norm_init(conf, in_confs, rng):
+    return {"gamma": init.ones((conf.size,))}
+
+
+@register_layer("rms_norm", init=rms_norm_init, auto_activation=False)
+def rms_norm_apply(conf, params, inputs, ctx):
+    """x / sqrt(mean(x^2) + eps) * gamma: no mean subtracted, no shift;
+    float32 statistics as layer_norm's."""
+    x = inputs[0]
+    y = rms_normalize(x.data.astype(jnp.float32), conf.attr("epsilon", 1e-5))
+    return x.with_data((y * params["gamma"].astype(jnp.float32)).astype(x.data.dtype))
+
+
 # ---------------------------------------------------------------------------
 # multi-head attention
 # ---------------------------------------------------------------------------
@@ -65,20 +83,46 @@ def mha_init(conf, in_confs, rng):
     import jax
 
     d = conf.size
+    h, kvh, dh = _head_dims(conf)
     d_in_q = in_confs[0].size
     d_in_kv = in_confs[1].size if len(in_confs) > 1 else d_in_q
     rq, rk, rv, ro = jax.random.split(rng, 4)
     std_q = 1.0 / math.sqrt(d_in_q)
     std_kv = 1.0 / math.sqrt(d_in_kv)
     p = {
-        "wq": init.normal(rq, (d_in_q, d), std_q),
-        "wk": init.normal(rk, (d_in_kv, d), std_kv),
-        "wv": init.normal(rv, (d_in_kv, d), std_kv),
-        "wo": init.normal(ro, (d, d), 1.0 / math.sqrt(d)),
+        "wq": init.normal(rq, (d_in_q, h * dh), std_q),
+        "wk": init.normal(rk, (d_in_kv, kvh * dh), std_kv),
+        "wv": init.normal(rv, (d_in_kv, kvh * dh), std_kv),
+        "wo": init.normal(ro, (h * dh, d), 1.0 / math.sqrt(h * dh)),
     }
     if conf.bias:
         p["b"] = init.zeros((d,))
     return p
+
+
+def _head_dims(conf):
+    """(query heads, key/value heads, head width).  By default the heads
+    divide the layer's size and every query head has key/value heads of its
+    own; `head_dim` sets a width apart from the size, `n_kv_heads` makes
+    groups of n_heads / n_kv_heads query heads share one key/value head."""
+    h = conf.attrs["n_heads"]
+    kvh = conf.attr("n_kv_heads") or h
+    dh = conf.attr("head_dim") or conf.size // h
+    assert conf.attr("head_dim") or conf.size % h == 0, (
+        f"{conf.name}: size {conf.size} not divisible by n_heads {h}")
+    assert h % kvh == 0, f"{conf.name}: {h} query heads over {kvh} key/value heads"
+    return h, kvh, dh
+
+
+# keys from which self-attention takes the blocked kernel unasked (the
+# benchmark's cells lie on both sides: 128 and 1,024 keys dense, 2,048 blocked)
+_FLASH_FROM_KEYS = 2048
+
+
+def _flash_asked():
+    from paddle_tpu.utils.flags import get_flag
+
+    return bool(get_flag("use_pallas_attention"))
 
 
 @register_layer("multi_head_attention", init=mha_init, auto_activation=False)
@@ -87,11 +131,9 @@ def mha_apply(conf, params, inputs, ctx):
     self-attention.  attrs: n_heads, causal."""
     q_in = inputs[0]
     kv_in = inputs[1] if len(inputs) > 1 else inputs[0]
-    h = conf.attrs["n_heads"]
+    h, kvh, dh = _head_dims(conf)
     causal = conf.attr("causal", False)
-    d = conf.size
-    dh = d // h
-    assert d % h == 0, f"{conf.name}: size {d} not divisible by n_heads {h}"
+    d = h * dh  # the heads' width together; conf.size is the output's
 
     # self-attention detection by TOPOLOGY, not object identity: the
     # mixed-precision cast rebuilds each input SeqTensor, so `kv_in is
@@ -103,7 +145,7 @@ def mha_apply(conf, params, inputs, ctx):
         qkv = acc_matmul(q_in.data, jnp.concatenate(
             [params["wq"], params["wk"], params["wv"]], axis=1
         ))
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q, k, v = jnp.split(qkv, [d, d + kvh * dh], axis=-1)
     else:
         q = acc_matmul(q_in.data, params["wq"])  # [B, Tq, D]
         k = acc_matmul(kv_in.data, params["wk"])  # [B, Tk, D]
@@ -111,11 +153,35 @@ def mha_apply(conf, params, inputs, ctx):
     b, tq = q.shape[0], q.shape[1]
     tk = k.shape[1]
     q = q.reshape(b, tq, h, dh)
-    k = k.reshape(b, tk, h, dh)
-    v = v.reshape(b, tk, h, dh)
+    k = k.reshape(b, tk, kvh, dh)
+    v = v.reshape(b, tk, kvh, dh)
+    group = h // kvh
 
     sp_axis = conf.attr("seq_parallel_axis")
     out = None
+    # The blocked kernel (ops/pallas_attention.py) streams k/v blocks through
+    # VMEM with an online softmax: no [T, T] score matrix in HBM.  TPU
+    # backend only.  It is taken where the flag asks for it, and from
+    # _FLASH_FROM_KEYS keys on whatever the flag says: there the dense
+    # scores cost more than they save (2 x 32 heads x 2,048^2 in float32 are
+    # 1.07 GB a layer; 251 -> 239 ms a step of the hybrid decoder, PERF.md).
+    # Asked for by the flag and not usable, the layer computes dense and
+    # SAYS so at trace time: a silent dense path would be timed and costed
+    # as the kernel.
+    from paddle_tpu.ops import pallas_attention as fa
+
+    flash_why = None
+    if jax.default_backend() != "tpu":
+        flash_why = f"the backend is {jax.default_backend()!r}, not 'tpu'"
+    elif tq != tk:
+        flash_why = f"query length {tq} != key length {tk}"
+    elif not fa.supported(tq, dh):
+        flash_why = f"T={tq}, head dim {dh} is not a shape the kernel takes"
+    take_flash = flash_why is None and (_flash_asked() or tk >= _FLASH_FROM_KEYS)
+    if group > 1 and (sp_axis is not None or take_flash):
+        # the ring and the kernel take a key/value head a query head
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+        kvh, group = h, 1
     if sp_axis is not None and tq == tk:
         # context parallelism: shard T over the mesh axis and run exact
         # ring attention (parallel/ring_attention.py) instead of the dense
@@ -156,38 +222,21 @@ def mha_apply(conf, params, inputs, ctx):
                 causal=causal,
             ).reshape(b, tq, d)
 
-    if out is None:
-        # Fused flash-attention Pallas kernel (ops/pallas_attention.py):
-        # streams k/v blocks through VMEM with an online softmax — no
-        # [T, T] score matrix in HBM.  TPU backend only.  Asked for and not
-        # usable, the layer computes dense and SAYS so at trace time: a
-        # silent dense path would be timed and costed as the kernel.
-        from paddle_tpu.ops import pallas_attention as fa
-        from paddle_tpu.utils.flags import get_flag
+    if out is None and take_flash:
+        bq, bk = fa.auto_blocks(tq)
+        out = fa.flash_attention_diff(
+            q, k, v,
+            kv_in.lengths if kv_in.is_seq else None,
+            causal, bq, bk, False,
+        ).reshape(b, tq, d)
+    elif out is None and _flash_asked():
+        import warnings
 
-        if get_flag("use_pallas_attention"):
-            why = None
-            if jax.default_backend() != "tpu":
-                why = f"the backend is {jax.default_backend()!r}, not 'tpu'"
-            elif tq != tk:
-                why = f"query length {tq} != key length {tk}"
-            elif not fa.supported(tq, dh):
-                why = f"T={tq}, head dim {dh} is not a shape the kernel takes"
-            if why is None:
-                bq, bk = fa.auto_blocks(tq)
-                out = fa.flash_attention_diff(
-                    q, k, v,
-                    kv_in.lengths if kv_in.is_seq else None,
-                    causal, bq, bk, False,
-                ).reshape(b, tq, d)
-            else:
-                import warnings
-
-                warnings.warn(
-                    f"{conf.name}: use_pallas_attention is on but {why}; "
-                    "computing dense O(T^2) attention",
-                    stacklevel=2,
-                )
+        warnings.warn(
+            f"{conf.name}: use_pallas_attention is on but {flash_why}; "
+            "computing dense O(T^2) attention",
+            stacklevel=2,
+        )
 
     if out is None:  # dense path
         # Explicit [B, h, T, dh] operands with LEADING batch dims: the
@@ -203,7 +252,16 @@ def mha_apply(conf, params, inputs, ctx):
         # whole-[T,T]-in-VMEM Pallas kernel with grid (B,) + in-core
         # batched-over-heads dots, which lost ~35% to tiny per-program
         # work at T=64.)
-        qh = q.transpose(0, 2, 1, 3)
+        #
+        # Grouped heads (n_kv_heads < n_heads): the query heads that share
+        # a key/value head are folded into the query axis, [B, kvh, g*Tq,
+        # dh], so the same two einsums run over kvh batch heads and no
+        # key or value is repeated.
+        if group == 1:
+            qh = q.transpose(0, 2, 1, 3)
+        else:
+            qh = (q.reshape(b, tq, kvh, group, dh).transpose(0, 2, 3, 1, 4)
+                  .reshape(b, kvh, group * tq, dh))
         kh = k.transpose(0, 2, 1, 3)
         vh = v.transpose(0, 2, 1, 3)
         scores = acc_einsum("bhqd,bhkd->bhqk", qh, kh) / math.sqrt(dh)
@@ -213,13 +271,16 @@ def mha_apply(conf, params, inputs, ctx):
             scores = scores + (1.0 - key_mask)[:, None, None, :] * NEG_INF
         if causal:
             cm = jnp.tril(jnp.ones((tq, tk), jnp.float32))
+            if group > 1:
+                cm = jnp.tile(cm, (group, 1))
             scores = scores + (1.0 - cm)[None, None, :, :] * NEG_INF
         w = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-        out = (
-            acc_einsum("bhqk,bhkd->bhqd", w, vh)
-            .transpose(0, 2, 1, 3)
-            .reshape(b, tq, d)
-        )
+        out = acc_einsum("bhqk,bhkd->bhqd", w, vh)
+        if group == 1:
+            out = out.transpose(0, 2, 1, 3).reshape(b, tq, d)
+        else:
+            out = (out.reshape(b, kvh, group, tq, dh).transpose(0, 3, 1, 2, 4)
+                   .reshape(b, tq, d))
 
     out = acc_matmul(out, params["wo"])
     if "b" in params:

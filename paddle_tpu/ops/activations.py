@@ -83,6 +83,13 @@ def _relu(x):
     return jax.nn.relu(x)
 
 
+@register_activation("relu2")
+def _relu2(x):
+    # squared ReLU (So et al. 2021, "Primer"): the gateless MLPs of the
+    # nemotron_h family
+    return jnp.square(jax.nn.relu(x))
+
+
 @register_activation("brelu")
 def _brelu(x):
     # Reference clips to [0, 24] (BReluActivation, ActivationFunction.cpp).

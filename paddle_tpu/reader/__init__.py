@@ -10,6 +10,7 @@ from paddle_tpu.reader.decorator import (  # noqa: F401
     compose,
     firstn,
     map_readers,
+    next_token_rows,
     shuffle,
     xmap_readers,
 )

@@ -158,6 +158,26 @@ def firstn(reader: Reader, n: int) -> Reader:
     return firstn_reader
 
 
+def next_token_rows(reader: Reader, row_len: int) -> Reader:
+    """The token-row reader of a decoder-only language model: cuts a reader
+    of token ids (single ids, or documents as lists of ids, end to end) into
+    full rows of `row_len` + 1 ids and yields (row[:-1], row[1:]), a row's
+    inputs and the token that follows each.  Consecutive rows share their
+    boundary token; a tail shorter than a row is left out."""
+    if row_len < 1:
+        raise ValueError(f"next_token_rows: row_len {row_len}")
+
+    def rows():
+        held: List[int] = []
+        for item in reader():
+            held.extend(item if isinstance(item, (list, tuple)) else [item])
+            while len(held) > row_len:
+                yield held[:row_len], held[1:row_len + 1]
+                held = held[row_len:]
+
+    return rows
+
+
 def cache(reader: Reader) -> Reader:
     """Materialize once in memory, replay after (the CACHE_PASS_IN_MEM mode of
     PyDataProvider2, reference PyDataProvider2.cpp:69)."""

@@ -336,6 +336,17 @@ BUILDERS = {
     "multi_head_attention": lambda: L.multi_head_attention(
         dense_seq(8), n_heads=2
     ),
+    "rms_norm": lambda: L.rms_norm(dense()),
+    "mamba2": lambda: L.mamba2(
+        dense_seq(6), n_heads=2, head_dim=4, n_groups=1, state_size=3, chunk_size=2
+    ),
+    # routing is piecewise like moe's: a small step keeps the finite
+    # difference on one side of a choice
+    "moe_topk": lambda: (
+        L.moe_topk(dense_seq(6), expert_hidden=4, num_experts=4, top_k=2,
+                   experts_held=(1, 3), shared_hidden=5),
+        {"atol": 8e-2, "rtol": 8e-2, "eps": 2e-4},
+    ),
     "selective_fc": lambda: (
         L.selective_fc(dense(8, "x"), ids(9, "sel"), size=9),
         {"check_inputs": False},

@@ -958,3 +958,83 @@ def test_attention_gru_core_scope_in_forward_and_backward():
     assert any("cast:decoder" in n for n in casts)
     assert any("cast:enc_fw" in n and "transpose(" in n for n in casts)
     assert not [n for n in casts if "recurrent_group:" in n or "gru:" in n or "fc:" in n]
+
+
+def _hybrid_step_names():
+    """The names in the lowered train step of a toy hybrid decoder (pattern
+    MEM*E, bfloat16 compute), and a run of the same network's forward pass."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.core.compiler import get_default_compute_dtype, set_default_compute_dtype
+    from paddle_tpu.core.topology import reset_auto_names
+    from paddle_tpu.models.hybrid_lm import hybrid_lm_cost
+
+    reset_auto_names()
+    before = get_default_compute_dtype()
+    set_default_compute_dtype(jnp.bfloat16)
+    try:
+        cost, _ = hybrid_lm_cost(
+            "MEM*E", 50, 16, mamba_heads=4, mamba_head_dim=8, mamba_groups=2, state_size=4,
+            chunk_size=4, attn_heads=4, attn_kv_heads=2, attn_head_dim=8, num_experts=8,
+            experts_per_token=3, expert_hidden=12, shared_hidden=20, experts_held=(2, 6))
+        trainer = paddle.trainer.SGD(
+            cost=cost, parameters=paddle.parameters.create(cost, seed=0),
+            update_equation=paddle.optimizer.Adam(learning_rate=1e-3))
+    finally:
+        set_default_compute_dtype(before)
+    rng = np.random.RandomState(0)
+    rows = [tuple(list(rng.randint(2, 50, 10)) for _ in range(2)) for _ in range(3)]
+    batch = trainer._make_feeder({"word": 0, "next_word": 1})(rows)
+    p = trainer.parameters
+    names = _scope_names(trainer._train_step.lower(
+        p.params, p.state, trainer._opt_state, batch, jax.random.PRNGKey(0)))
+    outs, _ = trainer.network.apply(p.params, batch, state=p.state, train=True)
+    return names, outs
+
+
+@pytest.fixture(scope="module")
+def hybrid_step():
+    return _hybrid_step_names()
+
+
+def test_hybrid_decoder_step_has_no_operation_outside_a_scope(hybrid_step):
+    names, _ = hybrid_step
+    for scope in ("mamba2:l0_mamba", "rms_norm:l0_norm", "moe_topk:l1_moe",
+                  "multi_head_attention:l3_attn", "cast:l0_mamba", "optimizer:adam/"):
+        assert any(scope in n for n in names), scope
+    # the one operation outside a scope is no layer's: the zeros that
+    # jax.grad itself writes for the experts' correction bias, which steers
+    # the choice alone and so has no gradient (a constant, folded by XLA)
+    assert _bare(names) == ["jit(step)/broadcast_in_dim"]
+
+
+@pytest.mark.parametrize("inner,layer", [
+    ("ssd_scan", "mamba2:"), ("moe_route", "moe_topk:"),
+    ("moe_experts", "moe_topk:"), ("moe_shared", "moe_topk:"),
+])
+def test_hybrid_decoder_inner_scopes_stay_within_their_layer(hybrid_step, inner, layer):
+    """Forward and backward: a scope without a colon, so the layer stays the
+    operations' innermost `type:name` scope (what `ssd_scan_roofline` and
+    `moe_experts_roofline` read beside `ssm_layers_share`, `moe_layers_share`)."""
+    names, _ = hybrid_step
+    mine = [n for n in names if f"/{inner}/" in n]
+    assert [n for n in mine if "transpose(" not in n], inner
+    # the route's choice has no gradient of its own; the rest run both ways
+    assert inner == "moe_route" or [n for n in mine if "transpose(" in n], inner
+    assert all(layer in n.split(f"/{inner}/")[0] for n in mine)
+
+
+def test_expert_layer_counters_ride_the_aux_outputs(hybrid_step):
+    """`<name>@rows_held` and `<name>@rows_dropped`, a [B, 1] row each as
+    `@aux_loss`: rows computed here, and none dropped, in every layer."""
+    import numpy as np
+
+    _, outs = hybrid_step
+    for name in ("l1_moe", "l4_moe"):
+        held, dropped = (np.asarray(outs[f"{name}@{k}"].data) for k in ("rows_held", "rows_dropped"))
+        assert held.shape == (3, 1) and (held == held[0, 0]).all()
+        assert 0 < held[0, 0] <= 3 * 10 * 3  # tokens x choices
+        assert (dropped == 0).all()
