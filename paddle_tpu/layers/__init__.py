@@ -1007,9 +1007,13 @@ def moe_topk(
     and times `scaling`, no capacity and no dropped token, plus a shared
     expert `shared_hidden` wide (layers/moe.py).  `experts_held` = (lo, hi)
     is the range of expert ids whose weights live here (all by default): the
-    layer returns the shared expert's output plus the held experts' part.
-    Counters ride the aux outputs ``<name>@rows_held`` and
-    ``<name>@rows_dropped``."""
+    layer returns the shared expert's output plus the held experts' part,
+    computed in passes over at most `layers.moe.held_rows_bound` sorted
+    (token, choice) rows at a time: twice the even share of the held
+    experts, from the shapes; rows beyond it take further passes and none
+    is left out.  Counters ride the aux outputs ``<name>@rows_held``,
+    ``<name>@rows_over_bound`` (the passes beyond the first) and
+    ``<name>@rows_dropped`` (0 by construction)."""
     if score_fn not in ("sigmoid", "softmax"):
         raise ValueError(f"moe_topk: no score function {score_fn!r}")
     lo, hi = experts_held or (0, num_experts)

@@ -161,8 +161,13 @@ def moe_apply(conf, params, inputs, ctx: ApplyContext):
 # (tests/test_hybrid_lm.py).  No token is ever dropped: the (token, choice)
 # pairs are sorted by expert, the pairs whose expert is not held sort last,
 # and `jax.lax.ragged_dot` multiplies each held expert's rows by its matrix.
-# Shapes are static for the worst case (every token chooses k held experts:
-# N x k rows); the products are spent on the rows that exist.
+# Shapes are static, and sized by the rows that exist rather than by the
+# worst case (every token chooses k held experts: N x k rows): the sorted
+# rows are worked through in passes of `held_rows_bound` rows, a bound taken
+# from the shapes alone, the first pass always and a further one for every
+# `held_rows_bound` rows beyond it (`<name>@rows_over_bound` counts those).
+# Where the bound is N x k (every expert held, or a handful of tokens) there
+# is the one pass and no loop.
 #
 # A separate layer from `moe` above, whose top-1 softmax routing into
 # capacity slots (one-hot dispatch einsums, dropped overflow, the Switch
@@ -189,41 +194,125 @@ def moe_topk_init(conf, in_confs, rng):
     return p
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_rows(x, order, inverse, k):
-    """x[order // k]: row i of the result is the token of the i-th sorted
-    (token, choice) pair.  `inverse` undoes `order`.  The transpose is a
-    gather too (un-sort, then add a token's k pairs), not a scatter."""
-    return jnp.take(x, order // k, axis=0)
+# The held experts' block works on at most this many times the (token, choice)
+# rows a router that spreads its choices evenly sends to the experts held
+# here.  Twice: a layer whose load is balanced (what the correction bias is
+# for) stays under it step after step, so the block's arrays are sized once,
+# from the shapes; a layer that is not pays one more pass of the block for
+# each further `held_rows_bound` rows and loses none.
+_ROWS_OVER_EVEN_SHARE = 2
 
 
-def _take_rows_fwd(x, order, inverse, k):
-    return _take_rows(x, order, inverse, k), (inverse, x.shape[0])
+def held_rows_bound(n, k, held, num_experts):
+    """Rows of one pass of the held experts' block, from the shapes alone:
+    the even share of `n * k` pairs that `held` of `num_experts` experts
+    draw, times `_ROWS_OVER_EVEN_SHARE`, rounded up to the 512-row tile of
+    XLA:TPU's grouped product (to 8 rows below one tile), and never more
+    than the pairs there are."""
+    pairs = n * k
+    want = -(-_ROWS_OVER_EVEN_SHARE * pairs * held // num_experts)
+    tile = 512 if want >= 512 else 8
+    return min(pairs, -(-want // tile) * tile)
 
 
-def _take_rows_bwd(k, res, g):
-    inverse, n = res
-    return jnp.take(g, inverse, axis=0).reshape(n, k, -1).sum(axis=1), None, None
+def _experts(f_act, xs, w1, w2, sizes, live):
+    """The two grouped products over rows sorted by expert.  Rows past the
+    last group are neither computed nor defined: they are cut out on both
+    sides of each product, so nothing flows back through them either."""
+    hmid = jax.lax.ragged_dot(xs, w1, sizes, preferred_element_type=xs.dtype)
+    hmid = f_act(jnp.where(live, hmid, 0))
+    ys = jax.lax.ragged_dot(hmid, w2, sizes, preferred_element_type=xs.dtype)
+    return jnp.where(live, ys, 0)
 
 
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+def _further_passes(rows, bound):
+    """Passes of `bound` rows beyond the first that `rows` rows take."""
+    return jnp.maximum(-(-rows // bound) - 1, 0)
 
 
-@jax.custom_vjp
-def _unsort(y, order, inverse):
-    """y[inverse]: from sorted rows back to (token, choice) order."""
-    return jnp.take(y, inverse, axis=0)
+def _passes(k, bound, order, group_sizes):
+    """-> (pass_rows, passes) for the sorted pairs cut into passes of `bound`
+    rows.  pass_rows(b) gives pass b's pairs, their tokens, which of its
+    rows hold a held pair, and each held expert's rows among them; `passes`
+    is how many hold any row, and at least one."""
+    edges = jnp.concatenate([jnp.zeros((1,), group_sizes.dtype), jnp.cumsum(group_sizes)])
+    rows = edges[-1]
+    order = jnp.pad(order, (0, -order.shape[0] % bound))
+
+    def pass_rows(b):
+        start = b * bound
+        pair = jax.lax.dynamic_slice(order, (start,), (bound,))
+        live = (start + jnp.arange(bound) < rows)[:, None]
+        cut = jnp.clip(edges, start, start + bound)
+        return pair, pair // k, live, cut[1:] - cut[:-1]
+
+    return pass_rows, 1 + _further_passes(rows, bound)
 
 
-def _unsort_fwd(y, order, inverse):
-    return _unsort(y, order, inverse), order
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _held_experts(f_act, k, bound, tokens, w1, w2, weights, order, group_sizes):
+    """sum over a token's held choices e of weights[token, e] E_e(token), [N, D].
+
+    `order` lists the (token, choice) pairs sorted by expert, the held ones
+    first; `group_sizes` counts each held expert's.  The rows are worked
+    through in passes of `bound`: the first always, each further one only
+    while rows are left, so the arrays are `bound` rows whatever the
+    routing and the cost follows the rows that exist.  A pass gathers its
+    tokens' rows, multiplies, and adds each weighted result row to its
+    token (a scatter-add of `bound` rows; float32 sums).
+
+    Nothing but the arguments is kept for the way back: each pass is
+    computed again there, one at a time."""
+    return _held_experts_fwd(f_act, k, bound, tokens, w1, w2, weights, order, group_sizes)[0]
 
 
-def _unsort_bwd(order, g):
-    return jnp.take(g, order, axis=0), None, None
+def _held_experts_fwd(f_act, k, bound, tokens, w1, w2, weights, order, group_sizes):
+    pass_rows, passes = _passes(k, bound, order, group_sizes)
+    wflat = weights.reshape(-1)
+
+    def add_pass(b, out):
+        pair, tok, live, sizes = pass_rows(b)
+        ys = _experts(f_act, jnp.where(live, tokens[tok], 0), w1, w2, sizes, live)
+        return out.at[tok].add(ys.astype(jnp.float32) * wflat[pair][:, None])
+
+    out = add_pass(0, jnp.zeros((tokens.shape[0], w2.shape[-1]), jnp.float32))
+    if bound < order.shape[0]:
+        out = jax.lax.fori_loop(1, passes, add_pass, out)
+    return out.astype(tokens.dtype), (tokens, w1, w2, weights, order, group_sizes)
 
 
-_unsort.defvjp(_unsort_fwd, _unsort_bwd)
+def _held_experts_bwd(f_act, k, bound, res, g):
+    tokens, w1, w2, weights, order, group_sizes = res
+    # as `jax.checkpoint` does for what it computes again: the way back reads
+    # its own copies of the arguments, so none made for the way forward (the
+    # weights as the loop holds them) has to live until here
+    tokens, w1, w2 = jax.lax.optimization_barrier((tokens, w1, w2))
+    pass_rows, passes = _passes(k, bound, order, group_sizes)
+    wflat = weights.reshape(-1)
+
+    def pass_grads(b, g_tokens, g_wflat):
+        pair, tok, live, sizes = pass_rows(b)
+        ys, back = jax.vjp(lambda xs, w1, w2: _experts(f_act, xs, w1, w2, sizes, live),
+                           jnp.where(live, tokens[tok], 0), w1, w2)
+        g_rows = g[tok].astype(jnp.float32)
+        g_xs, g_w1, g_w2 = back((g_rows * wflat[pair][:, None]).astype(ys.dtype))
+        return (g_tokens.at[tok].add(jnp.where(live, g_xs, 0).astype(jnp.float32)),
+                g_wflat.at[pair].add(jnp.sum(ys.astype(jnp.float32) * g_rows, axis=-1)),
+                g_w1, g_w2)
+
+    grads = pass_grads(0, jnp.zeros(tokens.shape, jnp.float32), jnp.zeros(wflat.shape, jnp.float32))
+    if bound < order.shape[0]:
+        def further(b, acc):
+            g_tokens, g_wflat, g_w1, g_w2 = pass_grads(b, acc[0], acc[1])
+            return g_tokens, g_wflat, acc[2] + g_w1, acc[3] + g_w2
+
+        grads = jax.lax.fori_loop(1, passes, further, grads)
+    g_tokens, g_wflat, g_w1, g_w2 = grads
+    return (g_tokens.astype(tokens.dtype), g_w1, g_w2,
+            g_wflat.reshape(weights.shape).astype(weights.dtype), None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def _route(tokens, params, k, score_fn, scaling):
@@ -264,30 +353,13 @@ def moe_topk_apply(conf, params, inputs, ctx: ApplyContext):
         # pairs in (token, choice) order; those held sort first, by expert
         key = jnp.where(here, chosen - lo, held).reshape(-1)
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        inverse = jnp.argsort(order).astype(jnp.int32)
         group_sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
         rows = jnp.sum(group_sizes)
-        live = (jnp.arange(n * k) < rows)[:, None]
 
-    @jax.checkpoint
-    def held_experts(tokens, w1, w2, weights):
-        # Recomputed on the way back rather than kept: the sorted rows and the
-        # experts' hidden rows are sized for the worst case, N x k rows, of
-        # which a chip holding held / num_experts of the experts fills that
-        # share; keeping them for four layers cost 2 GB of the chip here.
-        # Rows past the last group are neither computed nor defined: they are
-        # cut out on both sides of each product, so nothing flows back
-        # through them either.
-        xs = jnp.where(live, _take_rows(tokens, order, inverse, k), 0)
-        hmid = jax.lax.ragged_dot(xs, w1, group_sizes, preferred_element_type=xs.dtype)
-        hmid = f_act(jnp.where(live, hmid, 0))
-        ys = jax.lax.ragged_dot(hmid, w2, group_sizes, preferred_element_type=xs.dtype)
-        pairs = _unsort(jnp.where(live, ys, 0), order, inverse).reshape(n, k, -1)
-        return jnp.einsum("nk,nkd->nd", weights.astype(pairs.dtype), pairs,
-                          preferred_element_type=jnp.float32).astype(tokens.dtype)
-
+    bound = held_rows_bound(n, k, held, conf.attrs["num_experts"])
     with jax.named_scope("moe_experts"):
-        out = held_experts(tokens, params["w1"], params["w2"], jnp.where(here, weights, 0.0))
+        out = _held_experts(f_act, k, bound, tokens, params["w1"], params["w2"],
+                            jnp.where(here, weights, 0.0), order, group_sizes)
 
     if "shared_w1" in params:
         with jax.named_scope("moe_shared"):
@@ -295,11 +367,14 @@ def moe_topk_apply(conf, params, inputs, ctx: ApplyContext):
                                    params["shared_w2"])
 
     # counters, a [B, 1] row each as @aux_loss above: the (token, choice)
-    # rows computed here, and the rows dropped, which this routing has none of
+    # rows computed here, the passes of the held experts' block beyond the
+    # first that they took, and the rows dropped, which this routing has none of
     b = x.data.shape[0]
-    ctx.outputs[conf.name + "@rows_held"] = SeqTensor(jnp.broadcast_to(rows, (b, 1)))
-    ctx.outputs[conf.name + "@rows_dropped"] = SeqTensor(
-        jnp.broadcast_to(jnp.sum(here) - rows, (b, 1)).astype(jnp.int32))
+    for counter, value in (("rows_held", rows),
+                           ("rows_over_bound", _further_passes(rows, bound)),
+                           ("rows_dropped", jnp.sum(here) - rows)):
+        ctx.outputs[f"{conf.name}@{counter}"] = SeqTensor(
+            jnp.broadcast_to(value, (b, 1)).astype(jnp.int32))
 
     if valid is not None:
         out = out * valid[:, None].astype(out.dtype)

@@ -87,10 +87,10 @@ def test_chunked_scan_keeps_the_states_between_chunks_not_every_steps():
 D, E, HID, SHARED, K = 6, 8, 5, 7, 3
 
 
-def _moe_net(held, shared=SHARED):
+def _moe_net(held, shared=SHARED, num_experts=E):
     reset_auto_names()
     x_in = paddle.layer.data("x", paddle.data_type.dense_vector(D))
-    m = L.moe_topk(x_in, expert_hidden=HID, num_experts=E, top_k=K, experts_held=held,
+    m = L.moe_topk(x_in, expert_hidden=HID, num_experts=num_experts, top_k=K, experts_held=held,
                    shared_hidden=shared, scaling=2.5, name="moe")
     return CompiledNetwork(Topology([m]))
 
@@ -123,14 +123,11 @@ def _routing_bias(kind, lo):
     return jnp.asarray(bias)
 
 
-@pytest.mark.parametrize("routing", ["uniform", "one_expert", "all_held"])
-def test_held_experts_match_a_plain_masked_sum(routing):
-    lo, hi, n = 2, 6, 24
-    net = _moe_net((lo, hi))
-    params, state = net.init(jax.random.PRNGKey(0))
-    params["moe"]["router_bias"] = _routing_bias(routing, lo)
-    x = jax.random.normal(jax.random.PRNGKey(1), (n, D))
-    tilt = jax.random.normal(jax.random.PRNGKey(2), (n, D))
+def _against_the_plain_sum(net, p, x, lo, hi):
+    """Value and the gradients of every leaf and of x against `_plain_moe`;
+    -> the layer's counters and the held rows counted from the plain choice."""
+    tilt = jax.random.normal(jax.random.PRNGKey(2), x.shape)
+    state = net.init(jax.random.PRNGKey(0))[1]
 
     def layer(p, x):
         outs, _ = net.apply({"moe": p}, {"x": SeqTensor(x)}, state=state, train=True)
@@ -139,19 +136,125 @@ def test_held_experts_match_a_plain_masked_sum(routing):
     def plain(p, x):
         return jnp.sum(_plain_moe(x, p, lo, hi)[0] * tilt)
 
-    (got, outs), grads = jax.value_and_grad(layer, argnums=(0, 1), has_aux=True)(params["moe"], x)
-    want, grads_ref = jax.value_and_grad(plain, argnums=(0, 1))(params["moe"], x)
+    (got, outs), grads = jax.value_and_grad(layer, argnums=(0, 1), has_aux=True)(p, x)
+    want, grads_ref = jax.value_and_grad(plain, argnums=(0, 1))(p, x)
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for a, b in zip(jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(grads_ref)):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+    _, chosen = _plain_moe(x, p, lo, hi)
+    counters = {k: int(outs[f"moe@{k}"].data[0, 0])
+                for k in ("rows_held", "rows_over_bound", "rows_dropped")}
+    return counters, int(jnp.sum((chosen >= lo) & (chosen < hi)))
+
+
+@pytest.mark.parametrize("routing", ["uniform", "one_expert", "all_held"])
+def test_held_experts_match_a_plain_masked_sum(routing):
+    lo, hi, n = 2, 6, 24
+    net = _moe_net((lo, hi))
+    params, _ = net.init(jax.random.PRNGKey(0))
+    params["moe"]["router_bias"] = _routing_bias(routing, lo)
+    x = jax.random.normal(jax.random.PRNGKey(1), (n, D))
     # the counters: every (token, choice) that fell on a held expert was
-    # computed, whatever the skew, and none dropped
-    _, chosen = _plain_moe(x, params["moe"], lo, hi)
-    held_rows = int(jnp.sum((chosen >= lo) & (chosen < hi)))
-    assert int(outs["moe@rows_held"].data[0, 0]) == held_rows
-    assert int(outs["moe@rows_dropped"].data[0, 0]) == 0
+    # computed, whatever the skew, and none dropped; half the experts held
+    # make the bound all the pairs there are, so one pass takes them
+    counters, held_rows = _against_the_plain_sum(net, params["moe"], x, lo, hi)
+    assert counters == {"rows_held": held_rows, "rows_over_bound": 0, "rows_dropped": 0}
     assert {"uniform": 0 < held_rows < n * K, "one_expert": held_rows >= n,
             "all_held": held_rows == n * K}[routing]
+
+
+# the held experts' block in passes of `held_rows_bound` rows (layers/moe.py):
+# a quarter or less of the experts held, so the bound is under the N x K pairs
+
+@pytest.mark.parametrize("routing,num_experts,held,rows,passes", [
+    ("uniform", 8, (2, 4), None, 1),            # the router's own choice: under the bound of 40
+    ("none_held", 8, (2, 4), 0, 1),             # no token chooses a held expert
+    ("exactly_the_bound", 8, (2, 4), 40, 1),    # the last row of the one pass is a held pair
+    ("all_held", 8, (2, 4), 48, 2),             # every token chooses both: a second pass of 8 rows
+    ("all_held", 16, (2, 5), 72, 3),            # bound 32 of 72 pairs: two full passes and 8 rows
+])
+def test_held_experts_in_passes_match_a_plain_masked_sum(routing, num_experts, held, rows, passes):
+    from paddle_tpu.layers.moe import held_rows_bound
+
+    (lo, hi), n = held, 24
+    bound = held_rows_bound(n, K, hi - lo, num_experts)
+    assert bound < n * K and bound == {8: 40, 16: 32}[num_experts]
+    net = _moe_net(held, num_experts=num_experts)
+    p = net.init(jax.random.PRNGKey(0))[0]["moe"]
+    x = jax.random.normal(jax.random.PRNGKey(1), (n, D))
+    bias = np.zeros(num_experts, np.float32)
+    if routing != "uniform":
+        bias[lo:hi] = -10.0 if routing == "none_held" else 10.0
+    if routing == "exactly_the_bound":
+        # the first held expert by its bias, the second by the sign of x[:, 0]: 24 + 16 rows
+        bias[lo + 1] = 0.0
+        p["router"] = p["router"].at[:, lo + 1].set(0.0).at[0, lo + 1].set(10.0)
+        x = x.at[:, 0].set(jnp.where(jnp.arange(n) < 16, 3.0, -3.0))
+    p["router_bias"] = jnp.asarray(bias)
+    counters, held_rows = _against_the_plain_sum(net, p, x, lo, hi)
+    assert counters == {"rows_held": held_rows, "rows_over_bound": passes - 1, "rows_dropped": 0}
+    assert held_rows == rows if rows is not None else 0 < held_rows <= bound
+
+
+def test_the_row_bound_follows_the_shapes():
+    from paddle_tpu.layers.moe import held_rows_bound
+
+    assert held_rows_bound(4096, 6, 8, 128) == 3072  # the benchmark's cell: twice the 1,536 expected
+    assert held_rows_bound(4096, 6, 128, 128) == 4096 * 6  # every expert held: all the pairs
+    assert held_rows_bound(24, 3, 4, 8) == 72 and held_rows_bound(24, 3, 2, 8) == 40
+    assert held_rows_bound(4100, 6, 8, 128) == 3584  # whole tiles of 512 rows from one tile on
+    for n, k, held, e in [(24, 3, 2, 8), (500, 2, 3, 64), (4096, 6, 8, 128), (8192, 8, 16, 256)]:
+        r = held_rows_bound(n, k, held, e)
+        assert 2 * n * k * held / e <= r or r == n * k
+        assert r % (512 if r >= 512 else 8) == 0 or r == n * k
+        assert held_rows_bound(n + 1, k, held, e) >= r and held_rows_bound(n, k + 1, held, e) >= r
+        assert held_rows_bound(n, k, held + 1, e) >= r >= held_rows_bound(n, k, held, 2 * e)
+
+
+def _primitives_and_row_counts(jaxpr, widths, into):
+    """Every primitive of a jaxpr and of the jaxprs inside its equations,
+    and the rows (elements over the last axis) of every result whose last
+    axis is one of `widths`."""
+    for eqn in jaxpr.eqns:
+        into[0].add(eqn.primitive.name)
+        for v in eqn.outvars:
+            shape = getattr(v.aval, "shape", ())
+            if shape and shape[-1] in widths:
+                into[1].append(int(np.prod(shape[:-1])))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _primitives_and_row_counts(sub, widths, into)
+    return into
+
+
+@pytest.mark.parametrize("held,looped", [((0, 8), False), ((2, 6), False), ((2, 4), True)])
+def test_no_array_of_the_held_experts_outgrows_the_row_bound(held, looped):
+    """The mechanism, read from the program (counts carry over from the CPU):
+    forward and backward, no result D or H wide has more rows than the bound
+    of a pass (96 here, over the 64 tokens): N x K rows appear only where the
+    bound is N x K.  With every pair inside the bound there is no loop and
+    no branch at all: one pass, by shape."""
+    from paddle_tpu.layers.moe import held_rows_bound
+
+    n, d, hid = 64, 16, 12
+    reset_auto_names()
+    x_in = paddle.layer.data("x", paddle.data_type.dense_vector(d))
+    m = L.moe_topk(x_in, expert_hidden=hid, num_experts=8, top_k=K, experts_held=held, name="moe")
+    net = CompiledNetwork(Topology([m]))
+    params, state = net.init(jax.random.PRNGKey(0))
+    bound = held_rows_bound(n, K, held[1] - held[0], 8)
+    assert bound == (96 if looped else n * K)
+
+    def loss(p, x):
+        return jnp.sum(net.apply(p, {"x": SeqTensor(x)}, state=state, train=True)[0]["moe"].data)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, jnp.ones((n, d)))
+    primitives, rows = _primitives_and_row_counts(jaxpr.jaxpr, (d, hid), (set(), []))
+    assert "ragged_dot_general" in primitives or "ragged_dot" in primitives
+    assert ("while" in primitives) == looped and "cond" not in primitives
+    assert max(rows) == bound and rows.count(bound) >= 6  # a pass's rows, both ways
 
 
 def test_padded_positions_ask_nothing_of_the_experts():

@@ -979,7 +979,7 @@ def _hybrid_step_names():
         cost, _ = hybrid_lm_cost(
             "MEM*E", 50, 16, mamba_heads=4, mamba_head_dim=8, mamba_groups=2, state_size=4,
             chunk_size=4, attn_heads=4, attn_kv_heads=2, attn_head_dim=8, num_experts=8,
-            experts_per_token=3, expert_hidden=12, shared_hidden=20, experts_held=(2, 6))
+            experts_per_token=3, expert_hidden=12, shared_hidden=20, experts_held=(2, 4))
         trainer = paddle.trainer.SGD(
             cost=cost, parameters=paddle.parameters.create(cost, seed=0),
             update_equation=paddle.optimizer.Adam(learning_rate=1e-3))
@@ -1028,13 +1028,35 @@ def test_hybrid_decoder_inner_scopes_stay_within_their_layer(hybrid_step, inner,
 
 
 def test_expert_layer_counters_ride_the_aux_outputs(hybrid_step):
-    """`<name>@rows_held` and `<name>@rows_dropped`, a [B, 1] row each as
-    `@aux_loss`: rows computed here, and none dropped, in every layer."""
+    """`<name>@rows_held`, `<name>@rows_over_bound` and `<name>@rows_dropped`,
+    an int32 [B, 1] row each as `@aux_loss`: rows computed here, the passes
+    beyond the first they took, and none dropped, in every layer."""
     import numpy as np
+
+    from paddle_tpu.layers.moe import held_rows_bound
 
     _, outs = hybrid_step
     for name in ("l1_moe", "l4_moe"):
-        held, dropped = (np.asarray(outs[f"{name}@{k}"].data) for k in ("rows_held", "rows_dropped"))
-        assert held.shape == (3, 1) and (held == held[0, 0]).all()
+        held, over, dropped = (np.asarray(outs[f"{name}@{k}"].data)
+                               for k in ("rows_held", "rows_over_bound", "rows_dropped"))
+        for counter in (held, over, dropped):
+            assert counter.shape == (3, 1) and counter.dtype == np.int32
+            assert (counter == counter[0, 0]).all()
         assert 0 < held[0, 0] <= 3 * 10 * 3  # tokens x choices
+        # 2 of 8 experts held: passes of 48 rows over the 90 pairs
+        bound = held_rows_bound(3 * 10, 3, 2, 8)
+        assert bound == 48 and over[0, 0] == max(-(-held[0, 0] // bound) - 1, 0)
         assert (dropped == 0).all()
+
+
+def test_the_held_experts_passes_run_under_the_layers_scopes(hybrid_step):
+    """The loop over the passes beyond the first, forward and backward, and
+    what runs inside it: `moe_topk:<name>` then `moe_experts`, as the
+    operations of the first pass (what `moe_layers_share` and
+    `moe_experts_roofline` read)."""
+    names, _ = hybrid_step
+    loops = [n for n in names if n.endswith("/while") or "/while/body/" in n]
+    mine = [n for n in loops if "/moe_experts/" in n]
+    assert [n for n in mine if "transpose(" in n] and [n for n in mine if "transpose(" not in n]
+    assert all("moe_topk:" in n.split("/moe_experts/")[0] for n in mine)
+    assert [n for n in mine if "ragged_dot" in n]
