@@ -4,9 +4,8 @@
 #   make lint    — static analysis: AST self-lint over paddle_tpu + bench.py
 #                  (analysis/ast_rules), graph-lint over every shipped
 #                  demo config (tests/configs/), the T106 buffer-
-#                  donation audit over the step builders (incl. the
-#                  whole-pass epoch program), the C-rules lock-
-#                  discipline lint over the threaded planes
+#                  donation audit over the step builders, the C-rules
+#                  lock-discipline lint over the threaded planes
 #                  (analysis/concurrency_lint), and the N-rules
 #                  precision-flow lint (analysis/numerics_lint) in
 #                  four legs: package probes at f32, the demo-config
@@ -21,14 +20,12 @@
 #   make verify  — the full suite, then the decode-speed gate (beam-5
 #                  nmt_generate + spec-decode/prefix-cache A/B under the
 #                  bench regression guard — any >5%-worse-than-history
-#                  metric fails the target), a bench smoke (one metric),
-#                  the AOT-cache warm-boot record (cold/warm compile
-#                  counts + wall time, dispatches-per-epoch) and the
-#                  8-device multichip dry-run compile.
+#                  metric fails the target), a bench smoke (one metric)
+#                  and the 8-device multichip dry-run compile.
 #   make bench   — the full benchmark set (one JSON line per metric).
 #   make chip-smoke — chip_smoke.py: one process drives trainer.SGD and
 #                  ServingEngine at the NMT flagship's full width (plus
-#                  ResNet-50, the AOT cache, the Pallas flash kernels,
+#                  ResNet-50, the Pallas flash kernels,
 #                  data parallelism when several chips are visible) on the
 #                  TPU; fails at once where jax sees no TPU.  No CPU_ENV:
 #                  it never sets a platform itself.
@@ -179,7 +176,6 @@ verify: test-all
 	$(CPU_ENV) $(PY) -c "import bench; print(bench.bench_allreduce_virtual8())"
 	$(CPU_ENV) $(PY) -c "import bench; print(bench.bench_scaling_virtual8())"
 	$(CPU_ENV) $(PY) -c "import bench; [print(r) for r in bench.bench_quantized()]"
-	$(CPU_ENV) $(PY) -c "import bench; [print(r) for r in bench.bench_aot_warm_boot()]"
 	$(CPU_ENV) $(PY) -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
 
 bench:

@@ -3051,175 +3051,6 @@ def _bench_master_failover_in(base: str) -> dict:
 # benchmark/README.md; here every BENCH_r*.json in the repo is the history)
 # ---------------------------------------------------------------------------
 
-def bench_aot_warm_boot() -> list:
-    """Dispatch-elimination record (core/aot_cache.py + the whole-pass
-    epoch program): two guarded metrics.
-
-    ``aot_warm_boot_compile_ms`` — a fresh process prewarms a flagship MLP
-    config's train step through ``paddle-tpu cache warm`` twice against one
-    cache dir: run 1 is the cold boot (full XLA compiles, serialized to
-    disk), run 2 the warm boot (deserialize only).  The value is the warm
-    run's compile-path wall time; the record asserts zero compiles on the
-    warm boot and carries the cold/warm ratio (acceptance: warm <= 0.5x
-    cold).  The children run with JAX_PLATFORMS=cpu set explicitly.
-
-    ``whole_pass_dispatches_per_epoch`` — cached epochs >= 2 under
-    ``whole_pass_program`` run as ONE lax.scan dispatch; the in-process A/B
-    counts host dispatches per cached epoch and times the stepwise replay
-    against the epoch program on the same sealed pass."""
-    import shutil
-    import subprocess
-    import sys
-    import tempfile
-
-    import jax
-
-    results = []
-    tmp = tempfile.mkdtemp(prefix="aot_bench_")
-    try:
-        with open(os.path.join(tmp, "conf.py"), "w") as f:
-            f.write(
-                "from paddle.trainer_config_helpers import *\n"
-                "define_py_data_sources2(train_list='t', test_list=None,\n"
-                "                        module='prov', obj='process')\n"
-                "settings(batch_size=32, learning_rate=1e-3,\n"
-                "         learning_method=AdamOptimizer())\n"
-                "img = data_layer(name='pixel', size=784)\n"
-                "h1 = fc_layer(input=img, size=128, act=ReluActivation())\n"
-                "h2 = fc_layer(input=h1, size=64, act=ReluActivation())\n"
-                "pred = fc_layer(input=h2, size=10,\n"
-                "                act=SoftmaxActivation())\n"
-                "lbl = data_layer(name='label', size=10)\n"
-                "outputs(classification_cost(input=pred, label=lbl))\n"
-            )
-        with open(os.path.join(tmp, "prov.py"), "w") as f:
-            f.write(
-                "from paddle.trainer.PyDataProvider2 import *\n"
-                "@provider(input_types=[dense_vector(784),\n"
-                "                       integer_value(10)],\n"
-                "          should_shuffle=False)\n"
-                "def process(settings, f):\n"
-                "    for i in range(100):\n"  # 32x3 + a 4-row tail: 2 rungs
-                "        yield [0.01 * (i % 7)] * 784, i % 10\n"
-            )
-        with open(os.path.join(tmp, "t"), "w") as f:
-            f.write("dummy\n")
-        env = dict(os.environ)
-        env["PYTHONPATH"] = (
-            os.path.dirname(os.path.abspath(__file__))
-            + os.pathsep + env.get("PYTHONPATH", "")
-        )
-        # explicit, never inherited: with JAX_PLATFORMS=tpu in the
-        # environment the child would ask for the chip this process holds
-        env["JAX_PLATFORMS"] = "cpu"
-
-        def boot():
-            r = subprocess.run(
-                [sys.executable, "-m", "paddle_tpu", "cache", "warm",
-                 "--dir", os.path.join(tmp, "cache"),
-                 "--config", os.path.join(tmp, "conf.py")],
-                capture_output=True, text=True, env=env, timeout=600,
-            )
-            assert r.returncode == 0, r.stderr[-2000:]
-            return json.loads(r.stdout.strip().splitlines()[-1])
-
-        cold = boot()
-        warm = boot()
-        ratio = warm["warm_s"] / max(cold["warm_s"], 1e-9)
-        note = (
-            f"warm boot deserialized {warm['loads']} executable(s) with "
-            f"{warm['compiles']} compiles vs {cold['compiles']} cold "
-            f"compiles ({cold['warm_s']:.2f}s -> {warm['warm_s']:.2f}s)"
-        )
-        results.append({
-            "metric": "aot_warm_boot_compile_ms",
-            "value": round(warm["warm_s"] * 1e3, 1),
-            "unit": "ms",
-            "cold_compile_ms": round(cold["warm_s"] * 1e3, 1),
-            "warm_vs_cold_ratio": round(ratio, 4),
-            "meets_0p5x": bool(ratio <= 0.5),
-            "cold_compiles": cold["compiles"],
-            "warm_compiles": warm["compiles"],
-            "warm_loads": warm["loads"],
-            "shapes": cold["shapes"],
-            "note": note,
-            **_CPU_CHILD_FIELDS,
-        })
-    finally:
-        shutil.rmtree(tmp, ignore_errors=True)
-
-    # -- whole-pass epoch program: dispatches + ms per cached epoch -------
-    import paddle_tpu as paddle
-    from paddle_tpu.core.topology import reset_auto_names
-    from paddle_tpu.utils.flags import reset_flags, set_flag
-    from paddle_tpu.utils.timers import global_stats
-
-    def _model():
-        reset_auto_names()
-        x = paddle.layer.data("x", paddle.data_type.dense_vector(64))
-        h = paddle.layer.fc(x, size=128, act=paddle.activation.Relu())
-        pred = paddle.layer.fc(h, size=10, act=paddle.activation.Softmax())
-        y = paddle.layer.data("y", paddle.data_type.integer_value(10))
-        return paddle.layer.classification_cost(input=pred, label=y)
-
-    rng = np.random.RandomState(0)
-    samples = [
-        (rng.randn(64).astype(np.float32), int(rng.randint(10)))
-        for _ in range(512)
-    ]
-
-    def run(whole_pass: bool, passes: int = 4):
-        reset_flags()
-        global_stats.reset()
-        set_flag("cache_pass_in_mem", True)
-        if whole_pass:
-            set_flag("whole_pass_program", True)
-        cost = _model()
-        params = paddle.parameters.create(cost, seed=0)
-        tr = paddle.trainer.SGD(
-            cost=cost, parameters=params, seed=0,
-            update_equation=paddle.optimizer.Adam(learning_rate=1e-3),
-        )
-
-        def reader():
-            yield from samples
-
-        t_by_pass = {}
-        t0 = time.perf_counter()
-
-        def handler(ev):
-            nonlocal t0
-            if isinstance(ev, paddle.event.EndPass):
-                t_by_pass[ev.pass_id] = time.perf_counter() - t0
-                t0 = time.perf_counter()
-
-        tr.train(reader=paddle.batch(reader, 32), num_passes=passes,
-                 event_handler=handler, async_load_data=False)
-        disp = global_stats.count("epoch_program/dispatches")
-        reset_flags()
-        # cached epochs only (pass 1 streams + captures in both arms)
-        cached_ms = [v * 1e3 for p, v in sorted(t_by_pass.items()) if p >= 1]
-        return cached_ms, disp, tr._pass_cache.n_batches
-
-    step_ms, _, n_batches = run(False)
-    prog_ms, dispatches, _ = run(True)
-    cached_epochs = len(prog_ms)
-    results.append({
-        "metric": "whole_pass_dispatches_per_epoch",
-        "value": round(dispatches / max(cached_epochs, 1), 2),
-        "unit": "dispatches/epoch",
-        "stepwise_dispatches_per_epoch": n_batches,
-        "stepwise_cached_epoch_ms": round(float(np.median(step_ms)), 2),
-        "program_cached_epoch_ms": round(float(np.median(prog_ms)), 2),
-        "cached_epochs_timed": cached_epochs,
-        "note": "cached epochs >= 2 under whole_pass_program run as one "
-        "lax.scan dispatch over the stacked pass cache (bit-exact vs "
-        "stepwise, tests/test_epoch_program.py); stepwise pays one host "
-        "dispatch per batch",
-    })
-    return results
-
-
 REGRESSION_TOLERANCE = 0.05  # >5% worse than best prior = flagged
 
 
@@ -3354,7 +3185,6 @@ def main() -> None:
                bench_allreduce_virtual8, bench_scaling_virtual8,
                bench_elastic_scaling, bench_quantized,
                bench_master_failover,
-               bench_aot_warm_boot,
                bench_transformer,
                bench_transformer_long_context, bench_transformer_xl_context,
                bench_lstm_textcls,

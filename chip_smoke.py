@@ -5,8 +5,8 @@
 One process drives the two main paths once, through the entry points a user
 calls, at the full width of the attention-GRU NMT flagship (word 512, hidden
 512, vocab 30,000; random seeded weights), plus the legs that are cheap to
-know now (flash attention, ResNet-50, the AOT cache, data parallelism when
-more than one chip is visible).  It refuses to run unless
+know now (flash attention, ResNet-50, data parallelism when more than one
+chip is visible).  It refuses to run unless
 ``jax.devices()[0].platform == "tpu"`` and never sets ``JAX_PLATFORMS`` or
 ``XLA_FLAGS`` itself.  It prints one JSON line per leg, goes on to the next
 leg when one fails, exits non-zero if any failed, and ends with a summary
@@ -31,7 +31,6 @@ from __future__ import annotations
 
 import json
 import os
-import shutil
 import sys
 import threading
 import time
@@ -39,9 +38,6 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-OUT_DIR = os.path.join(
-    os.path.dirname(os.path.abspath(__file__)), "chiprun_out", "chip_smoke"
-)
 NMT_FEEDING = {"src_word": 0, "trg_word": 1, "trg_next": 2}
 
 
@@ -573,45 +569,6 @@ def resnet50_train(
 
 
 # ---------------------------------------------------------------------------
-# aot_roundtrip
-# ---------------------------------------------------------------------------
-
-
-def aot_roundtrip(cache_dir: str) -> Dict[str, Any]:
-    """``AOTCache`` on a fresh directory: the first cache compiles and
-    stores, a second one on the same directory loads — and the loaded
-    executable runs."""
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.core.aot_cache import AOTCache
-    from paddle_tpu.utils.timers import StatSet
-
-    shutil.rmtree(cache_dir, ignore_errors=True)
-    fn = jax.jit(lambda x, w: jnp.tanh(x @ w).sum(axis=-1))
-    args = (jnp.ones((8, 128), jnp.float32), jnp.full((128, 128), 0.01, jnp.float32))
-    identity = {"kind": "chip_smoke", "shape": "8x128x128"}
-    want = np.asarray(fn(*args))
-
-    cold_stats, warm_stats = StatSet(), StatSet()
-    cold = AOTCache(cache_dir, stats=cold_stats)
-    cold.get_or_compile(fn, args, identity)
-    assert (cold.compiles, cold.loads) == (1, 0), cold.summary()
-    assert cold_stats.count("aot_cache/unsupported") == 0, cold.summary()
-    warm = AOTCache(cache_dir, stats=warm_stats)
-    exe = warm.get_or_compile(fn, args, identity)
-    assert (warm.compiles, warm.loads) == (0, 1), warm.summary()
-    for counter in ("corrupt", "stale", "unsupported"):
-        assert warm_stats.count(f"aot_cache/{counter}") == 0, warm.summary()
-    t0 = time.perf_counter()
-    got = np.asarray(jax.block_until_ready(exe(*args)))
-    step_s = time.perf_counter() - t0
-    np.testing.assert_allclose(got, want, rtol=1e-6)
-    return {"aot_loads": warm.loads, "aot_compiles": warm.compiles,
-            "entries": len(warm.entries()), "step_ms": round(step_s * 1e3, 3)}
-
-
-# ---------------------------------------------------------------------------
 # driver
 # ---------------------------------------------------------------------------
 
@@ -687,9 +644,6 @@ def run_all(meter: CompileMeter) -> int:
     results["nmt_serve"], _ = run_leg("nmt_serve", meter, serve)
     results["resnet50_train"], _ = run_leg(
         "resnet50_train", meter, lambda: resnet50_train(meter))
-    results["aot_roundtrip"], _ = run_leg(
-        "aot_roundtrip", meter,
-        lambda: aot_roundtrip(os.path.join(OUT_DIR, "aot_cache")))
     # after the legs that need less memory: peak_bytes_in_use is a
     # high-water mark of the process, and this leg's is the highest
     results["flash_attention"], _ = run_leg(

@@ -90,20 +90,6 @@ def _build_train_parser() -> argparse.ArgumentParser:
         "its host->device transfer; needs the pass cache enabled",
     )
     ap.add_argument(
-        "--aot_cache_dir", default=None,
-        help="persistent AOT executable cache (core/aot_cache.py): warm "
-        "boots deserialize compiled train-step/epoch-program executables "
-        "from here instead of retracing; prewarm with `paddle-tpu cache "
-        "warm`",
-    )
-    ap.add_argument(
-        "--whole_pass_program", type=_flag_bool, default=False, nargs="?",
-        const=True,
-        help="run cached epochs >= 2 as ONE on-device lax.scan program "
-        "over the stacked pass cache (O(1) host dispatches per epoch, "
-        "bit-exact vs stepwise); needs --cache_pass_in_mem",
-    )
-    ap.add_argument(
         "--checkpoint_dir", default=None,
         help="fault-tolerance plane (robustness/): write full-state "
         "checkpoints (params + optimizer state + RNG + pass/batch "
@@ -326,10 +312,6 @@ def cmd_train(argv: List[str]) -> int:
         _flags.set_flag("cache_pass_in_mem", True)
     if args.data_echo_factor is not None:
         _flags.set_flag("data_echo_factor", args.data_echo_factor)
-    if args.aot_cache_dir:
-        _flags.set_flag("aot_cache_dir", args.aot_cache_dir)
-    if args.whole_pass_program:
-        _flags.set_flag("whole_pass_program", True)
     if args.chaos:
         from paddle_tpu.robustness import chaos as _chaos
 
@@ -1790,10 +1772,10 @@ def cmd_master(argv: List[str]) -> int:
 
 
 def _donation_audit_builders():
-    """T106 over the shipped step builders: trace make_train_step,
-    make_multi_train_step, and the whole-pass epoch program on a probe MLP
-    and audit that every large carried buffer (params/opt-state/carry) is
-    donated.  Pure host-side tracing — no compile, no FLOPs."""
+    """T106 over the shipped step builders: trace make_train_step and
+    make_multi_train_step on a probe MLP and audit that every large
+    carried buffer (params/opt-state) is donated.  Pure host-side tracing
+    — no compile, no FLOPs."""
     import jax
     import jax.numpy as jnp
 
@@ -1802,12 +1784,7 @@ def _donation_audit_builders():
     from paddle_tpu.core.batch import SeqTensor
     from paddle_tpu.core.compiler import CompiledNetwork
     from paddle_tpu.core.topology import Topology, reset_auto_names
-    from paddle_tpu.trainer.step import (
-        make_epoch_program,
-        make_multi_train_step,
-        make_train_carry,
-        make_train_step,
-    )
+    from paddle_tpu.trainer.step import make_multi_train_step, make_train_step
 
     reset_auto_names()
     x = paddle.layer.data("x", paddle.data_type.dense_vector(64))
@@ -1828,7 +1805,6 @@ def _donation_audit_builders():
     stacked = jax.tree_util.tree_map(
         lambda v: jnp.stack([v] * k), batch
     )
-    carry = make_train_carry(params, state, opt_state, rng)
     diags = []
     diags += donation_audit(
         make_train_step(net, opt, mesh=None),
@@ -1840,125 +1816,11 @@ def _donation_audit_builders():
         params, state, opt_state, stacked, rng,
         source="trainer/step.py:make_multi_train_step",
     )
-    diags += donation_audit(
-        make_epoch_program(net, opt, mesh=None),
-        carry, stacked, jnp.arange(k),
-        source="trainer/step.py:make_epoch_program",
-    )
     print(
-        f"donation audit: 3 step builders traced, {len(diags)} T106 "
+        f"donation audit: 2 step builders traced, {len(diags)} T106 "
         "finding(s)"
     )
     return diags
-
-
-def cmd_cache(argv: List[str]) -> int:
-    """``paddle-tpu cache`` — the persistent AOT executable cache
-    (core/aot_cache.py) maintenance face:
-
-    * ``ls``               — entries with size + full key provenance;
-    * ``warm``             — prewarm: parse a config, stage its feed, and
-                             compile-or-load the train-step executable for
-                             every distinct batch shape the ladder realizes
-                             (fleet boots then deserialize, not retrace);
-    * ``prune --max-mb N`` — drop oldest entries until the store fits;
-    * ``clear``            — drop everything.
-
-    Each run closes with one JSON summary line (the warm-boot bench and the
-    StatSet counters aot_cache/{hit,miss,stale,corrupt} read it)."""
-    ap = argparse.ArgumentParser(
-        prog="paddle-tpu cache",
-        description="persistent AOT executable cache maintenance "
-        "(core/aot_cache.py)",
-    )
-    ap.add_argument("action", choices=["ls", "warm", "prune", "clear"])
-    ap.add_argument("--dir", required=True, help="cache directory")
-    ap.add_argument("--config", default=None,
-                    help="warm: v1 config file whose train step to prewarm")
-    ap.add_argument("--config_args", default="")
-    ap.add_argument("--batch_size", type=int, default=0,
-                    help="warm: override the config's batch size")
-    ap.add_argument("--max-shapes", type=int, default=16,
-                    help="warm: stop after this many distinct batch shapes")
-    ap.add_argument("--max-mb", type=float, default=None,
-                    help="prune: keep the store under this many MB")
-    args = ap.parse_args(argv)
-
-    from paddle_tpu.core.aot_cache import AOTCache
-
-    cache = AOTCache(args.dir)
-    if args.action == "ls":
-        for e in cache.entries():
-            key = e.get("key", {})
-            prov = ", ".join(
-                f"{k}={key[k]}" for k in
-                ("kind", "n_steps", "batch", "topology", "jax", "backend")
-                if key.get(k) is not None
-            )
-            print(
-                f"{e['file']}  {e['bytes'] / 1e6:8.2f} MB  "
-                + (f"CORRUPT: {e['corrupt']}" if "corrupt" in e else prov)
-            )
-        print(json.dumps(cache.summary()))
-        return 0
-    if args.action == "clear":
-        n = cache.clear()
-        print(json.dumps({**cache.summary(), "removed": n}))
-        return 0
-    if args.action == "prune":
-        if args.max_mb is None:
-            print("error: prune needs --max-mb", file=sys.stderr)
-            return 2
-        removed = cache.prune(int(args.max_mb * 1e6))
-        print(json.dumps({**cache.summary(), "removed": removed}))
-        return 0
-
-    # warm: compile-or-load every distinct shape the config's feed realizes
-    if not args.config:
-        print("error: warm needs --config", file=sys.stderr)
-        return 2
-    from paddle_tpu.core.batch import batch_shape_key
-    from paddle_tpu.parallel.mesh import shard_batch
-    from paddle_tpu.utils import flags as _flags
-    from paddle_tpu.v1_compat import make_batched_reader, parse_config
-
-    logging.basicConfig(
-        level=logging.INFO, format="%(asctime)s %(name)s %(message)s"
-    )
-    _flags.set_flag("aot_cache_dir", args.dir)
-    config_path = os.path.abspath(args.config)
-    parsed = parse_config(config_path, args.config_args)
-    if args.batch_size:
-        parsed.settings.batch_size = args.batch_size
-    trainer = _make_trainer(parsed, _flags.get_flag("seed"))
-    reader = make_batched_reader(
-        parsed, os.path.dirname(config_path), parsed.settings.batch_size,
-        train=True,
-    )
-    feeder = trainer._make_feeder(parsed.feeding)
-    seen = set()
-    t0 = time.time()
-    for raw in reader():
-        # shape-dedup on the HOST feeder batch: staging is shape-preserving
-        # and the scan must not pay a full-dataset H2D transfer to discover
-        # a handful of rungs — only the first batch of each new shape ever
-        # touches the device
-        fed = feeder(raw)
-        key = batch_shape_key(fed)
-        if key in seen:
-            continue
-        seen.add(key)
-        trainer.warm_compile(shard_batch(fed, trainer.mesh))
-        if len(seen) >= args.max_shapes:
-            break
-    summary = {
-        **trainer._aot_cache.summary(),
-        "config": args.config,
-        "shapes": len(seen),
-        "warm_s": round(time.time() - t0, 3),
-    }
-    print(json.dumps(summary))
-    return 0
 
 
 def cmd_lint(argv: List[str]) -> int:
@@ -2361,7 +2223,6 @@ _COMMANDS = {
     "lint": cmd_lint,
     "explore": cmd_explore,
     "fuzz": cmd_fuzz,
-    "cache": cmd_cache,
     "serve": cmd_serve,
     "route": cmd_route,
     "scenario": cmd_scenario,
@@ -2392,8 +2253,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         print("                      cocktails (arrival x chaos x netem x")
         print("                      torn checkpoints) vs the invariant set;")
         print("                      shrink + replay violation specs")
-        print("    cache             AOT executable cache: ls / warm / prune /")
-        print("                      clear a persistent compile cache dir")
         print("    serve             continuous-batching serving plane over")
         print("                      the NMT flagship (request queue + paged")
         print("                      decode cache, SLO admission/shedding,")

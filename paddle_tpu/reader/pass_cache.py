@@ -139,7 +139,7 @@ class PassCache:
         self.nbytes = 0
         self._batches: List[Any] = []
         self._bucket_counts: Dict[tuple, int] = {}
-        self._stacked = None  # capture-order stack (whole-pass program)
+        self._stacked = None  # capture-order stack (stacked_pass)
 
     @classmethod
     def from_flags(cls, reader=None, seed: Optional[int] = None,
@@ -288,22 +288,10 @@ class PassCache:
         assert self.ready, "pass cache not sealed"
         return self._batches[0]
 
-    def fits_stacked(self) -> bool:
-        """Whether holding the stacked capture-order copy IN ADDITION to
-        the per-batch cache fits the HBM budget — the whole-pass program
-        costs a second copy of the pass, and a pass captured just under
-        the budget must not silently double past it (the feed switch falls
-        back to stepwise replay instead)."""
-        return self.budget is None or 2 * self.nbytes <= self.budget
-
     def stacked(self):
         """The cached pass stacked on a leading [N, ...] axis in CAPTURE
-        order — built once, held for the cache's lifetime, and reused by
-        every epoch of the whole-pass program (the per-epoch shuffle rides
-        as a permutation argument INSIDE the program, so replaying an
-        epoch is one dispatch, not a restack).  Single-bucket only; costs
-        one extra copy of the pass in HBM — callers gate on
-        :meth:`fits_stacked` (SGD's feed switch does)."""
+        order — built once and held for the cache's lifetime.  Single-
+        bucket only; costs one extra copy of the pass in HBM."""
         assert self.ready, "pass cache not sealed; nothing to stack"
         assert self.n_buckets <= 1, (
             "stacked() needs a single shape bucket; this cache holds "
@@ -319,8 +307,7 @@ class PassCache:
         return self._stacked
 
     def epoch_perm(self, pass_id: int):
-        """This epoch's replay order as a device int32 vector — the
-        permutation argument of the whole-pass epoch program."""
+        """This epoch's replay order as a device int32 vector."""
         import jax.numpy as jnp
 
         return jnp.asarray(self.epoch_order(pass_id), jnp.int32)

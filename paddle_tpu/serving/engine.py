@@ -24,10 +24,6 @@ Decode outputs are BIT-IDENTICAL per request to the one-shot
 the gathered pages hold exactly the bytes prefill wrote, masked padding
 contributes exact zeros, and every per-row op is batch-row independent.
 
-With ``aot_cache_dir`` set (PR 8), both program families dispatch through
-the persistent serialized-executable cache, so a serving process boots
-warm: deserialize, don't retrace.
-
 **Chunked prefill** (``serving_prefill_chunk_tokens``): a prompt whose
 padded source extent exceeds the chunk bound no longer prefills as one
 monolithic encoder dispatch that stalls every decoding sequence for its
@@ -70,6 +66,7 @@ write) counted under ``trace_counts['prefill_chunk']``.
 
 from __future__ import annotations
 
+import hashlib
 import time
 from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -176,7 +173,6 @@ class ServingEngine:
         max_new_tokens: Optional[int] = None,
         block_steps: Optional[int] = None,
         prefill_chunk_tokens: Optional[int] = None,
-        aot_cache_dir: Optional[str] = None,
         int8_weights: Optional[bool] = None,
         prefix_cache: Optional[bool] = None,
         spec_decode: Optional[bool] = None,
@@ -322,10 +318,10 @@ class ServingEngine:
             prefix_cache if prefix_cache is not None
             else _flags.get_flag("serving_prefix_cache")
         )
-        from paddle_tpu.core import aot_cache as _aotmod
-
+        net = self._gen.net
+        graph = net.topology.serialize() + f"|compute={net.compute_dtype}"
         self._cache_sig = (
-            _aotmod.topology_fingerprint(self._gen.net),
+            hashlib.sha256(graph.encode()).hexdigest()[:16],
             str(jnp.dtype(self._dtype)),
             self.src_slot,
             self.src_vocab,
@@ -397,19 +393,10 @@ class ServingEngine:
         self._decode_table: Dict[Tuple[int, int], Any] = {}
         self._verify_table: Dict[Tuple[int, int], Any] = {}
         self._beam_table: Dict[Tuple[int, int, int], Any] = {}
-        self._prefill_table: Dict[tuple, Any] = {}
         self._ref_table: Dict[tuple, Any] = {}
         self._chunk_jits: Optional[Dict[str, Any]] = (
             self._make_chunk_programs() if self.prefill_chunk_tokens else None
         )
-
-        self._aot = None
-        if aot_cache_dir is None:
-            aot_cache_dir = _flags.get_flag("aot_cache_dir")
-        if aot_cache_dir:
-            from paddle_tpu.core.aot_cache import AOTCache
-
-            self._aot = AOTCache(aot_cache_dir, stats=self._stats)
 
     # ------------------------------------------------------------------
     @property
@@ -983,77 +970,23 @@ class ServingEngine:
 
         return jax.jit(beam)
 
-    def _prefill_exe(self, batch, args):
-        if self._aot is None:
-            # jax.jit dispatches by shape itself; the table only earns its
-            # keep routing distinct shapes to deserialized AOT executables
-            return self._prefill_jit
-        key = batch_shape_key(batch)
-        exe = self._prefill_table.get(key)
-        if exe is None:
-            from paddle_tpu.core import aot_cache as _aot
-
-            exe = self._aot.get_or_compile(
-                self._prefill_jit, args,
-                {
-                    "kind": "serving_prefill",
-                    "topology": _aot.topology_fingerprint(self._gen.net),
-                    "batch": str(key),
-                    "pool_rows": self._pages.pool_rows,
-                    "block_tokens": self.block_tokens,
-                    "max_slots": self.max_slots,
-                },
-            )
-            self._prefill_table[key] = exe
-        return exe
-
-    def _decode_exe(self, b_rung: int, p_rung: int, args):
+    def _decode_exe(self, b_rung: int, p_rung: int):
         key = (b_rung, p_rung)
         exe = self._decode_table.get(key)
         if exe is None:
             self._stats.incr("serving_decode/compile_miss")
             exe = self._make_decode(b_rung, p_rung)
-            if self._aot is not None:
-                from paddle_tpu.core import aot_cache as _aot
-
-                exe = self._aot.get_or_compile(
-                    exe, args,
-                    {
-                        "kind": "serving_decode",
-                        "topology": _aot.topology_fingerprint(self._gen.net),
-                        "slot_rung": b_rung,
-                        "page_rung": p_rung,
-                        "pool_rows": self._pages.pool_rows,
-                        "block_tokens": self.block_tokens,
-                        "max_slots": self.max_slots,
-                    },
-                )
             self._decode_table[key] = exe
         else:
             self._stats.incr("serving_decode/compile_hit")
         return exe
 
-    def _verify_exe(self, b_rung: int, p_rung: int, args):
+    def _verify_exe(self, b_rung: int, p_rung: int):
         key = (b_rung, p_rung)
         exe = self._verify_table.get(key)
         if exe is None:
             self._stats.incr("serving_verify/compile_miss")
             exe = self._make_verify(b_rung, p_rung)
-            if self._aot is not None:
-                from paddle_tpu.core import aot_cache as _aot
-
-                exe = self._aot.get_or_compile(
-                    exe, args,
-                    {
-                        "kind": "serving_verify",
-                        "topology": _aot.topology_fingerprint(self._gen.net),
-                        "slot_rung": b_rung,
-                        "page_rung": p_rung,
-                        "pool_rows": self._pages.pool_rows,
-                        "block_tokens": self.block_tokens,
-                        "max_slots": self.max_slots,
-                    },
-                )
             self._verify_table[key] = exe
         else:
             self._stats.incr("serving_verify/compile_hit")
@@ -1249,18 +1182,16 @@ class ServingEngine:
             else:
                 h_override[k] = resume["h"]
                 r._resume = None
-        args = (
-            self._gp, self._state, batch, self._enc_pool, self._ep_pool,
-            self._h, page_rows, slot_rows, boot_mask, h_override,
-            self._w["sp_b"],
-        )
         self.prefill_shapes.observe(batch)
-        exe = self._prefill_exe(batch, args)
         with _obs.span(
             "prefill", cat="serving", n=len(group), src_pad=int(s_pad),
             reqs=[r.req_id for _, r, _ in group],
         ):
-            self._enc_pool, self._ep_pool, self._h = exe(*args)
+            self._enc_pool, self._ep_pool, self._h = self._prefill_jit(
+                self._gp, self._state, batch, self._enc_pool, self._ep_pool,
+                self._h, page_rows, slot_rows, boot_mask, h_override,
+                self._w["sp_b"],
+            )
         if self.prefix_cache_enabled:
             # capture each cleanly-booted slot's decoder boot state (tiny
             # [H] row) — at retire its fully-written pages + this state
@@ -1408,7 +1339,7 @@ class ServingEngine:
                 self._h, self._enc_pool, self._ep_pool, slot_idx, tables,
                 enc_len, ids, live, draft, self._w_arg,
             )
-            exe = self._verify_exe(b_rung, p_rung, args)
+            exe = self._verify_exe(b_rung, p_rung)
             self._h, toks, n_emit, m_full = exe(*args)
             toks_host = np.asarray(toks)
             n_emit_host = np.asarray(n_emit)
@@ -1418,7 +1349,7 @@ class ServingEngine:
                 self._h, self._enc_pool, self._ep_pool, slot_idx, tables,
                 enc_len, ids, live, self._w_arg,
             )
-            exe = self._decode_exe(b_rung, p_rung, args)
+            exe = self._decode_exe(b_rung, p_rung)
             self._h, toks = exe(*args)
             toks_host = np.asarray(toks)  # [B,K]: ONE host sync per K tokens
             n_emit_host = None

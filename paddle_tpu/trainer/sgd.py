@@ -234,20 +234,6 @@ class SGD:
 
         self.compile_cache = CompileShapeCache("train_step")
         self._eval_cache = CompileShapeCache("eval_step")
-        # Persistent AOT executable cache (core/aot_cache.py): with the
-        # aot_cache_dir flag set, every train-step/epoch-program variant
-        # dispatches through a per-shape executable table backed by the
-        # on-disk serialized-executable store — a warm boot deserializes
-        # where a cold boot retraces.  None = today's jit dispatch path.
-        from paddle_tpu.utils import flags as _flags
-
-        aot_dir = _flags.get_flag("aot_cache_dir")
-        self._aot_cache = None
-        if aot_dir:
-            from paddle_tpu.core.aot_cache import AOTCache
-
-            self._aot_cache = AOTCache(aot_dir)
-        self._exec_table: Dict[tuple, Any] = {}  # (kind, shape key) -> exe
         # dynamic-width (batch-wide trans) weights resolve exactly ONCE, at
         # the first batch this trainer ever sees; a later batch-size change
         # must fail loudly, never silently re-draw trained weights
@@ -317,135 +303,9 @@ class SGD:
             ladder=ladder,
         )
 
-    # -- AOT executable cache dispatch (core/aot_cache.py) --------------
-    def _aot_identity(self, kind: str, batch, n_steps=None) -> Dict[str, Any]:
-        """Identity key of one compiled program variant: what program this
-        is (step kind + optional scan length), over which graph (topology
-        fingerprint incl. compute dtype), at which ladder rung (batch shape
-        key), on which mesh, with which donation signature."""
-        from paddle_tpu.core import aot_cache as _aot
-        from paddle_tpu.core.batch import batch_shape_key
-
-        return {
-            "kind": kind,
-            "n_steps": n_steps,
-            "topology": _aot.topology_fingerprint(self.network),
-            "batch": repr(batch_shape_key(batch)),
-            "mesh": _aot.mesh_fingerprint(self.mesh),
-            "donation": "(0,)" if kind == "epoch_program" else "(0, 1, 2)",
-            "model_sharded": bool(self._model_sharded),
-        }
-
-    def _aot_meta(self) -> Dict[str, Any]:
-        """Header-only key fields: mismatches make an entry STALE (retraced
-        and overwritten) rather than addressing a different file — the
-        hyperparameters and flags that change the compiled program without
-        changing which program it logically is."""
-        from paddle_tpu.core.aot_cache import optimizer_fingerprint
-        from paddle_tpu.utils.flags import get_flag
-
-        return {
-            "optimizer": optimizer_fingerprint(self.optimizer),
-            "sentinel": bool(get_flag("divergence_sentinel")),
-            "pruned": bool(self._prune_masks),
-        }
-
     def _run_train_step(self, params, state, opt_state, batch, rng):
-        """One train-step dispatch.  Without an AOT cache this is the jit
-        call; with one, each batch shape resolves once per process to a
-        compiled executable — deserialized from disk when a previous boot
-        compiled this rung (warm), ``lower().compile()`` + stored when not
-        (cold) — and every later dispatch of the shape reuses it."""
-        if self._aot_cache is None:
-            return self._train_step(params, state, opt_state, batch, rng)
-        from paddle_tpu.core.batch import batch_shape_key
-
-        key = ("train_step", batch_shape_key(batch))
-        exe = self._exec_table.get(key)
-        if exe is None:
-            exe = self._aot_cache.get_or_compile(
-                self._train_step,
-                (params, state, opt_state, batch, rng),
-                self._aot_identity("train_step", batch),
-                self._aot_meta(),
-            )
-            self._exec_table[key] = exe
-        return exe(params, state, opt_state, batch, rng)
-
-    def warm_compile(self, batch) -> bool:
-        """Populate the AOT cache for one batch shape WITHOUT running a
-        step (the ``paddle-tpu cache warm`` prewarm path: compile-or-load
-        every ladder rung offline so fleet boots deserialize).  True when
-        the shape was newly resolved this call."""
-        assert self._aot_cache is not None, "warm_compile needs aot_cache_dir"
-        from paddle_tpu.core.batch import batch_shape_key
-
-        key = ("train_step", batch_shape_key(batch))
-        if key in self._exec_table:
-            return False
-        rng = jax.random.PRNGKey(0)
-        self._exec_table[key] = self._aot_cache.get_or_compile(
-            self._train_step,
-            (self.parameters.params, self.parameters.state, self._opt_state,
-             batch, rng),
-            self._aot_identity("train_step", batch),
-            self._aot_meta(),
-        )
-        return True
-
-    # -- whole-pass on-device epoch program -----------------------------
-    def _dispatch_epoch_program(self, pass_cache, pass_id, params, state,
-                                opt_state):
-        """Run one cached epoch as ONE host dispatch (trainer/step.py
-        make_epoch_program): carried-state in, per-step metrics out.
-        Returns (params, state, opt_state, step_metrics) with
-        ``step_metrics`` a dict of host arrays stacked [n_batches, ...] —
-        one fetch, from which the caller replays the exact stepwise
-        event/sentinel bookkeeping."""
-        from paddle_tpu.core.batch import batch_shape_key
-        from paddle_tpu.trainer.step import (
-            make_epoch_program,
-            make_train_carry,
-            split_train_carry,
-        )
-
-        n = pass_cache.n_batches
-        stacked = pass_cache.stacked()
-        perm = pass_cache.epoch_perm(pass_id)
-        key = ("epoch_program", n, batch_shape_key(pass_cache.sample_batch()))
-        prog = self._exec_table.get(key)
-        if prog is None:
-            jitted = make_epoch_program(
-                self.network, self.optimizer, self.mesh, self._metrics_fn,
-                prune_masks=self._prune_masks,
-            )
-            if self._aot_cache is not None:
-                carry0 = make_train_carry(params, state, opt_state, self._rng)
-                prog = self._aot_cache.get_or_compile(
-                    jitted, (carry0, stacked, perm),
-                    self._aot_identity(
-                        "epoch_program", pass_cache.sample_batch(), n_steps=n
-                    ),
-                    self._aot_meta(),
-                )
-            else:
-                prog = jitted
-            self._exec_table[key] = prog
-            _log.info(
-                "whole-pass epoch program ready: %d steps per dispatch "
-                "(%s dispatch table)", n,
-                "aot-cached" if self._aot_cache is not None else "jit",
-            )
-        carry = make_train_carry(params, state, opt_state, self._rng)
-        with stat_timer("epoch_program"), _obs.span(
-            "epoch_program", cat="trainer", p=pass_id, n_steps=n,
-        ):
-            carry, ms = prog(carry, stacked, perm)
-        global_stats.incr("epoch_program/dispatches")
-        global_stats.incr("epoch_program/steps", n)
-        params, state, opt_state, self._rng = split_train_carry(carry)
-        step_ms = {k: np.asarray(v) for k, v in ms.items()}  # one fetch
-        return params, state, opt_state, step_ms
+        """The loop's one point of dispatch: the jitted step."""
+        return self._train_step(params, state, opt_state, batch, rng)
 
     def train(
         self,
@@ -521,11 +381,6 @@ class SGD:
                 "show_parameter_stats_period"
             )
         log_period = _flags.get_flag("log_period")
-        # whole-pass on-device epoch program: cached epochs >= 2 run as ONE
-        # lax.scan dispatch over the stacked pass cache (O(1) host round-
-        # trips per epoch), bit-exact against the stepwise loop below
-        whole_pass = _flags.get_flag("whole_pass_program")
-        whole_pass_warned = False
         feeder = self._make_feeder(feeding)
 
         def _stage(data_batch):
@@ -665,22 +520,12 @@ class SGD:
             self._pass_cache.drop()
         self._pass_cache = pass_cache
         self._pass_cache_reader = reader if pass_cache is not None else None
-        if whole_pass and pass_cache is None:
-            _log.warning(
-                "whole_pass_program requested but no device-resident pass "
-                "cache is available (needs cache_pass_in_mem or a "
-                "CACHE_PASS_IN_MEM provider, num_passes > 1, and a "
-                "non-resumed run); training stepwise",
-            )
 
         def judge_step(pass_id, bid, cost, health, grad_norm, metrics, rows):
-            """Per-step sentinel judging + report bookkeeping — the ONE
-            copy shared by the stepwise loop and the epoch-program replay,
-            so the bit-exact parity contract between the two paths cannot
-            drift through a one-sided edit.  Reads the pass-local
-            accumulators (pass_costs/pass_weights/pass_accums) from the
-            enclosing scope; emits EndIteration; returns the sentinel
-            verdict."""
+            """Per-step sentinel judging + report bookkeeping.  Reads the
+            pass-local accumulators (pass_costs/pass_weights/pass_accums)
+            from the enclosing scope; emits EndIteration; returns the
+            sentinel verdict."""
             verdict = "ok"
             if sentinel is not None and health is not None:
                 healthy = float(health) >= 0.5
@@ -750,75 +595,7 @@ class SGD:
             # retried window (truncate back to the last checkpoint's mark)
             costs_mark = 0
             accums_mark: Dict[str, np.ndarray] = {}
-            use_epoch_prog = (
-                whole_pass
-                and pass_cache is not None
-                and pass_cache.ready
-                and recovery is None  # per-step rollback anchors need the
-                and not skip          # host loop (and mid-pass resume too)
-                and pass_cache.n_buckets == 1
-                and not pass_cache.sample_shuffle
-                and pass_cache.fits_stacked()
-            )
-            if (
-                whole_pass and not use_epoch_prog and not whole_pass_warned
-                and pass_cache is not None and pass_cache.ready
-            ):
-                whole_pass_warned = True
-                reasons = []
-                if recovery is not None:
-                    reasons.append("checkpoint/rollback plane active")
-                if skip:
-                    reasons.append("mid-pass resume")
-                if pass_cache.n_buckets != 1:
-                    reasons.append(f"{pass_cache.n_buckets} shape buckets")
-                if pass_cache.sample_shuffle:
-                    reasons.append("sample_shuffle")
-                if not pass_cache.fits_stacked():
-                    reasons.append(
-                        "stacked copy would exceed pass_cache_hbm_budget_mb"
-                        " (needs 2x the cached pass)"
-                    )
-                _log.warning(
-                    "whole_pass_program requested but replaying stepwise "
-                    "(%s)", "; ".join(reasons) or "unknown",
-                )
-            if use_epoch_prog:
-                # ONE host dispatch replays the whole cached pass on
-                # device; the fetched per-step metrics then drive the SAME
-                # event/sentinel bookkeeping the stepwise loop performs,
-                # so trajectories and reports match it bit for bit
-                params, state, opt_state, step_ms = (
-                    self._dispatch_epoch_program(
-                        pass_cache, pass_id, params, state, opt_state
-                    )
-                )
-                n_steps = pass_cache.n_batches
-                rows = _batch_rows(pass_cache.sample_batch())
-                healths = step_ms.pop("health", None)
-                grad_norms = step_ms.pop("grad_norm", None)
-                for i in range(n_steps):
-                    event_handler(v2_event.BeginIteration(pass_id, i))
-                    self._step_count += 1
-                    verdict = judge_step(
-                        pass_id, i, float(step_ms["cost"][i]),
-                        None if healths is None else healths[i],
-                        None if grad_norms is None else grad_norms[i],
-                        {k: v[i] for k, v in step_ms.items()}, rows,
-                    )
-                    if verdict == "diverged":
-                        _log.error(
-                            "divergence detected at pass %d batch %d inside "
-                            "the whole-pass epoch program — no per-step "
-                            "rollback in this mode (run with checkpoint_dir "
-                            "for the stepwise path)", pass_id, i,
-                        )
-                        if sentinel is not None:
-                            sentinel.reset()
-                # the stepwise loop below sees an exhausted feed; the
-                # shared pass-end bookkeeping runs as usual
-                batches = iter(())
-            elif pass_cache is not None and pass_cache.ready:
+            if pass_cache is not None and pass_cache.ready:
                 # cached pass: device-resident replay, seed-reproducible
                 # shuffle, zero H2D — the feeder/prefetcher never runs
                 batches = pass_cache.epoch(pass_id)
@@ -931,11 +708,6 @@ class SGD:
                             self._step_count,
                             format_parameter_stats(parameter_stats(params)),
                         )
-                    # judging every step costs no extra sync here: this loop
-                    # fetches the cost scalar anyway (events need it) —
-                    # sentinel_check_interval only matters for fetch-free
-                    # multi-step dispatch loops (make_multi_train_step's folded
-                    # health/skipped_steps)
                     verdict = judge_step(
                         pass_id, bid, cost, health, grad_norm, metrics,
                         _batch_rows(batch),

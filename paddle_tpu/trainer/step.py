@@ -18,13 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from paddle_tpu.core.batch import DEFAULT_LADDER, canonicalize_batch
-from paddle_tpu.core.compiler import (
-    CompiledNetwork,
-    CompileShapeCache,
-    NetState,
-    Params,
-)
+from paddle_tpu.core.compiler import CompiledNetwork, NetState, Params
 from paddle_tpu.optimizer import Optimizer, OptState
 from paddle_tpu.parallel.mesh import DATA_AXIS
 
@@ -383,144 +377,6 @@ def make_multi_train_step(
         in_shardings=(repl, repl, repl, batch_sh, repl),
         out_shardings=(repl, repl, repl, repl),
     )
-
-
-def make_train_carry(params, state, opt_state, rng):
-    """The explicit carried-state pytree of the whole-pass epoch program:
-    params, layer state, optimizer state, the RNG chain, the divergence-
-    sentinel health scalars, and the on-device metric accumulators as ONE
-    tree — the step/feed/sentinel interface the serving plane and elastic
-    residency also consume.  ``health_min``/``skipped`` fold the sentinel
-    across the epoch; ``cost_sum``/``ok_steps`` accumulate the healthy-step
-    cost so a fetch-free multi-epoch driver still has a running mean."""
-    import jax.numpy as jnp
-
-    return {
-        "params": params,
-        "state": state,
-        "opt_state": opt_state,
-        "rng": rng,
-        "health_min": jnp.asarray(1.0, jnp.float32),
-        "skipped": jnp.asarray(0.0, jnp.float32),
-        "cost_sum": jnp.asarray(0.0, jnp.float32),
-        "ok_steps": jnp.asarray(0.0, jnp.float32),
-    }
-
-
-def split_train_carry(carry):
-    """(params, state, opt_state, rng) back out of an epoch-program carry."""
-    return carry["params"], carry["state"], carry["opt_state"], carry["rng"]
-
-
-def make_epoch_program(
-    network: CompiledNetwork,
-    optimizer: Optimizer,
-    mesh: Optional[Mesh] = None,
-    extra_metrics: Optional[
-        Callable[[Dict[str, Any]], Dict[str, jnp.ndarray]]
-    ] = None,
-    prune_masks: Optional[Params] = None,
-    sentinel: Optional[bool] = None,
-):
-    """A WHOLE training pass as one jitted on-device program:
-    ``(carry, stacked_batches, perm) -> (carry, per_step_metrics)``.
-
-    The epoch loop moves inside the XLA computation (the TF paper's
-    keep-the-iteration-loop-in-the-runtime argument; arXiv:1605.08695
-    §4.4): ``stacked_batches`` is the device-resident pass cache stacked on
-    a leading [N, ...] axis in CAPTURE order (built once, reused every
-    epoch), ``perm`` is this epoch's shuffle permutation, and the gather +
-    ``lax.scan`` of the shared step body replace O(steps/K) host dispatches
-    with exactly ONE per epoch.
-
-    Bit-exact parity with the stepwise SGD loop is a contract, not an
-    accident: the carry chains ``rng, step_rng = jax.random.split(rng)``
-    per step — the same split sequence SGD.train performs on the host — so
-    params, metrics, and the sentinel's skip decisions match the stepwise
-    path bit for bit (tests/test_epoch_program.py).  Per-step metrics come
-    back stacked [N, ...] so the host replays its event/bookkeeping loop
-    from one fetch.
-
-    Only the carry is donated: the stacked batches ARE the pass cache —
-    donating them would free HBM the next epoch replays from."""
-    step = _train_step_body(
-        network, optimizer, extra_metrics, prune_masks, sentinel=sentinel
-    )
-
-    def epoch(carry, batches, perm):
-        batches = jax.tree_util.tree_map(lambda x: x[perm], batches)
-
-        def body(c, b):
-            rng, step_rng = jax.random.split(c["rng"])
-            p, s, o, m = step(
-                c["params"], c["state"], c["opt_state"], b, step_rng
-            )
-            h = m.get("health", jnp.asarray(1.0, jnp.float32))
-            new_c = {
-                "params": p,
-                "state": s,
-                "opt_state": o,
-                "rng": rng,
-                "health_min": jnp.minimum(c["health_min"], h),
-                "skipped": c["skipped"] + (1.0 - h),
-                "cost_sum": c["cost_sum"]
-                + jnp.where(h >= 0.5, m["cost"].astype(jnp.float32), 0.0),
-                "ok_steps": c["ok_steps"] + h,
-            }
-            return new_c, m
-
-        return jax.lax.scan(body, carry, batches)
-
-    if mesh is None:
-        return jax.jit(epoch, donate_argnums=(0,))
-    repl = NamedSharding(mesh, P())
-    batch_sh = NamedSharding(mesh, P(None, DATA_AXIS))
-    return jax.jit(
-        epoch,
-        donate_argnums=(0,),
-        in_shardings=(repl, batch_sh, repl),
-        out_shardings=(repl, repl),
-    )
-
-
-def make_bucketed_train_step(
-    network: CompiledNetwork,
-    optimizer: Optimizer,
-    mesh: Optional[Mesh] = None,
-    extra_metrics: Optional[
-        Callable[[Dict[str, Any]], Dict[str, jnp.ndarray]]
-    ] = None,
-    infer_param_shardings: bool = False,
-    prune_masks: Optional[Params] = None,
-    ladder=DEFAULT_LADDER,
-    cache: Optional[CompileShapeCache] = None,
-):
-    """A train step that enforces the bucket-shape contract at the dispatch
-    boundary: every incoming batch is canonicalized to the shape ladder
-    (core.batch.canonicalize_batch) BEFORE it reaches jax.jit, so the
-    executable cache is keyed per ladder rung — bounded recompiles however
-    lengths are distributed — and every dispatch is accounted against a
-    :class:`~paddle_tpu.core.compiler.CompileShapeCache` (hit/miss counters
-    in the StatSet plane).
-
-    Returns ``(step, cache)``; ``step`` has the make_train_step signature.
-    Feeds that already ladder their shapes (DataFeeder(ladder=...)) pay only
-    the shape check; anything else — hand-built batches, exotic readers —
-    gets padded up to the nearest rung here."""
-    inner = make_train_step(
-        network, optimizer, mesh, extra_metrics,
-        infer_param_shardings=infer_param_shardings,
-        prune_masks=prune_masks,
-    )
-    if cache is None:
-        cache = CompileShapeCache("train_step")
-
-    def step(params, state, opt_state, batch, rng):
-        batch = canonicalize_batch(batch, ladder)
-        cache.observe(batch)
-        return inner(params, state, opt_state, batch, rng)
-
-    return step, cache
 
 
 def make_grad_step(

@@ -9,9 +9,6 @@ never hits.  Entry points that run on the chip (``paddle_tpu/cli.py``
   directory in code, so whoever runs the program places the cache;
 * unset — the fixed ``<checkout>/.jax_cache`` next to the package
   (git-ignored), the same path on every run of the same checkout.
-
-The AOT executable cache (``core/aot_cache.py``, the ``aot_cache_dir`` flag)
-is a different thing and stays an explicit flag.
 """
 
 from __future__ import annotations

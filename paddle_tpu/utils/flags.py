@@ -141,28 +141,6 @@ define_flag("pass_cache_hbm_budget_mb", 4096,
             "in wire form / data-axis size (uint8 224x224x3 ~ 0.15 "
             "MB/image; a batch sharded over n chips counts its largest "
             "per-device shard)")
-define_flag("aot_cache_dir", "",
-            "persistent AOT executable cache directory (core/aot_cache.py): "
-            "every train-step/epoch-program variant the shape ladder "
-            "realizes is serialized to disk after its first compile, and a "
-            "later process boot DESERIALIZES instead of paying the full XLA "
-            "retrace (warm boot).  Entries are keyed by topology "
-            "fingerprint, ladder rung, mesh, dtype/donation signature and "
-            "jax+backend version — stale or foreign entries are detected "
-            "and retraced, never loaded wrong.  Prewarm the full rung set "
-            "offline with `paddle-tpu cache warm`; empty = off (today's "
-            "retrace path)")
-define_flag("whole_pass_program", False,
-            "whole-pass on-device epoch program: when the device-resident "
-            "pass cache holds a sealed single-bucket pass, epochs >= 2 run "
-            "as ONE jitted lax.scan over the stacked cache (trainer/step."
-            "py make_epoch_program) — O(1) host dispatches per epoch "
-            "instead of one per batch, bit-exact against the stepwise "
-            "path (sentinel skip semantics included).  Requires "
-            "cache_pass_in_mem; falls back to stepwise replay for "
-            "bucketed (multi-shape) passes, sample_shuffle, or runs with "
-            "a checkpoint/rollback plane (per-step anchors need the host "
-            "loop).  Costs one extra stacked copy of the pass in HBM")
 define_flag("divergence_sentinel", True,
             "fold a device-side finiteness check of loss + gradient global-"
             "norm into the jitted train step (robustness/): one fused "
@@ -170,13 +148,6 @@ define_flag("divergence_sentinel", True,
             "non-finite step is SKIPPED on device (params/opt-state pass "
             "through unchanged) instead of corrupting the run.  The flag "
             "costs one norm reduction per step and no extra host sync")
-define_flag("sentinel_check_interval", 1,
-            "health-flag fetch cadence for FETCH-FREE dispatch loops "
-            "(multi-step scan drivers fold min-health + skip counts per "
-            "dispatch, trainer/step.py make_multi_train_step, and check "
-            "the fold every N dispatches).  SGD.train ignores this: its "
-            "loop syncs on the cost scalar every step anyway, so it "
-            "judges every step at zero extra cost")
 define_flag("sentinel_skip_limit", 3,
             "consecutive device-skipped (non-finite) steps that declare "
             "divergence and trigger rollback (robustness.recovery)")

@@ -350,31 +350,6 @@ def test_scan_early_exit_matches_full_scan(small_seq2seq):
         )
 
 
-def test_make_bucketed_train_step_canonicalizes_and_counts(small_seq2seq):
-    """Two ragged paddings of the same rung dispatch ONE compiled shape
-    through make_bucketed_train_step, and the cache says so."""
-    import jax
-    import jax.numpy as jnp
-
-    import paddle_tpu as paddle
-    from paddle_tpu.trainer.step import make_bucketed_train_step
-
-    net, params, state = small_seq2seq
-    opt = paddle.optimizer.Momentum(learning_rate=0.1, momentum=0.9)
-    step, cache = make_bucketed_train_step(net, opt, mesh=None)
-    costs = []
-    for T in (20, 30, 25):  # all round to rung 32
-        p = jax.tree_util.tree_map(jnp.array, params)
-        s = jax.tree_util.tree_map(jnp.array, state)
-        _, _, _, m = step(
-            p, s, opt.init(p), _nmt_batch(T), jax.random.PRNGKey(0)
-        )
-        costs.append(float(m["cost"]))
-    assert cache.n_shapes == 1
-    assert cache.misses == 1 and cache.hits == 2
-    assert abs(costs[0] - costs[1]) < 1e-5 and abs(costs[1] - costs[2]) < 1e-5
-
-
 # ---------------------------------------------------------------------------
 # flag plumbing
 # ---------------------------------------------------------------------------

@@ -88,11 +88,6 @@ def test_resnet_train(meter):
     assert report["steps"] == 2 and np.isfinite(report["costs"]).all()
 
 
-def test_aot_roundtrip(tmp_path):
-    report = chip_smoke.aot_roundtrip(str(tmp_path / "aot"))
-    assert report == {**report, "aot_loads": 1, "aot_compiles": 0, "entries": 1}
-
-
 def test_main_refuses_to_run_without_a_tpu():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run(
@@ -130,7 +125,7 @@ def test_last_line_has_exactly_the_keys_the_driver_reads(
         monkeypatch.setattr(chip_smoke, name, leg)
 
     stub("nmt_train", ({"first_cost": 2.0}, "parameters"))
-    for name in ("nmt_serve", "resnet50_train", "aot_roundtrip", "flash_attention"):
+    for name in ("nmt_serve", "resnet50_train", "flash_attention"):
         stub(name, {})
     code = chip_smoke.run_all(meter)
     lines = [json.loads(l) for l in capsys.readouterr().out.strip().splitlines()]
@@ -141,7 +136,7 @@ def test_last_line_has_exactly_the_keys_the_driver_reads(
     }
     assert code == (0 if failing is None else 1)
     # more than one device here, so the data-parallel leg ran too
-    assert lines[-2]["leg"] == "summary" and len(lines[-2]["legs"]) == 6
+    assert lines[-2]["leg"] == "summary" and len(lines[-2]["legs"]) == 5
     assert [n for n, ok in lines[-2]["legs"].items() if not ok] == (
         [failing] if failing else [])
 

@@ -22,6 +22,10 @@ import pytest
 REF = "/root/reference"
 REF_TESTS = f"{REF}/paddle/trainer/tests"
 OPT_A = f"{REF_TESTS}/sample_trainer_config_opt_a.conf"
+# a config the repo holds, for the tests of this CLI's own faces
+DEMO_MLP = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "configs", "demo_mnist_mlp.py"
+)
 
 
 def run_cli(args, cwd=None, timeout=900):
@@ -116,9 +120,9 @@ def test_checkgrad_job_passes():
 
 
 def test_dump_config_prints_topology():
-    r = run_cli(["dump_config", f"{REF}/v1_api_demo/mnist/light_mnist.py"])
+    r = run_cli(["dump_config", DEMO_MLP])
     assert r.returncode == 0, r.stderr[-2000:]
-    assert "conv" in r.stdout and "pixel" in r.stdout
+    assert "fc __fc_layer_0__ size=128" in r.stdout and "pixel" in r.stdout
 
 
 # ---------------------------------------------------------------------------
@@ -237,12 +241,38 @@ def test_merge_model_roundtrip(tmp_path):
     assert "Test cost" in r.stdout
 
 
-def test_reference_train_sh_flag_lines_accepted():
+def _write_provider_config(d):
+    """A config with a provider of its own beside it: what `train` needs to
+    get as far as its reader."""
+    (d / "conf.py").write_text(
+        "from paddle.trainer_config_helpers import *\n"
+        "define_py_data_sources2(train_list='train.list', test_list=None,\n"
+        "                        module='prov', obj='process')\n"
+        "settings(batch_size=4, learning_rate=1e-3,\n"
+        "         learning_method=MomentumOptimizer())\n"
+        "img = data_layer(name='pixel', size=12)\n"
+        "lbl = data_layer(name='label', size=3)\n"
+        "fc1 = fc_layer(input=img, size=3, act=SoftmaxActivation())\n"
+        "outputs(classification_cost(input=fc1, label=lbl))\n"
+    )
+    (d / "prov.py").write_text(
+        "from paddle.trainer.PyDataProvider2 import *\n"
+        "@provider(input_types=[dense_vector(12), integer_value(3)])\n"
+        "def process(settings, f):\n"
+        "    for i in range(16):\n"
+        "        yield [0.125 * (i % 8)] * 12, i % 3\n"
+    )
+    (d / "train.list").write_text("dummy\n")
+    return d / "conf.py"
+
+
+def test_reference_train_sh_flag_lines_accepted(tmp_path):
     """A reference train.sh command line (mnist/train.sh passes
     --test_all_data_in_one_period and friends) must run — unknown gflags
     are warned about, never fatal."""
+    conf = _write_provider_config(tmp_path)
     r = run_cli([
-        "train", f"--config={OPT_A}", "--num_passes=0", "--batch_size=400",
+        "train", f"--config={conf}", "--num_passes=0", "--batch_size=400",
         "--test_all_data_in_one_period=1", "--num_gradient_servers=1",
         "--nics=eth0", "--ports_num=1",
     ])
@@ -252,17 +282,17 @@ def test_reference_train_sh_flag_lines_accepted():
     # typos of SUPPORTED flags and stray tokens stay fatal — a multi-hour
     # run must not silently drop --save_dir because of a typo
     r = run_cli([
-        "train", f"--config={OPT_A}", "--num_passes=0", "--save_dri=/tmp/x",
+        "train", f"--config={conf}", "--num_passes=0", "--save_dri=/tmp/x",
     ])
     assert r.returncode == 2
     assert "unrecognized arguments" in r.stderr
-    r = run_cli(["train", f"--config={OPT_A}", "num_passes=5"])
+    r = run_cli(["train", f"--config={conf}", "num_passes=5"])
     assert r.returncode == 2
 
     # gflags separate-value and --no<flag> boolean-negation spellings of
     # ignored reference flags must also pass, including negative values
     r = run_cli([
-        "train", f"--config={OPT_A}", "--num_passes=0",
+        "train", f"--config={conf}", "--num_passes=0",
         "--nics", "eth0", "--gpu_id", "-1", "--nolocal", "--notest_wait",
     ])
     assert r.returncode == 0, r.stderr[-2000:]
@@ -272,7 +302,7 @@ def test_reference_train_sh_flag_lines_accepted():
     # value — it stays a hard error (would otherwise silently drop a
     # mistyped option)
     r = run_cli([
-        "train", f"--config={OPT_A}", "--nolocal", "batch_size=32",
+        "train", f"--config={conf}", "--nolocal", "batch_size=32",
     ])
     assert r.returncode == 2
     assert "unrecognized arguments" in r.stderr
@@ -301,7 +331,7 @@ def test_make_diagram_writes_dot(tmp_path):
     """make_diagram renders a config to Graphviz dot
     (submit_local.sh.in make_diagram -> python -m paddle.utils.make_model_diagram)."""
     out = tmp_path / "net.dot"
-    r = run_cli(["make_diagram", OPT_A, str(out)])
+    r = run_cli(["make_diagram", DEMO_MLP, str(out)])
     assert r.returncode == 0, r.stderr[-2000:]
     text = out.read_text()
     assert text.startswith("digraph")

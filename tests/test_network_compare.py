@@ -4,6 +4,8 @@ configurations must produce identical outputs).  Here: the recurrent_group
 compositions (gru_group / lstmemory_group) vs the fused single-scan layers
 (grumemory / lstmemory) with tied parameters, on variable-length batches."""
 
+import os
+
 import jax
 import numpy as np
 import pytest
@@ -180,8 +182,6 @@ def _tie_by_signature(src_tree, dst_tree):
 
 
 def _build(conf_path, config_args=""):
-    import os
-
     from paddle_tpu.v1_compat import parse_config
 
     old = os.getcwd()
@@ -195,6 +195,10 @@ def _build(conf_path, config_args=""):
     return p, net, params, state
 
 
+@pytest.mark.skipif(
+    not os.path.isdir(GSERVER),
+    reason=f"needs the reference's own .conf pairs under {GSERVER}",
+)
 @pytest.mark.parametrize(
     "pair",
     ["concat_dotmul", "concat_fullmatrix", "concat_slice", "concat_table",

@@ -163,8 +163,8 @@ def test_prefill_once_zero_dispatches_bit_identical(small_gen):
 
     before = dict(eng.trace_counts)
     dispatches = []
-    orig_exe = eng._prefill_exe
-    eng._prefill_exe = lambda *a: (dispatches.append(1), orig_exe(*a))[1]
+    orig_exe = eng._prefill_jit
+    eng._prefill_jit = lambda *a: (dispatches.append(1), orig_exe(*a))[1]
     (r2,) = run_all(eng, [Request(src)])
     assert r2.tokens == golden  # bit-identical through the shared blocks
     assert eng.prefix_hits == 1
