@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import dataclasses
 import logging
 import os
 import statistics
@@ -55,33 +56,43 @@ _SLOW_STEP_RATIO = 2.0
 _SLOW_STEP_MIN_MS = 50.0
 
 
+_STEP_CHILDREN = ("feed_wait", "train_step", "block_fetch")
+
+
 @contextlib.contextmanager
 def _step_span(pass_id: int, bid: int, recent: collections.deque):
     """The parent span ``step`` of ONE iteration of the stepwise loop, from
-    before the batch is taken to after the step's last bookkeeping.
+    before the batch is taken to after the iteration's last bookkeeping.
 
-    Yields a dict the loop hangs its three child spans on
+    Yields a dict the loop hangs its child spans on
     (``with _obs.span(...) as phase["feed_wait"]`` / ``"train_step"`` /
-    ``"block_fetch"``); what of the step they do not cover — handlers,
-    judge_step, compile_cache.observe, recovery bookkeeping — is the step's
-    self time, so the four numbers partition the step by construction.
+    ``"block_fetch"``), at most one of each; what of the iteration they do
+    not cover — handlers, judge_step, compile_cache.observe, recovery
+    bookkeeping — is its self time, so the four numbers partition it by
+    construction.  The loop keeps one step in flight where it may (see
+    ``SGD.train``): the iteration that dispatches batch ``bid`` then fetches
+    the cost of the step BEFORE it, whose id the loop leaves under
+    ``phase["fetched"]``; the first iteration of a pass fetches nothing, and
+    the one that finds the pass exhausted dispatches nothing and fetches the
+    last step's cost.
 
-    On exit (``continue``/``return`` included) the step's length joins
+    On exit (``continue``/``return`` included) the iteration's length joins
     ``recent`` and, where it stands out of their median, one ``slow_step``
-    instant lands in the always-on ring with the four phases, beside a
-    ``slow_steps`` count and a log line — the record a ``--trace 0`` job
-    leaves of WHERE a stall sat.  The iteration that finds the pass
-    exhausted holds a ``feed_wait`` alone and is no step: it is skipped.
+    instant lands in the always-on ring with the four phases and the id of
+    the step ``fetch_ms`` waited for, beside a ``slow_steps`` count and a
+    log line — the record a ``--trace 0`` job leaves of WHERE a stall sat.
+    An iteration that neither dispatched nor fetched (a ``feed_wait`` alone:
+    the pass was exhausted with nothing in flight) is skipped.
     Rides the spans' own clock readings; disarmed, nothing is judged."""
     phase: Dict[str, Any] = {}
     with _obs.span("step", cat="trainer", p=pass_id, b=bid) as whole:
         yield phase
-    times = [whole] + [
-        phase.get(k) for k in ("feed_wait", "train_step", "block_fetch")
-    ]
-    if any(t is None or t[1] is None for t in times):
-        return  # recorder off (or switched mid-step), or no step was run
-    ms, wait_ms, dispatch_ms, fetch_ms = ((t[1] - t[0]) * 1e3 for t in times)
+    spans = [whole] + [phase.get(k, (0.0, 0.0)) for k in _STEP_CHILDREN]
+    if any(t is None or t[1] is None for t in spans):
+        return  # recorder off (or switched mid-step)
+    if "train_step" not in phase and "block_fetch" not in phase:
+        return  # nothing was dispatched or fetched
+    ms, wait_ms, dispatch_ms, fetch_ms = ((t[1] - t[0]) * 1e3 for t in spans)
     median = statistics.median(recent) if recent else None
     recent.append(ms)
     if (
@@ -90,18 +101,46 @@ def _step_span(pass_id: int, bid: int, recent: collections.deque):
         or ms - median < _SLOW_STEP_MIN_MS
     ):
         return
+    fetched = phase.get("fetched")
     parts = dict(
         ms=ms, feed_wait_ms=wait_ms, dispatch_ms=dispatch_ms,
         fetch_ms=fetch_ms, self_ms=ms - wait_ms - dispatch_ms - fetch_ms,
     )
-    _obs.instant("slow_step", cat="trainer", p=pass_id, b=bid, **parts)
+    _obs.instant(
+        "slow_step", cat="trainer", p=pass_id, b=bid, fetched=fetched, **parts
+    )
     global_stats.incr("slow_steps")
     _log.warning(
         "slow_step pass %d batch %d: %.1f ms against a median of %.1f "
-        "(feed_wait %.1f, dispatch %.1f, fetch %.1f, self %.1f)",
-        pass_id, bid, ms, median, wait_ms, dispatch_ms, fetch_ms,
+        "(feed_wait %.1f, dispatch %.1f, fetch %.1f of batch %s, self %.1f)",
+        pass_id, bid, ms, median, wait_ms, dispatch_ms, fetch_ms, fetched,
         parts["self_ms"],
     )
+
+
+@dataclasses.dataclass
+class _IssuedStep:
+    """What a dispatched step leaves for the host to settle once its cost
+    is fetched: its place, what of the step's outputs the bookkeeping reads,
+    and what falls due at it.  ``rows`` is the count, not the batch, so the
+    batch's buffers can go while the step is in flight."""
+
+    pass_id: int
+    bid: int
+    count: int  # the trainer's step count with this step applied
+    rows: int
+    is_live: bool  # taken from the reader, not from a rollback's replay
+    metrics: Dict[str, Any]
+    health: Any
+    grad_norm: Any
+    saves: bool  # save_dir + saving_period_by_batches fall due at it
+    shows_stats: bool  # show_parameter_stats_period falls due at it
+
+    @property
+    def reads_params(self) -> bool:
+        """Settling it reads the parameters as THIS step left them, which
+        the next dispatch donates: it is settled before that dispatch."""
+        return self.saves or self.shows_stats
 
 
 class SGD:
@@ -329,6 +368,28 @@ class SGD:
         (reference: Trainer.cpp:454-488, flags saving_period /
         saving_period_by_batches / start_pass).
 
+        One step in flight, and the order of events.  An iteration takes
+        the next batch and dispatches its step BEFORE it waits for the cost
+        of the step before, so the device finds step N+1 queued when step N
+        ends; the loop never holds more than one unsettled step.  The
+        contract: ``BeginIteration(N)`` precedes N's dispatch;
+        ``EndIteration(N)`` comes once N's cost is on the host, in order,
+        exactly once, possibly after ``BeginIteration(N+1)``; every
+        ``EndIteration`` of a pass precedes its ``EndPass``.  Each step
+        computes the bits it would compute alone (the same jitted function
+        on the same arguments).  Where settling step N reads or publishes
+        the parameters as N left them — which N+1's dispatch donates — N
+        is settled first: always with a ``checkpoint_dir`` (rollback
+        windows, periodic checkpoints, the preemption guard, ``resume``'s
+        trajectory) or the numerics sanitizer armed, and at each step at
+        which ``save_dir`` + ``saving_period_by_batches`` or
+        ``show_parameter_stats_period`` falls due (StatSet
+        ``run_ahead_steps`` counts the steps dispatched behind an unsettled
+        one, ``run_ahead_drains`` those settled early for what was due).
+        ``trainer.parameters`` is published at pass ends, checkpoints and
+        saves only: a handler that reads it mid-pass sees the last
+        published, since donated, buffers.
+
         async_load_data (reference TrainData(async_load_data=...) +
         DataProvider.h's double-buffer queue): run the host-side feed —
         converters, sharding, the device_put issue — on a background thread
@@ -521,33 +582,161 @@ class SGD:
         self._pass_cache = pass_cache
         self._pass_cache_reader = reader if pass_cache is not None else None
 
-        def judge_step(pass_id, bid, cost, health, grad_norm, metrics, rows):
+        def judge_step(step: _IssuedStep, cost: float) -> str:
             """Per-step sentinel judging + report bookkeeping.  Reads the
             pass-local accumulators (pass_costs/pass_weights/pass_accums)
             from the enclosing scope; emits EndIteration; returns the
             sentinel verdict."""
             verdict = "ok"
-            if sentinel is not None and health is not None:
-                healthy = float(health) >= 0.5
-                if healthy and grad_norm is not None:
+            if sentinel is not None and step.health is not None:
+                healthy = float(step.health) >= 0.5
+                if healthy and step.grad_norm is not None:
                     global_stats.observe(
-                        "robustness.grad_norm", float(grad_norm)
+                        "robustness.grad_norm", float(step.grad_norm)
                     )
                 verdict = sentinel.observe(cost, healthy)
-            if log_period and self._step_count % log_period == 0:
-                _log.info("pass %d batch %d cost %.6f", pass_id, bid, cost)
+            if log_period and step.count % log_period == 0:
+                _log.info(
+                    "pass %d batch %d cost %.6f", step.pass_id, step.bid, cost
+                )
             evaluator: Dict[str, float] = {}
             if verdict == "ok":
                 pass_costs.append(cost)
-                pass_weights.append(rows)
-                evaluator, accums = self._split_metrics(metrics)
+                pass_weights.append(step.rows)
+                evaluator, accums = self._split_metrics(step.metrics)
                 for k, v in accums.items():
                     pass_accums[k] = pass_accums.get(k, 0) + v
                 evaluator.update(self._finalize(accums))
             event_handler(
-                v2_event.EndIteration(pass_id, bid, cost, evaluator)
+                v2_event.EndIteration(step.pass_id, step.bid, cost, evaluator)
             )
             return verdict
+
+        def publish() -> None:
+            """``trainer.parameters`` and the optimizer state become the
+            loop's working values: at checkpoints, saves and pass ends, and
+            only while no dispatch has donated them."""
+            self.parameters.params, self.parameters.state = params, state
+            self._opt_state = opt_state
+
+        def settle(step: _IssuedStep, phase: Dict[str, Any]) -> bool:
+            """Waits for an issued step's cost (the iteration's
+            ``block_fetch``) and does everything that follows from it, in
+            the order the loop always had: the kill drill, the parameter
+            stats, judge_step -> EndIteration, the sentinel's verdict and
+            rollback, the periodic checkpoint, the batch-period save, the
+            preemption guard.  Whatever of it reads or publishes
+            ``params`` runs only while they are still this step's: with a
+            checkpoint_dir or the numerics sanitizer nothing is dispatched
+            behind a step before it is settled, and a step that
+            ``reads_params`` is settled before the next dispatch.  -> True
+            when the job was preempted and ``train`` is to return."""
+            nonlocal params, state, opt_state, pass_accums
+            nonlocal costs_mark, accums_mark, replay
+            pass_id, bid = step.pass_id, step.bid
+            with _obs.span(
+                "block_fetch", cat="trainer", b=bid
+            ) as phase["block_fetch"]:
+                cost = float(step.metrics["cost"])
+            phase["fetched"] = bid
+            if _chaos.fire("kill"):  # hard-preemption drill: no flush
+                _chaos.kill_self()
+            if step.shows_stats:
+                # reference TrainerInternal.cpp:83-110 per-param stats log
+                from paddle_tpu.utils.debug import (
+                    format_parameter_stats,
+                    parameter_stats,
+                )
+
+                _log.info(
+                    "parameter stats @ step %d:\n%s",
+                    step.count,
+                    format_parameter_stats(parameter_stats(params)),
+                )
+            verdict = judge_step(step, cost)
+            if num_san is not None and (
+                verdict in ("skip", "diverged") or not np.isfinite(cost)
+            ):
+                # name the op that went non-finite, not just the step
+                num_san.postmortem(f"{verdict} at pass {pass_id} batch {bid}")
+            if not step.is_live and not replay and recovery is not None:
+                recovery.replay_done()  # window re-applied cleanly
+            if verdict == "diverged":
+                if recovery is None:
+                    _log.error(
+                        "divergence detected at pass %d batch %d but no "
+                        "checkpoint_dir is set — cannot roll back",
+                        pass_id, bid,
+                    )
+                    if sentinel is not None:
+                        sentinel.reset()
+                else:
+                    action, window = recovery.on_divergence()
+                    if action != "none":
+                        # restore_fn updated self.*; resync the loop's
+                        # working refs and drop the undone bookkeeping
+                        params = self.parameters.params
+                        state = self.parameters.state
+                        opt_state = self._opt_state
+                        del pass_costs[costs_mark:]
+                        del pass_weights[costs_mark:]
+                        pass_accums = {
+                            k: np.copy(v) for k, v in accums_mark.items()
+                        }
+                        if sentinel is not None:
+                            sentinel.reset()
+                        if action == "retry":
+                            replay = deque(window)
+                return False
+            if (
+                recovery is not None
+                and verdict == "ok"
+                and checkpoint_period_batches
+                and not recovery.replaying
+                and (sentinel is None or sentinel.steady)
+                and step.count % checkpoint_period_batches == 0
+            ):
+                publish()
+                recovery.checkpoint(
+                    step.count,
+                    {
+                        "step_count": step.count,
+                        "pass_id": pass_id,
+                        "batch_id": bid,
+                    },
+                )
+                costs_mark = len(pass_costs)
+                accums_mark = {k: np.copy(v) for k, v in pass_accums.items()}
+            if step.saves:
+                publish()
+                self.save_pass(save_dir, pass_id, batch_id=bid + 1)
+            if guard is not None and guard.triggered:
+                # preemption: finish THIS step's bookkeeping, persist a
+                # synchronous final checkpoint + marker, hand back
+                publish()
+                extra = {
+                    "step_count": step.count,
+                    "pass_id": pass_id,
+                    "batch_id": bid,
+                    "preempted": True,
+                }
+                self.save_checkpoint(manager, step=step.count, extra=extra)
+                write_marker(checkpoint_dir, {**extra, "signal": guard.signum})
+                self.preempted = True
+                _log.warning(
+                    "preempted at pass %d batch %d (step %d): state "
+                    "checkpointed under %s; restart with resume=True",
+                    pass_id, bid, step.count, checkpoint_dir,
+                )
+                return True
+            return False
+
+        # the loop may keep one step in flight unless settling a step reads
+        # the parameters as that step left them (rollback windows, periodic
+        # checkpoints, the preemption guard and resume's bit-for-bit
+        # trajectory; the sanitizer's re-execution): the next dispatch
+        # donates them
+        run_ahead = recovery is None and num_san is None
 
         params, state = self.parameters.params, self.parameters.state
         opt_state = self._opt_state
@@ -623,8 +812,9 @@ class SGD:
                         b for bb in batches for b in (bb,) * echo_factor
                     )
             live = iter(batches)
-            replay: deque = deque()
+            replay = deque()
             batch_id = skip - 1
+            in_flight: Optional[_IssuedStep] = None  # dispatched, unsettled
             while True:
                 bid = replay[0][1] if replay else batch_id + 1
                 with _step_span(pass_id, bid, self._step_ms) as phase:
@@ -637,12 +827,25 @@ class SGD:
                             _, _, batch = replay.popleft()
                             is_live = False
                         else:
-                            try:
-                                batch = next(live)
-                            except StopIteration:
-                                break
+                            batch = next(live, None)
                             batch_id = bid
                             is_live = True
+                    if in_flight is not None and (
+                        batch is None or in_flight.reads_params
+                    ):
+                        # the last step of the pass; or one whose save or
+                        # stats read what the next dispatch would donate
+                        if batch is not None:
+                            global_stats.incr("run_ahead_drains")
+                            _obs.instant(
+                                "run_ahead_drain", cat="trainer",
+                                p=pass_id, b=in_flight.bid,
+                            )
+                        if settle(in_flight, phase):
+                            return
+                        in_flight = None
+                    if batch is None:
+                        break  # the pass is exhausted, and settled
                     if not self._width_resolved:
                         # fc/matrix-projection weights over a whole-minibatch
                         # trans have a batch-dependent height; the FIRST batch
@@ -666,9 +869,10 @@ class SGD:
                         )
                     if is_live and recovery is not None:
                         recovery.record(pass_id, bid, batch)
-                    # obs: DISPATCH (issue the async jitted step) then BLOCK
-                    # (the host sync on the fetched cost scalar) — the split
-                    # that shows whether a slow step is compute or host-feed
+                    # obs: DISPATCH (issue the async jitted step); the BLOCK
+                    # (the host sync on a fetched cost scalar) is settle's —
+                    # the split that shows whether a slow step is compute or
+                    # host-feed
                     with stat_timer("train_step"), _obs.span(
                         "train_step", cat="trainer", p=pass_id, b=bid,
                     ) as phase["train_step"]:
@@ -685,127 +889,34 @@ class SGD:
                             params, state, opt_state, batch, step_rng
                         )
                     self._step_count += 1
-                    health = metrics.pop("health", None)
-                    grad_norm = metrics.pop("grad_norm", None)
-                    with _obs.span(
-                        "block_fetch", cat="trainer", b=bid
-                    ) as phase["block_fetch"]:
-                        cost = float(metrics["cost"])
-                    if _chaos.fire("kill"):  # hard-preemption drill: no flush
-                        _chaos.kill_self()
-                    if (
-                        show_parameter_stats_period
-                        and self._step_count % show_parameter_stats_period == 0
-                    ):
-                        # reference TrainerInternal.cpp:83-110 per-param stats log
-                        from paddle_tpu.utils.debug import (
-                            format_parameter_stats,
-                            parameter_stats,
-                        )
-
-                        _log.info(
-                            "parameter stats @ step %d:\n%s",
-                            self._step_count,
-                            format_parameter_stats(parameter_stats(params)),
-                        )
-                    verdict = judge_step(
-                        pass_id, bid, cost, health, grad_norm, metrics,
-                        _batch_rows(batch),
+                    issued = _IssuedStep(
+                        pass_id, bid, self._step_count, _batch_rows(batch),
+                        is_live, metrics,
+                        health=metrics.pop("health", None),
+                        grad_norm=metrics.pop("grad_norm", None),
+                        saves=bool(
+                            save_dir
+                            and saving_period_by_batches
+                            and (bid + 1) % saving_period_by_batches == 0
+                        ),
+                        shows_stats=bool(
+                            show_parameter_stats_period
+                            and self._step_count % show_parameter_stats_period
+                            == 0
+                        ),
                     )
-                    if num_san is not None and (
-                        verdict in ("skip", "diverged")
-                        or not np.isfinite(cost)
-                    ):
-                        # name the op that went non-finite, not just the step
-                        num_san.postmortem(
-                            f"{verdict} at pass {pass_id} batch {bid}"
-                        )
-                    if not is_live and not replay and recovery is not None:
-                        recovery.replay_done()  # window re-applied cleanly
-                    if verdict == "diverged":
-                        if recovery is None:
-                            _log.error(
-                                "divergence detected at pass %d batch %d but no "
-                                "checkpoint_dir is set — cannot roll back",
-                                pass_id, bid,
-                            )
-                            if sentinel is not None:
-                                sentinel.reset()
-                        else:
-                            action, window = recovery.on_divergence()
-                            if action != "none":
-                                # restore_fn updated self.*; resync the loop's
-                                # working refs and drop the undone bookkeeping
-                                params = self.parameters.params
-                                state = self.parameters.state
-                                opt_state = self._opt_state
-                                del pass_costs[costs_mark:]
-                                del pass_weights[costs_mark:]
-                                pass_accums = {
-                                    k: np.copy(v) for k, v in accums_mark.items()
-                                }
-                                if sentinel is not None:
-                                    sentinel.reset()
-                                if action == "retry":
-                                    replay = deque(window)
-                        continue
-                    if (
-                        recovery is not None
-                        and verdict == "ok"
-                        and checkpoint_period_batches
-                        and not recovery.replaying
-                        and (sentinel is None or sentinel.steady)
-                        and self._step_count % checkpoint_period_batches == 0
-                    ):
-                        self.parameters.params, self.parameters.state = params, state
-                        self._opt_state = opt_state
-                        recovery.checkpoint(
-                            self._step_count,
-                            {
-                                "step_count": self._step_count,
-                                "pass_id": pass_id,
-                                "batch_id": bid,
-                            },
-                        )
-                        costs_mark = len(pass_costs)
-                        accums_mark = {
-                            k: np.copy(v) for k, v in pass_accums.items()
-                        }
-                    if (
-                        save_dir
-                        and saving_period_by_batches
-                        and (bid + 1) % saving_period_by_batches == 0
-                    ):
-                        self.parameters.params, self.parameters.state = params, state
-                        self._opt_state = opt_state
-                        self.save_pass(save_dir, pass_id, batch_id=bid + 1)
-                    if guard is not None and guard.triggered:
-                        # preemption: finish THIS step's bookkeeping, persist a
-                        # synchronous final checkpoint + marker, hand back
-                        self.parameters.params, self.parameters.state = params, state
-                        self._opt_state = opt_state
-                        extra = {
-                            "step_count": self._step_count,
-                            "pass_id": pass_id,
-                            "batch_id": bid,
-                            "preempted": True,
-                        }
-                        self.save_checkpoint(
-                            manager, step=self._step_count, extra=extra
-                        )
-                        write_marker(
-                            checkpoint_dir, {**extra, "signal": guard.signum}
-                        )
-                        self.preempted = True
-                        _log.warning(
-                            "preempted at pass %d batch %d (step %d): state "
-                            "checkpointed under %s; restart with resume=True",
-                            pass_id, bid, self._step_count, checkpoint_dir,
-                        )
+                    if in_flight is not None:
+                        # dispatched behind its predecessor, which the
+                        # device is still running: wait for that one now
+                        global_stats.incr("run_ahead_steps")
+                        settling, in_flight = in_flight, issued
+                    elif run_ahead:
+                        settling, in_flight = None, issued
+                    else:
+                        settling = issued
+                    if settling is not None and settle(settling, phase):
                         return
-            # persist latest values so checkpoints/test see them
-            self.parameters.params, self.parameters.state = params, state
-            self._opt_state = opt_state
+            publish()  # so checkpoints and test() see the pass's values
             pass_metrics = {
                 # per-SAMPLE mean: weight each batch by its row count (batch
                 # sizes vary across rungs under the bucketed feed)
@@ -836,8 +947,7 @@ class SGD:
                         "batch_id": -1,
                     },
                 )
-        self.parameters.params, self.parameters.state = params, state
-        self._opt_state = opt_state
+        publish()
 
     # ------------------------------------------------------------------
     def elastic_model(self, decode):

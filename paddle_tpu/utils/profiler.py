@@ -10,8 +10,9 @@
   its layer.  While the profile is active the trainer loop's five host
   spans ride the same timeline: ``step`` (one whole iteration) over
   ``feed_wait``, ``train_step`` (the dispatch) and ``block_fetch`` (the
-  cost's way back) on the trainer thread, and ``feed`` (the staging work)
-  on the prefetch thread.  Host-side timers live in utils/timers.py, eager
+  way back of a cost: the step's own, or that of the step before where
+  the loop keeps one in flight) on the trainer thread, and ``feed`` (the
+  staging work) on the prefetch thread.  Host-side timers live in utils/timers.py, eager
   per-layer timing in utils/debug.py.
 
 * :func:`enable_nan_checks` is the FP-trap equivalent (the reference

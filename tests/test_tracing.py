@@ -754,14 +754,19 @@ def _spans_by_thread(tracer):
     return {tid: spans for tid, (spans, _) in out.items()}
 
 
+@pytest.mark.parametrize("depth", ["one_in_flight", "none_in_flight"])
 @pytest.mark.parametrize("async_load_data", [True, False])
-def test_step_span_partitions_every_iteration(private_tracer, async_load_data):
+def test_step_span_partitions_every_iteration(private_tracer, tmp_path, async_load_data, depth):
     import paddle_tpu as paddle
 
     ended = []
     trainer = _tiny_trainer()
+    # with a checkpoint_dir every step is settled in the iteration that
+    # dispatched it; without, in the next one (trainer.SGD.train's docstring)
+    ahead = depth == "one_in_flight"
     trainer.train(
         _tiny_reader(), num_passes=1, async_load_data=async_load_data,
+        checkpoint_dir=None if ahead else str(tmp_path / "ck"),
         event_handler=lambda e: ended.append(e.batch_id)
         if isinstance(e, paddle.event.EndIteration) else None,
     )
@@ -770,18 +775,27 @@ def test_step_span_partitions_every_iteration(private_tracer, async_load_data):
     loop = next(s for s in by_thread.values() if any(x[0] == "step" for x in s))
     steps = [i for i, s in enumerate(loop) if s[0] == "step"]
     # one `step` per iteration, top level, back to back in batch order; the
-    # last is the iteration that found the pass exhausted (a feed_wait alone)
+    # last is the iteration that found the pass exhausted
     assert [loop[i][3]["b"] for i in steps] == list(range(_N_BATCHES + 1))
     assert all(loop[i][4] == 0 and loop[i][3]["p"] == 0 for i in steps)
     for i in steps:
         name, t0, t1, args, _, _ = loop[i]
         kids = [s for s in loop if s[5] == i]
-        if args["b"] == _N_BATCHES:
-            assert [k[0] for k in kids] == ["feed_wait"]
-            continue
-        assert tuple(k[0] for k in kids) == _STEP_CHILDREN
-        assert all(k[3]["b"] == args["b"] for k in kids)  # one step, one b
-        assert kids[1][3]["p"] == args["p"]
+        b = args["b"]
+        # every iteration that dispatches holds one feed_wait and one
+        # train_step of its own batch, and one block_fetch: of its own step at
+        # depth 0, of the step before under run-ahead (none in the first);
+        # the exhausted iteration dispatches nothing and fetches the last
+        # step's cost if that is still in flight
+        fetched = b - 1 if ahead else b
+        want = [("feed_wait", b)]
+        if b < _N_BATCHES:
+            want.append(("train_step", b))
+        if 0 <= fetched < _N_BATCHES:
+            want.append(("block_fetch", fetched))
+        assert [(k[0], k[3]["b"]) for k in kids] == want
+        if b < _N_BATCHES:
+            assert kids[1][3]["p"] == args["p"]
         # children lie inside the parent, in order, without overlap: what
         # they leave uncovered is the step's self time, and the four add up
         edges = [t0] + [t for k in kids for t in (k[1], k[2])] + [t1]
@@ -799,6 +813,24 @@ def test_step_span_partitions_every_iteration(private_tracer, async_load_data):
             assert by_thread[tid] is not loop and s[5] is None
         else:
             assert by_thread[tid] is loop and loop[s[5]][0] == "feed_wait"
+
+
+def test_a_step_that_saves_is_fetched_before_the_next_dispatch(private_tracer, tmp_path):
+    """A batch-period save reads the parameters its step left: that step's
+    block_fetch comes BEFORE the next train_step, in the same iteration,
+    and the iteration still holds at most one of each child."""
+    _tiny_trainer().train(_tiny_reader(), num_passes=1, save_dir=str(tmp_path),
+                          saving_period_by_batches=3)
+    loop = next(s for s in _spans_by_thread(private_tracer).values()
+                if any(x[0] == "step" for x in s))
+    got = {s[3]["b"]: [(k[0], k[3]["b"]) for k in loop if k[5] == i]
+           for i, s in enumerate(loop) if s[0] == "step"}
+    assert got[2] == [("feed_wait", 2), ("train_step", 2), ("block_fetch", 1)]
+    assert got[3] == [("feed_wait", 3), ("block_fetch", 2), ("train_step", 3)]  # batch 3 saved
+    assert got[4] == [("feed_wait", 4), ("train_step", 4), ("block_fetch", 3)]
+    assert got[_N_BATCHES] == [("feed_wait", _N_BATCHES), ("block_fetch", _N_BATCHES - 1)]
+    drains = [e["args"] for e in private_tracer.events() if e["name"] == "run_ahead_drain"]
+    assert drains == [{"p": 0, "b": 2}]  # the save after the last batch drained nothing early
 
 
 @pytest.mark.parametrize("phase", ["feed_wait_ms", "self_ms"])
@@ -826,14 +858,36 @@ def test_slow_step_emits_one_record_with_its_phases(private_tracer, caplog, phas
     slow = [e for e in private_tracer.events() if e["name"] == "slow_step"]
     assert len(slow) == 1 and slow[0]["ph"] == "i" and slow[0]["cat"] == "trainer"
     args = slow[0]["args"]
-    assert set(args) == {"p", "b", "ms", "feed_wait_ms", "dispatch_ms", "fetch_ms", "self_ms"}
-    assert (args["p"], args["b"]) == (0, slow_batch)
+    assert set(args) == {"p", "b", "fetched", "ms", "feed_wait_ms", "dispatch_ms",
+                         "fetch_ms", "self_ms"}
+    # batch 4 is read in the iteration that dispatches it, while step 3 is in
+    # flight; its EndIteration comes one iteration later, once step 5 is
+    # dispatched: the record names the iteration AND the step it fetched
+    b = slow_batch if phase == "feed_wait_ms" else slow_batch + 1
+    assert (args["p"], args["b"], args["fetched"]) == (0, b, b - 1)
     parts = ("feed_wait_ms", "dispatch_ms", "fetch_ms", "self_ms")
     assert sum(args[k] for k in parts) == pytest.approx(args["ms"])
     assert args[phase] >= 500 and args["ms"] - args[phase] < 50  # the phase that held it
     assert global_stats.count("slow_steps") == before + 1
     lines = [r.getMessage() for r in caplog.records if "slow_step" in r.getMessage()]
-    assert len(lines) == 1 and f"batch {slow_batch}" in lines[0]
+    assert len(lines) == 1 and f"batch {b}:" in lines[0] and f"of batch {b - 1}," in lines[0]
+
+
+def test_a_stall_in_the_last_fetch_of_a_pass_is_recorded(private_tracer):
+    """The iteration that finds the pass exhausted dispatches nothing, and
+    waits for the last step's cost: a stall there is a slow_step too."""
+    import paddle_tpu as paddle
+
+    clock = private_tracer.clock
+
+    def handler(e):
+        if isinstance(e, paddle.event.EndIteration) and e.batch_id == _N_BATCHES - 1:
+            clock.t += 0.5
+
+    _tiny_trainer().train(_tiny_reader(), num_passes=1, event_handler=handler)
+    slow = [e["args"] for e in private_tracer.events() if e["name"] == "slow_step"]
+    assert [(a["b"], a["fetched"], a["dispatch_ms"]) for a in slow] == [
+        (_N_BATCHES, _N_BATCHES - 1, 0.0)]
 
 
 def test_steady_run_emits_no_slow_step(private_tracer):
@@ -844,7 +898,9 @@ def test_steady_run_emits_no_slow_step(private_tracer):
     trainer.train(_tiny_reader(), num_passes=2)
     assert not [e for e in private_tracer.events() if e["name"] == "slow_step"]
     assert global_stats.count("slow_steps") == before
-    assert len(trainer._step_ms) == 2 * _N_BATCHES  # every step joined the history
+    # every iteration that dispatched or fetched joined the history: one a
+    # batch, and the one at each pass's end that fetched the last cost
+    assert len(trainer._step_ms) == 2 * (_N_BATCHES + 1)
 
 
 def test_slow_step_needs_the_recorder(private_tracer):
