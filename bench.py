@@ -1960,8 +1960,8 @@ def _bench_transformer_ctx(
 
         batches = [mk() for _ in range(2 if seq_len >= 1024 else 4)]
         k = 4 if seq_len >= 1024 else 8
-        # flash kernels actually IN the step's program (3 per attention,
-        # fwd + bwd): asking for them is not running them — the layer
+        # flash kernels actually IN the step's program (lowered: forward +
+        # the fused backward a distinct shape, shared by its layers): asking for them is not running them — the layer
         # computes dense, with a warning, where the kernel cannot be used
         n_flash_calls = (
             make_train_step(net, opt, mesh=None)

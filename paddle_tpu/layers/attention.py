@@ -26,6 +26,7 @@ from paddle_tpu.core import initializers as init
 from paddle_tpu.core.batch import SeqTensor
 from paddle_tpu.layers.base import register_layer
 from paddle_tpu.ops import acc_einsum, acc_matmul
+from paddle_tpu.utils.timers import global_stats
 
 NEG_INF = -1e9
 
@@ -114,15 +115,105 @@ def _head_dims(conf):
     return h, kvh, dh
 
 
-# keys from which self-attention takes the blocked kernel unasked (the
-# benchmark's cells lie on both sides: 128 and 1,024 keys dense, 2,048 blocked)
-_FLASH_FROM_KEYS = 2048
+# Keys from which attention with as many queries as keys takes the blocked
+# kernels unasked: the smallest count at which they beat the dense path with
+# AND without the causal mask (scripts/attention_sweep.py on a v5e, PERF.md
+# section 6, PR 35; forward + backward of the core, 8,192 tokens, 8 heads of
+# 64; dense / blocked ms): 256 keys 0.57 / 0.64 and 0.56 / 0.60 causal, 512
+# keys 0.57 / 0.73 and 1.30 / 0.70, 1,024 keys 2.15 / 1.07 and 3.06 / 0.95,
+# 2,048 keys 4.00 / 1.76 and 5.85 / 1.32.  The benchmark's cells lie on both
+# sides: 128 keys dense, 1,024 and 2,048 blocked.
+_FLASH_FROM_KEYS = 1024
 
 
 def _flash_asked():
     from paddle_tpu.utils.flags import get_flag
 
     return bool(get_flag("use_pallas_attention"))
+
+
+def _blocked_core(mesh, batch):
+    """How the blocked kernels enter the program being traced on `mesh`
+    (ctx.mesh) -> (wrap, why): `wrap` takes the per-device function of
+    (q, k, v, lengths) to the one to call, or is None with the reason.
+
+    XLA does not partition a Mosaic kernel: lowered into a program over more
+    than one device (trainer/step.py's data-parallel step is a plain jit
+    with a NamedSharding on the batch), jax refuses it outright.  So where
+    the mesh holds more than one device the kernels run under a shard_map
+    over the batch (DATA_AXIS), every other axis replicated: a batch row's
+    attention needs no other row.  Inside a shard_map that already holds
+    EVERY axis (the quantized-allreduce step) the program is a device's own
+    and the kernels are called as they are; inside one that holds only some
+    of them (the pipeline) jax refuses the kernels, and the layer stays
+    dense."""
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.parallel.mesh import DATA_AXIS
+
+    held = jax.sharding.get_abstract_mesh()
+    if held.manual_axes:
+        if set(held.manual_axes) == set(held.axis_names):
+            return (lambda f: f), None
+        return None, f"the layer is traced inside a shard_map over {held.manual_axes} only"
+    if mesh is None or mesh.size == 1:
+        return (lambda f: f), None
+    n = mesh.shape.get(DATA_AXIS, 1)
+    if n == 1 or batch % n:
+        return None, (f"the mesh {dict(mesh.shape)} holds {mesh.size} devices and "
+                      f"{batch} rows do not split over its {DATA_AXIS!r} axis")
+    rows = P(DATA_AXIS)
+    return (lambda f: jax.shard_map(f, mesh=mesh, in_specs=(rows,) * 4, out_specs=rows,
+                                    check_vma=False)), None
+
+
+def _dense_core(q, k, v, key_mask, causal):
+    """softmax(QK^T)V with the [Tq, Tk] scores held whole: q [B, Tq, h, dh],
+    k and v [B, Tk, kvh, dh], key_mask [B, Tk] (1 = a key that exists) or
+    None -> [B, Tq, h * dh].  float32 softmax, weights in v's dtype."""
+    b, tq, h, dh = q.shape
+    tk, kvh = k.shape[1], k.shape[2]
+    group = h // kvh
+    # Explicit [B, h, T, dh] operands with LEADING batch dims: the
+    # score/output einsums and every dot_general their VJP emits then
+    # have (b, h) as proper leading batch dimensions, which the TPU
+    # layout assignment handles in place.  With h trapped at dim 2
+    # ("bqhd,bkhd->bhqk") the backward materialized layout-change
+    # copies of every [B,h,T,T]/[B,T,h,dh] grad — measured 9.1 ms of
+    # a 36 ms transformer-base step (25% in pure copies).  (Two
+    # alternatives measured SLOWER on v5e: a single packed
+    # [B,T,3,h,dh]->[3,B,h,T,dh] relayout of the fused QKV — the 5-D
+    # transpose tiles worse than three separate ones — and a
+    # whole-[T,T]-in-VMEM Pallas kernel with grid (B,) + in-core
+    # batched-over-heads dots, which lost ~35% to tiny per-program
+    # work at T=64.)
+    #
+    # Grouped heads (n_kv_heads < n_heads): the query heads that share
+    # a key/value head are folded into the query axis, [B, kvh, g*Tq,
+    # dh], so the same two einsums run over kvh batch heads and no
+    # key or value is repeated.
+    if group == 1:
+        qh = q.transpose(0, 2, 1, 3)
+    else:
+        qh = (q.reshape(b, tq, kvh, group, dh).transpose(0, 2, 3, 1, 4)
+              .reshape(b, kvh, group * tq, dh))
+    kh = k.transpose(0, 2, 1, 3)
+    vh = v.transpose(0, 2, 1, 3)
+    scores = acc_einsum("bhqd,bhkd->bhqk", qh, kh) / math.sqrt(dh)
+    scores = scores.astype(jnp.float32)
+    if key_mask is not None:
+        scores = scores + (1.0 - key_mask)[:, None, None, :] * NEG_INF
+    if causal:
+        cm = jnp.tril(jnp.ones((tq, tk), jnp.float32))
+        if group > 1:
+            cm = jnp.tile(cm, (group, 1))
+        scores = scores + (1.0 - cm)[None, None, :, :] * NEG_INF
+    w = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    out = acc_einsum("bhqk,bhkd->bhqd", w, vh)
+    if group == 1:
+        return out.transpose(0, 2, 1, 3).reshape(b, tq, h * dh)
+    return (out.reshape(b, kvh, group, tq, dh).transpose(0, 3, 1, 2, 4)
+            .reshape(b, tq, h * dh))
 
 
 @register_layer("multi_head_attention", init=mha_init, auto_activation=False)
@@ -159,29 +250,36 @@ def mha_apply(conf, params, inputs, ctx):
 
     sp_axis = conf.attr("seq_parallel_axis")
     out = None
-    # The blocked kernel (ops/pallas_attention.py) streams k/v blocks through
-    # VMEM with an online softmax: no [T, T] score matrix in HBM.  TPU
-    # backend only.  It is taken where the flag asks for it, and from
-    # _FLASH_FROM_KEYS keys on whatever the flag says: there the dense
-    # scores cost more than they save (2 x 32 heads x 2,048^2 in float32 are
-    # 1.07 GB a layer; 251 -> 239 ms a step of the hybrid decoder, PERF.md).
-    # Asked for by the flag and not usable, the layer computes dense and
-    # SAYS so at trace time: a silent dense path would be timed and costed
-    # as the kernel.
+    # The blocked kernels (ops/pallas_attention.py) keep a block of scores
+    # in VMEM between two MXU products: no [T, T] scores, weights or their
+    # gradients in HBM.  TPU backend only.  They are taken where the flag
+    # asks for them, and from _FLASH_FROM_KEYS keys on whatever the flag
+    # says: from there the dense path is bound by those bytes (2.2-2.4 GB a
+    # layer at 8 x 8 heads x 1,024^2) and loses in time as well as in memory.
+    # The choice is made here, at trace time, and counted here
+    # (`attention_blocked_layers` / `attention_dense_layers`).
+    # Asked for by the flag, or due by the key count on a TPU, and not
+    # usable, the layer computes dense and SAYS so at trace time: a silent
+    # dense path would be timed and costed as the kernel.  On a mesh of
+    # several devices they run under a shard_map over the batch
+    # (_blocked_core).
     from paddle_tpu.ops import pallas_attention as fa
 
-    flash_why = None
+    flash_why = wrap = None
+    wanted = _flash_asked()
     if jax.default_backend() != "tpu":
         flash_why = f"the backend is {jax.default_backend()!r}, not 'tpu'"
     elif tq != tk:
         flash_why = f"query length {tq} != key length {tk}"
     elif not fa.supported(tq, dh):
         flash_why = f"T={tq}, head dim {dh} is not a shape the kernel takes"
-    take_flash = flash_why is None and (_flash_asked() or tk >= _FLASH_FROM_KEYS)
+    elif wanted or tk >= _FLASH_FROM_KEYS:
+        wanted = True
+        wrap, flash_why = _blocked_core(ctx.mesh, b)
+    take_flash = wrap is not None
     if group > 1 and (sp_axis is not None or take_flash):
         # the ring and the kernel take a key/value head a query head
         k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
-        kvh, group = h, 1
     if sp_axis is not None and tq == tk:
         # context parallelism: shard T over the mesh axis and run exact
         # ring attention (parallel/ring_attention.py) instead of the dense
@@ -223,64 +321,24 @@ def mha_apply(conf, params, inputs, ctx):
             ).reshape(b, tq, d)
 
     if out is None and take_flash:
-        bq, bk = fa.auto_blocks(tq)
-        out = fa.flash_attention_diff(
-            q, k, v,
-            kv_in.lengths if kv_in.is_seq else None,
-            causal, bq, bk, False,
-        ).reshape(b, tq, d)
-    elif out is None and _flash_asked():
+        global_stats.incr("attention_blocked_layers")
+        bq, bk = fa.auto_blocks(tq, causal)
+        lengths = kv_in.lengths if kv_in.is_seq else jnp.full((b,), tk, jnp.int32)
+        out = wrap(lambda q, k, v, n: fa.flash_attention_diff(q, k, v, n, causal, bq, bk, False))(
+            q, k, v, lengths).reshape(b, tq, d)
+    elif out is None and wanted:
         import warnings
 
+        due = ("use_pallas_attention is on" if _flash_asked()
+               else f"{tk} keys are due the blocked kernels")
         warnings.warn(
-            f"{conf.name}: use_pallas_attention is on but {flash_why}; "
-            "computing dense O(T^2) attention",
+            f"{conf.name}: {due} but {flash_why}; computing dense O(T^2) attention",
             stacklevel=2,
         )
 
-    if out is None:  # dense path
-        # Explicit [B, h, T, dh] operands with LEADING batch dims: the
-        # score/output einsums and every dot_general their VJP emits then
-        # have (b, h) as proper leading batch dimensions, which the TPU
-        # layout assignment handles in place.  With h trapped at dim 2
-        # ("bqhd,bkhd->bhqk") the backward materialized layout-change
-        # copies of every [B,h,T,T]/[B,T,h,dh] grad — measured 9.1 ms of
-        # a 36 ms transformer-base step (25% in pure copies).  (Two
-        # alternatives measured SLOWER on v5e: a single packed
-        # [B,T,3,h,dh]->[3,B,h,T,dh] relayout of the fused QKV — the 5-D
-        # transpose tiles worse than three separate ones — and a
-        # whole-[T,T]-in-VMEM Pallas kernel with grid (B,) + in-core
-        # batched-over-heads dots, which lost ~35% to tiny per-program
-        # work at T=64.)
-        #
-        # Grouped heads (n_kv_heads < n_heads): the query heads that share
-        # a key/value head are folded into the query axis, [B, kvh, g*Tq,
-        # dh], so the same two einsums run over kvh batch heads and no
-        # key or value is repeated.
-        if group == 1:
-            qh = q.transpose(0, 2, 1, 3)
-        else:
-            qh = (q.reshape(b, tq, kvh, group, dh).transpose(0, 2, 3, 1, 4)
-                  .reshape(b, kvh, group * tq, dh))
-        kh = k.transpose(0, 2, 1, 3)
-        vh = v.transpose(0, 2, 1, 3)
-        scores = acc_einsum("bhqd,bhkd->bhqk", qh, kh) / math.sqrt(dh)
-        scores = scores.astype(jnp.float32)
-        if kv_in.is_seq:
-            key_mask = kv_in.mask(jnp.float32)  # [B, Tk]
-            scores = scores + (1.0 - key_mask)[:, None, None, :] * NEG_INF
-        if causal:
-            cm = jnp.tril(jnp.ones((tq, tk), jnp.float32))
-            if group > 1:
-                cm = jnp.tile(cm, (group, 1))
-            scores = scores + (1.0 - cm)[None, None, :, :] * NEG_INF
-        w = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-        out = acc_einsum("bhqk,bhkd->bhqd", w, vh)
-        if group == 1:
-            out = out.transpose(0, 2, 1, 3).reshape(b, tq, d)
-        else:
-            out = (out.reshape(b, kvh, group, tq, dh).transpose(0, 3, 1, 2, 4)
-                   .reshape(b, tq, d))
+    if out is None:
+        global_stats.incr("attention_dense_layers")
+        out = _dense_core(q, k, v, kv_in.mask(jnp.float32) if kv_in.is_seq else None, causal)
 
     out = acc_matmul(out, params["wo"])
     if "b" in params:

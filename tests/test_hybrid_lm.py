@@ -332,14 +332,18 @@ def test_grouped_heads_match_repeated_heads(causal):
     np.testing.assert_allclose(g["wk"], summed, rtol=1e-4, atol=1e-6)
 
 
-@pytest.mark.parametrize("keys,blocked", [(2048, True), (1024, False)])
-def test_the_blocked_kernel_is_taken_from_2048_keys_on(monkeypatch, keys, blocked):
+@pytest.mark.parametrize("keys,blocked", [(1024, True), (512, False)])
+def test_the_blocked_kernel_is_taken_from_1024_keys_on(monkeypatch, keys, blocked):
     """On the TPU self-attention takes `ops/pallas_attention` unasked from
-    2,048 keys on and stays dense below (where the benchmark's Transformer
-    cells are), and hands the kernel a key/value head for every query head.
+    1,024 keys on (`transformer-train-1k`, `nemotron-train-2k`) and stays dense
+    below (`transformer-train-128`), hands the kernel a key/value head for
+    every query head, and counts the choice where it makes it, at trace time.
     The kernel is a stand-in here: the choice and the wiring are what is read."""
+    from paddle_tpu.layers import attention
     from paddle_tpu.ops import pallas_attention as fa
+    from paddle_tpu.utils.timers import global_stats
 
+    assert attention._FLASH_FROM_KEYS == 1024
     d_in, heads, kv_heads, dh = 6, 2, 1, 8
     reset_auto_names()
     x_in = paddle.layer.data("x", paddle.data_type.dense_vector_sequence(d_in))
@@ -349,7 +353,14 @@ def test_the_blocked_kernel_is_taken_from_2048_keys_on(monkeypatch, keys, blocke
     params, state = net.init(jax.random.PRNGKey(7))
     batch = {"x": mkseq(jax.random.normal(jax.random.PRNGKey(8), (1, keys, d_in)),
                         np.asarray([keys], np.int32))}
+
+    def counted():
+        return (global_stats.count("attention_blocked_layers"),
+                global_stats.count("attention_dense_layers"))
+
+    before = counted()
     dense = net.apply(params, batch, state=state, train=False)[0]["att"].data
+    assert counted() == (before[0], before[1] + 1)  # the CPU backend: dense whatever the keys
     seen = []
 
     def stand_in(q, k, v, lengths, causal, bq, bk, interpret):
@@ -360,9 +371,33 @@ def test_the_blocked_kernel_is_taken_from_2048_keys_on(monkeypatch, keys, blocke
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(fa, "flash_attention_diff", stand_in)
+    before = counted()
     got = net.apply(params, batch, state=state, train=False)[0]["att"].data
     assert seen == ([((1, keys, heads, dh),) * 3 + (True,)] if blocked else [])
+    assert counted() == (before[0] + blocked, before[1] + (not blocked))
     np.testing.assert_allclose(got, dense, rtol=1e-4, atol=1e-5)
+
+
+def test_the_hybrid_decoders_attention_layer_counts_as_blocked_at_2048_keys(monkeypatch):
+    """`nemotron-train-2k`'s pattern as the chip would trace it (only the
+    backend's name is faked; nothing is compiled or run): its one attention
+    layer, 2,048 keys, grouped heads of 128, takes the blocked kernels: 1 / 0."""
+    from paddle_tpu.models.hybrid_lm import hybrid_lm_cost
+    from paddle_tpu.utils.timers import global_stats
+
+    cost, _ = hybrid_lm_cost(
+        "MEMEMEM*E", 64, 32, mamba_heads=2, mamba_head_dim=8, mamba_groups=1, state_size=8,
+        attn_heads=4, attn_kv_heads=2, attn_head_dim=128, num_experts=4, experts_per_token=2,
+        expert_hidden=16, shared_hidden=16, experts_held=(0, 2))
+    net = CompiledNetwork(Topology([cost]), compute_dtype=jnp.bfloat16)
+    params, state = net.init(jax.random.PRNGKey(0))
+    ids = SeqTensor(jnp.ones((2, 2048), jnp.int32), jnp.full((2,), 2048, jnp.int32))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    count = lambda: [global_stats.count(f"attention_{path}_layers") for path in ("blocked", "dense")]
+    before = count()
+    jax.eval_shape(lambda p: net.apply(p, {"word": ids, "next_word": ids}, state=state, train=True)[0][cost.name].data,
+                   params)
+    assert [a - b for a, b in zip(count(), before)] == [1, 0]
 
 
 # -- the token-row reader ------------------------------------------------------

@@ -1,0 +1,134 @@
+"""The blocked attention kernels compiled for the chip WITHOUT the chip: the
+TPU's compiler is installed here and compiles for a v5e that is described,
+not attached.  Mosaic refuses here what it would refuse there (a slice off
+the tiling, a layout it cannot relayout, more VMEM than a kernel may use),
+which interpret mode on the CPU cannot show.  Nothing runs: no result, no
+time.  Every test that describes the topology lives in THIS file and does so
+inside a fixture: each xdist worker imports every test file, and the TPU's
+library is loaded by the one that runs this one.
+"""
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.ops import pallas_attention as fa
+
+# nothing is attached, so a second load of the library beside a job that
+# holds it is harmless: without the last entry it is refused
+_ENV = {"TPU_SKIP_MDS_QUERY": "1", "TPU_ACCELERATOR_TYPE": "v5litepod-4",
+        "TPU_WORKER_HOSTNAMES": "localhost", "TPU_LOG_DIR": "disabled",
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1"}
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four chips of a v5e 2x2 host, described.  Skips where the TPU's
+    library is not installed; any other failure to describe them fails."""
+    from jax.experimental import topologies
+
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("libtpu is not installed: no v5e:2x2 topology can be described here")
+    before = {name: os.environ.get(name) for name in _ENV}
+    os.environ.update(_ENV)
+    try:
+        yield topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    finally:
+        for name, value in before.items():
+            if value is None:
+                os.environ.pop(name, None)
+            else:
+                os.environ[name] = value
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    """Such a compile is written to the persistent cache and cannot be read
+    back: the cache is off for the length of one test of this file."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+
+
+# (B, T, H, dh, causal): the Transformer cell's two kinds of layer, the hybrid
+# decoder's (after its key/value heads are repeated), a long row
+_SHAPES = {
+    "transformer-1k": (8, 1024, 8, 64, False),
+    "transformer-1k-causal": (8, 1024, 8, 64, True),
+    "hybrid-2k-causal": (2, 2048, 32, 128, True),
+    "long-8k": (1, 8192, 8, 64, False),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_SHAPES))
+def test_the_blocked_kernels_compile_for_a_v5e_at_the_cells_shapes(v5e, name):
+    from jax.sharding import SingleDeviceSharding
+
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    b, t, h, dh, causal = _SHAPES[name]
+    bq, bk = fa.auto_blocks(t, causal)
+    spec = jax.ShapeDtypeStruct((b, t, h, dh), jnp.bfloat16, sharding=one_chip)
+    lengths = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip)
+
+    def forward_and_backward(q, k, v, g, lengths):
+        out, vjp = jax.vjp(lambda *a: fa.flash_attention_diff(*a, lengths, causal, bq, bk, False), q, k, v)
+        return (out, *vjp(g))
+
+    compiled = (jax.jit(forward_and_backward).trace(spec, spec, spec, spec, lengths)
+                .lower(lowering_platforms=("tpu",)).compile())
+    # forward + the one fused backward
+    assert compiled.as_text().count("tpu_custom_call") == 2
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["jit-over-the-mesh", "quantized-allreduce"])
+def test_a_data_parallel_step_with_the_blocked_kernels_compiles_for_four_chips(v5e, monkeypatch, quantized):
+    """trainer/step.py's data-parallel step is ONE program over the mesh, and
+    XLA partitions no Mosaic kernel: jax refuses one that is lowered bare into
+    such a program.  The layer sees the mesh (ctx.mesh) and puts its kernels
+    under a shard_map over the rows: a Transformer of 1,024 keys, one layer of
+    each kind, a row a chip, lowers and compiles with all six kernels in it.
+    The quantized-allreduce step traces the layers inside a shard_map of its
+    own over the whole mesh, where the kernels are called bare."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import paddle_tpu as paddle
+    from paddle_tpu.core.batch import SeqTensor
+    from paddle_tpu.core.compiler import CompiledNetwork
+    from paddle_tpu.core.topology import Topology, reset_auto_names
+    from paddle_tpu.models.transformer import transformer_cost
+    from paddle_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+    from paddle_tpu.trainer.step import make_train_step
+    from paddle_tpu.utils.timers import global_stats
+
+    mesh = Mesh(np.array(v5e.devices).reshape(4, 1), (DATA_AXIS, MODEL_AXIS))
+    reset_auto_names()
+    cost, _ = transformer_cost(50, 50, d_model=128, n_heads=2, n_layers=1, d_ff=64)
+    net = CompiledNetwork(Topology([cost]), compute_dtype=jnp.bfloat16)
+    net.mesh = mesh  # as trainer.SGD sets it
+    opt = paddle.optimizer.Adam(learning_rate=1e-3)
+    params, state = jax.eval_shape(net.init, jax.random.PRNGKey(0))
+    ids = SeqTensor(jax.ShapeDtypeStruct((4, 1024), jnp.int32), jax.ShapeDtypeStruct((4,), jnp.int32))
+    batch = {name: ids for name in ("src_word", "trg_word", "trg_next")}
+    args = (params, state, jax.eval_shape(opt.init, params), batch, jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    by_row = NamedSharding(mesh, P(DATA_AXIS))
+    placed = [jax.tree_util.tree_map(
+        lambda x, sh=(by_row if arg is batch else NamedSharding(mesh, P())): jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sh), arg) for arg in args]
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    count = lambda: [global_stats.count(f"attention_{path}_layers") for path in ("blocked", "dense")]
+    before = count()
+    compiled = make_train_step(net, opt, mesh=mesh, quantized=quantized).trace(*placed).lower(lowering_platforms=("tpu",)).compile()
+    assert [a - b for a, b in zip(count(), before)] == [3, 0]
+    assert compiled.as_text().count("tpu_custom_call") == 6

@@ -144,3 +144,94 @@ def test_transformer_infer():
     )
     assert probs.shape == (7, V)  # 4 + 3 decoder timesteps
     np.testing.assert_allclose(probs.sum(1), 1.0, rtol=1e-3)
+
+
+@pytest.mark.parametrize("keys,blocked_dense", [(1024, (18, 0)), (128, (0, 18))])
+def test_the_18_attention_layers_count_their_path_where_they_choose_it(monkeypatch, keys, blocked_dense):
+    """`transformer-train-1k` and `-128` as the chip would trace them (the
+    backend's name is the one thing faked; nothing is compiled or run): six
+    encoder layers, six decoder self- and six cross-attention layers, every
+    one with as many queries as keys, take the blocked kernels at 1,024 keys
+    and the dense path at 128, and say so in `attention_blocked_layers` /
+    `attention_dense_layers`, one count a layer traced."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.core.batch import SeqTensor
+    from paddle_tpu.core.compiler import CompiledNetwork
+    from paddle_tpu.core.topology import Topology
+    from paddle_tpu.utils.timers import global_stats
+
+    reset_auto_names()
+    cost, _ = transformer_cost(50, 50, d_model=16, n_heads=2, n_layers=6, d_ff=32)
+    net = CompiledNetwork(Topology([cost]), compute_dtype=jnp.bfloat16)
+    params, state = net.init(jax.random.PRNGKey(0))
+    ids = SeqTensor(jnp.ones((2, keys), jnp.int32), jnp.full((2,), keys, jnp.int32))
+    batch = {name: ids for name in ("src_word", "trg_word", "trg_next")}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    before = [global_stats.count(f"attention_{path}_layers") for path in ("blocked", "dense")]
+    jax.eval_shape(lambda p: net.apply(p, batch, state=state, train=True)[0][cost.name].data, params)
+    after = [global_stats.count(f"attention_{path}_layers") for path in ("blocked", "dense")]
+    assert (after[0] - before[0], after[1] - before[1]) == blocked_dense
+
+
+@pytest.mark.parametrize("rows,blocked_dense", [(4, (3, 0)), (6, (0, 3))])
+def test_on_a_mesh_the_blocked_kernels_go_under_a_shard_map_over_the_rows(monkeypatch, rows, blocked_dense):
+    """XLA partitions no Mosaic kernel, and the data-parallel step is one
+    program over the mesh: where the layer sees a mesh of several devices
+    (ctx.mesh) its kernels run under a shard_map over the rows, and rows that
+    do not split over the data axis keep the layer dense, which it says.  The
+    backend's name is faked and the step only traced; that it compiles for
+    four chips is `tests/test_tpu_compile.py`'s to show."""
+    import warnings
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.core.batch import SeqTensor
+    from paddle_tpu.core.compiler import CompiledNetwork
+    from paddle_tpu.core.topology import Topology
+    from paddle_tpu.parallel.mesh import make_mesh
+    from paddle_tpu.utils.timers import global_stats
+
+    reset_auto_names()
+    cost, _ = transformer_cost(50, 50, d_model=16, n_heads=2, n_layers=1, d_ff=32)
+    net = CompiledNetwork(Topology([cost]), compute_dtype=jnp.bfloat16)
+    net.mesh = make_mesh(data=4, model=1, devices=jax.devices()[:4])
+    params, state = net.init(jax.random.PRNGKey(0))
+    ids = SeqTensor(jnp.ones((rows, 1024), jnp.int32), jnp.full((rows,), 1024, jnp.int32))
+    batch = {name: ids for name in ("src_word", "trg_word", "trg_next")}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    count = lambda: [global_stats.count(f"attention_{path}_layers") for path in ("blocked", "dense")]
+    before = count()
+    with warnings.catch_warnings(record=True) as said:
+        warnings.simplefilter("always")
+        program = str(jax.make_jaxpr(
+            lambda p: net.apply(p, batch, state=state, train=True)[0][cost.name].data)(params))
+    assert tuple(a - b for a, b in zip(count(), before)) == blocked_dense
+    assert program.count("shard_map") == blocked_dense[0]
+    refusals = [str(w.message) for w in said if "do not split over its 'data' axis" in str(w.message)]
+    assert len(refusals) == blocked_dense[1]
+
+
+@pytest.mark.parametrize("held,taken", [(("data", "model"), True), (("data",), False)])
+def test_inside_a_shard_map_the_kernels_are_called_bare_only_where_it_holds_every_axis(held, taken):
+    """The quantized-allreduce step traces the layers inside a shard_map over
+    the whole mesh: the program is a device's own there and the kernels need
+    no wrapping.  Inside one that leaves an axis to XLA jax refuses them."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from paddle_tpu.layers.attention import _blocked_core
+    from paddle_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(data=2, model=2, devices=jax.devices()[:4])
+    seen = []
+
+    def body(x):
+        wrap, why = _blocked_core(mesh, x.shape[0])
+        seen.append((wrap is not None, why))
+        return x if wrap is None else wrap(lambda y: y)(x)
+
+    jax.eval_shape(jax.shard_map(body, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+                                 axis_names=set(held), check_vma=False), jnp.ones((4, 8)))
+    (got, why), = seen
+    assert got == taken and (why is None) == taken
