@@ -24,6 +24,7 @@ Softmax = _make("softmax")
 SequenceSoftmax = _make("sequence_softmax")
 Relu = _make("relu")
 Relu2 = _make("relu2")
+Silu = _make("silu")
 BRelu = _make("brelu")
 Tanh = _make("tanh")
 STanh = _make("stanh")

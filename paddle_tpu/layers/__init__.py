@@ -38,6 +38,7 @@ from paddle_tpu.layers.recurrent_group import (  # noqa: F401
     memory,
     recurrent_group,
 )
+from paddle_tpu.layers.loop import layer_loop  # noqa: F401
 from paddle_tpu.layers.generation import (  # noqa: F401
     GeneratedInput,
     beam_search,
@@ -1163,6 +1164,44 @@ def classification_cost(
         name=(name + "_unweighted") if (name and weight is not None) else name,
     )
     return _weighted(inner, weight, name=name)
+
+
+def looped_exit_cost(
+    input: LayerOutput,
+    head: LayerOutput,
+    gate: LayerOutput,
+    label: LayerOutput,
+    beta: float = 0.0,
+    name: Optional[str] = None,
+) -> LayerOutput:
+    """Expected loss of a `layer_loop` under its exit gate (layers/cost.py
+    `looped_exit_cost_apply` has the equations): `input` is the loop, `head`
+    the bias-free fc from its output to the vocabulary, `gate` the fc from
+    its output to one exit logit.  The cost runs the head and the gate on
+    EVERY pass's output with those two layers' own weights, so both must
+    name them (`param_attr=ParamAttr(name=...)`, and the gate's `bias_attr`
+    likewise): that is how two layers share storage here.  `beta` weights
+    the entropy of the exit distribution.  Aux outputs ``<name>@pass_ce``
+    and ``<name>@exit_p``, [B, n_steps]."""
+    if input.conf.type != "layer_loop":
+        raise ValueError(f"looped_exit_cost: input {input.name!r} is a {input.conf.type}, not a layer_loop")
+    names = {}
+    for what, fc_layer, keys in (("head", head, ("w0",)), ("gate", gate, ("w0", "b"))):
+        c = fc_layer.conf
+        shared = c.attr("param_names") or {}
+        if c.type != "fc" or c.inputs != (input.name,) or any(k not in shared for k in keys):
+            raise ValueError(
+                f"looped_exit_cost: the {what} {c.name!r} must be an fc over the loop "
+                f"{input.name!r} alone whose parameters {keys} carry ParamAttr names")
+        names.update({f"{what}_{k[0]}": shared[k] for k in keys})
+    if head.conf.bias or gate.size != 1 or not gate.conf.bias:
+        raise ValueError("looped_exit_cost: the head has no bias; the gate is one logit wide with a bias")
+    return LayerOutput(
+        LayerConf(
+            name=name or auto_name("looped_exit_cost"), type="looped_exit_cost", size=1,
+            inputs=(input.name, head.name, gate.name, label.name), bias=False,
+            attrs={"beta": float(beta), "param_names": names}),
+        [input, head, gate, label])
 
 
 def cross_entropy_cost(input, label, name=None):
@@ -2496,6 +2535,7 @@ def multi_head_attention(
     name: Optional[str] = None,
     n_kv_heads: Optional[int] = None,
     head_dim: Optional[int] = None,
+    rope_theta: Optional[float] = None,
 ) -> LayerOutput:
     """Multi-head attention; omit key_value for self-attention.  `causal`
     masks future positions (decoder self-attention).  `seq_parallel_axis`
@@ -2503,7 +2543,10 @@ def multi_head_attention(
     as exact ring attention (long-context path, parallel/ring_attention).
     `n_kv_heads` < `n_heads` gives grouped-query attention (each key/value
     head serves n_heads / n_kv_heads query heads); `head_dim` sets the heads'
-    width apart from size / n_heads (the output stays `size` wide)."""
+    width apart from size / n_heads (the output stays `size` wide).
+    `rope_theta` turns q and k by the rotary position code of that base
+    (positions 0..T-1 of a row) before the scores; None adds no position
+    code."""
     kv = key_value or query
     conf = LayerConf(
         name=name or auto_name("mha"),
@@ -2517,6 +2560,7 @@ def multi_head_attention(
             "seq_parallel_axis": seq_parallel_axis,
             "n_kv_heads": n_kv_heads,
             "head_dim": head_dim,
+            "rope_theta": rope_theta,
         },
     )
     return LayerOutput(conf, [query, kv])

@@ -216,10 +216,26 @@ def _dense_core(q, k, v, key_mask, causal):
             .reshape(b, tq, h * dh))
 
 
+def rotary(x, theta):
+    """Rotary position code over the head width: x [B, T, heads, dh], the
+    positions 0..T-1 of the row, pair (i, i + dh/2) of a head turned by
+    position * theta^(-2i/dh) ("rotate-half"), so that a query-key product
+    depends on the two positions' difference alone.  Angles and the turn in
+    float32, the result back in x's type."""
+    t, dh = x.shape[1], x.shape[-1]
+    assert dh % 2 == 0, f"rotary needs an even head width, got {dh}"
+    inv_freq = jnp.exp(jnp.arange(0, dh, 2, dtype=jnp.float32) * (-math.log(theta) / dh))
+    angles = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]  # [T, dh/2]
+    cos, sin = jnp.cos(angles)[None, :, None, :], jnp.sin(angles)[None, :, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
 @register_layer("multi_head_attention", init=mha_init, auto_activation=False)
 def mha_apply(conf, params, inputs, ctx):
     """inputs: (query, key_value) — pass the same layer twice for
-    self-attention.  attrs: n_heads, causal."""
+    self-attention.  attrs: n_heads, causal, rope_theta (None: no position
+    code in the layer)."""
     q_in = inputs[0]
     kv_in = inputs[1] if len(inputs) > 1 else inputs[0]
     h, kvh, dh = _head_dims(conf)
@@ -247,6 +263,12 @@ def mha_apply(conf, params, inputs, ctx):
     k = k.reshape(b, tk, kvh, dh)
     v = v.reshape(b, tk, kvh, dh)
     group = h // kvh
+    rope_theta = conf.attr("rope_theta")
+    if rope_theta is not None:
+        # before the core, so the dense path, the ring and the blocked
+        # kernels all take rotated q and k unchanged
+        with jax.named_scope("rope"):
+            q, k = rotary(q, rope_theta), rotary(k, rope_theta)
 
     sp_axis = conf.attr("seq_parallel_axis")
     out = None

@@ -13,8 +13,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.core import initializers as init
 from paddle_tpu.core.batch import SeqTensor
 from paddle_tpu.layers.base import register_layer
+from paddle_tpu.ops import acc_matmul
 
 _EPS = 1e-10
 # two-sided probability clip for the BCE family: must be representable in
@@ -231,3 +233,75 @@ def multi_nn_cost_apply(conf, params, inputs, ctx):
     for t in inputs:
         total = total + jnp.mean(t.data)
     return SeqTensor(jnp.broadcast_to(total, (1,)))
+
+
+# ---------------------------------------------------------------------------
+# looped_exit_cost — the expected loss of a looped stack under its exit gate
+# ---------------------------------------------------------------------------
+
+
+def exit_distribution(z: jnp.ndarray) -> jnp.ndarray:
+    """log p over the passes from the exit gate's logits z [R, ...]:
+    p^1 = g^1, p^t = g^t prod_{j<t}(1 - g^j), and the LAST pass takes what is
+    left, p^R = prod_{j<R}(1 - g^j), so p sums to 1 whatever the gate says of
+    the last pass.  log(1 - g) is log_sigmoid(-z), never log(1 - sigmoid(z))."""
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-z), axis=0)  # log prod_{j<=t}(1 - g^j)
+    before = jnp.concatenate([jnp.zeros_like(z[:1]), stay[:-1]], axis=0)
+    return jnp.concatenate([jax.nn.log_sigmoid(z[:-1]) + before[:-1], before[-1:]], axis=0)
+
+
+def looped_exit_cost_init(conf, in_confs, rng):
+    """The head's and the gate's own shapes: the storage is theirs (the
+    layers named by `param_names` are declared first and own it)."""
+    d = in_confs[0].size
+    return {"head_w": init.zeros((d, in_confs[1].size)), "gate_w": init.zeros((d, 1)),
+            "gate_b": init.zeros((1,))}
+
+
+@register_layer("looped_exit_cost", init=looped_exit_cost_init, auto_activation=False,
+                full_precision=True)
+def looped_exit_cost_apply(conf, params, inputs, ctx):
+    """inputs: (the layer_loop, its head fc, its exit-gate fc, the labels).
+    With x^t the R outputs of the loop's passes (`<loop>@passes`):
+
+        ce^t_i = -log softmax(x^t_i W_out)[label_i];  g^t_i = sigmoid(w_g . x^t_i + b_g)
+        p = exit_distribution(g);  H(p) = -sum_t p^t log p^t
+        cost of a row = sum over its tokens of [ sum_t p^t ce^t - beta H(p) ]
+
+    The head, the log-softmax and the gather run one pass at a time inside a
+    recomputed unit (`lax.map` over `jax.checkpoint`), so one pass's [B, T, V]
+    logits are alive at a time, in the forward and in the backward, and what
+    is kept is ce^t and the gate's logit, [R, B, T] each.  The head's product
+    runs in the compute type as an `fc` would, under the head's own scope
+    inside this layer's; the log-softmax's sums, the gate, p and H in
+    float32.  The head's and the gate's outputs on the last pass (inputs 1
+    and 2) are not read: the last pass takes what the others leave.
+
+    Aux outputs, a row's means over its tokens: `<name>@pass_ce` [B, R] and
+    `<name>@exit_p` [B, R]."""
+    loop, _, _, label = inputs
+    passes = ctx.outputs[conf.inputs[0] + "@passes"].data  # [R, B, T, D]
+    ids = _label_ids(label)
+    head_w = params["head_w"].astype(ctx.dtype)
+    gate_w, gate_b = params["gate_w"].astype(jnp.float32), params["gate_b"].astype(jnp.float32)
+
+    def one_pass(x):
+        with jax.named_scope(f"fc:{conf.inputs[1]}"):
+            logits = acc_matmul(x.astype(ctx.dtype), head_w)
+        ce = _fused_ce_from_logits(logits, ids)
+        with jax.named_scope(f"fc:{conf.inputs[2]}"):
+            z = jnp.matmul(x.astype(jnp.float32), gate_w,
+                           precision=jax.lax.Precision.HIGHEST)[..., 0] + gate_b[0]
+        return ce, z
+
+    ce, z = jax.lax.map(jax.checkpoint(one_pass), passes)  # [R, B, T] each, float32
+    log_p = exit_distribution(z)
+    p = jnp.exp(log_p)
+    beta = conf.attr("beta", 0.0)
+    token_cost = jnp.sum(p * ce, axis=0) + beta * jnp.sum(p * log_p, axis=0)
+    mask = loop.mask(jnp.float32) if loop.is_seq else jnp.ones(token_cost.shape, jnp.float32)
+    tokens = jnp.maximum(jnp.sum(mask, axis=1), 1.0)
+    for key, value in (("pass_ce", ce), ("exit_p", p)):
+        ctx.outputs[f"{conf.name}@{key}"] = SeqTensor(
+            (jnp.sum(value * mask, axis=2) / tokens).T)
+    return SeqTensor(jnp.sum(token_cost * mask, axis=1)[:, None])

@@ -90,6 +90,12 @@ def _relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
+@register_activation("silu")
+def _silu(x):
+    # x * sigmoid(x): the gate of the gated MLPs (gate fc, up fc, dotmul, down fc)
+    return jax.nn.silu(x)
+
+
 @register_activation("brelu")
 def _brelu(x):
     # Reference clips to [0, 24] (BReluActivation, ActivationFunction.cpp).

@@ -219,6 +219,24 @@ def _multi_nn_out():
     return LayerOutput(conf, [a, b])
 
 
+def _layer_loop_out():
+    """Three passes of one fc + residual over a sequence, one set of weights."""
+    def step(x):
+        return L.addto([x, L.fc(x, size=6, act=A.Tanh(), name="mix")], act=A.Identity(), name="res")
+
+    return L.layer_loop(step, dense_seq(6), 3, name="loop")
+
+
+def _looped_exit_cost_out():
+    named = paddle.attr.ParamAttr
+    x = L.layer_loop(lambda h: L.fc(h, size=6, act=A.Tanh(), name="mix"),
+                     L.embedding(ids_seq(12, "word"), size=6), 2, name="loop")
+    head = L.fc(x, size=12, act=A.Softmax(), bias_attr=False, param_attr=named(name="head.w"), name="head")
+    gate = L.fc(x, size=1, act=A.Sigmoid(), param_attr=named(name="gate.w"), bias_attr=named(name="gate.b"),
+                name="gate")
+    return L.looped_exit_cost(x, head=head, gate=gate, label=ids_seq(12, "next"), beta=0.05)
+
+
 BUILDERS = {
     "fc": lambda: L.fc(dense(), size=6, act=A.Tanh()),
     "embedding": lambda: L.embedding(ids_seq(), size=6),
@@ -324,6 +342,8 @@ BUILDERS = {
         {"batch_size": 2, "atol": 8e-2, "rtol": 8e-2},
     ),
     "recurrent_group": _recurrent_group_out,
+    "layer_loop": _layer_loop_out,
+    "looped_exit_cost": _looped_exit_cost_out,
     "gru_step": _gru_step_out,
     "lstm_step": _lstm_step_out,
     # tiny eps keeps the finite difference inside one top-k routing cell —

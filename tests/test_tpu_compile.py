@@ -132,3 +132,46 @@ def test_a_data_parallel_step_with_the_blocked_kernels_compiles_for_four_chips(v
     compiled = make_train_step(net, opt, mesh=mesh, quantized=quantized).trace(*placed).lower(lowering_platforms=("tpu",)).compile()
     assert [a - b for a, b in zip(count(), before)] == [3, 0]
     assert compiled.as_text().count("tpu_custom_call") == 6
+
+
+def test_a_data_parallel_step_with_the_looped_decoder_compiles_for_four_chips(v5e, monkeypatch):
+    """`layer_loop` hands its sub-network the trainer's mesh, so the attention
+    layers inside the scan put their kernels under a shard_map over the rows
+    as they do outside one: a looped decoder of 1,024 keys and heads of 128,
+    one layer, two passes, a row a chip, lowers and compiles with one forward
+    kernel in the forward scan's body and, in the backward scan's, the
+    recomputed forward and the fused backward."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import paddle_tpu as paddle
+    from paddle_tpu.core.batch import SeqTensor
+    from paddle_tpu.core.compiler import CompiledNetwork
+    from paddle_tpu.core.topology import Topology, reset_auto_names
+    from paddle_tpu.models.looped_lm import looped_lm_cost
+    from paddle_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+    from paddle_tpu.trainer.step import make_train_step
+    from paddle_tpu.utils.timers import global_stats
+
+    mesh = Mesh(np.array(v5e.devices).reshape(4, 1), (DATA_AXIS, MODEL_AXIS))
+    reset_auto_names()
+    cost, _ = looped_lm_cost(64, 128, n_layers=1, n_passes=2, n_heads=2, head_dim=128, intermediate=64,
+                             exit_beta=0.05)
+    net = CompiledNetwork(Topology([cost]), compute_dtype=jnp.bfloat16)
+    net.mesh = mesh  # as trainer.SGD sets it
+    opt = paddle.optimizer.Adam(learning_rate=1e-3)
+    params, state = jax.eval_shape(net.init, jax.random.PRNGKey(0))
+    ids = SeqTensor(jax.ShapeDtypeStruct((4, 1024), jnp.int32), jax.ShapeDtypeStruct((4,), jnp.int32))
+    batch = {"word": ids, "next_word": ids}
+    args = (params, state, jax.eval_shape(opt.init, params), batch, jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    by_row = NamedSharding(mesh, P(DATA_AXIS))
+    placed = [jax.tree_util.tree_map(
+        lambda x, sh=(by_row if arg is batch else NamedSharding(mesh, P())): jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sh), arg) for arg in args]
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    count = lambda: [global_stats.count(k) for k in ("attention_blocked_layers", "attention_dense_layers", "loop_passes")]
+    before = count()
+    compiled = make_train_step(net, opt, mesh=mesh).trace(*placed).lower(lowering_platforms=("tpu",)).compile()
+    assert [a - b for a, b in zip(count(), before)] == [1, 0, 2]  # one pass traced, two run
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3 and " all-reduce" in text

@@ -25,7 +25,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 KINDS = [
     "train:demo_mnist_mlp", "train:demo_text_lstm", "train:demo_seq2seq_attention",
-    "train:transformer", "train:hybrid_lm", "train:data_mesh4",
+    "train:transformer", "train:hybrid_lm", "train:looped_lm", "train:data_mesh4",
     "eval:demo_mnist_mlp", "eval:demo_text_lstm", "eval:demo_seq2seq_attention",
     "infer:forward", "generate:beam",
     "serving:prefill", "serving:decode", "serving:beam",
@@ -196,6 +196,20 @@ def _():
     trainer = paddle.trainer.SGD(cost=cost, parameters=paddle.parameters.create(cost, seed=0),
                                  update_equation=adam())
     rng = np.random.RandomState(2)
+    rows = [ids(rng, 50, 9) for _ in range(2)]
+    return first_cost(trainer, [(r[:-1], r[1:]) for r in rows], {"word": 0, "next_word": 1})
+
+
+@kind("train:looped_lm")
+def _():
+    from paddle_tpu.models.looped_lm import looped_lm_cost
+
+    reset_auto_names()
+    cost, _ = looped_lm_cost(50, 16, n_layers=1, n_passes=2, n_heads=2, head_dim=8, intermediate=24,
+                             exit_beta=0.05)
+    trainer = paddle.trainer.SGD(cost=cost, parameters=paddle.parameters.create(cost, seed=0),
+                                 update_equation=adam())
+    rng = np.random.RandomState(3)
     rows = [ids(rng, 50, 9) for _ in range(2)]
     return first_cost(trainer, [(r[:-1], r[1:]) for r in rows], {"word": 0, "next_word": 1})
 
