@@ -102,12 +102,15 @@ class Inference:
             self.trace_count += 1
             all_outs, _ = self.network.apply(params, batch, state=state, train=False)
             # Keep auxiliary side outputs of the selected layers too
-            # ("<name>@scores" from beam_search, "<name>@cell" from lstm_step).
+            # ("<name>@scores" from beam_search, "<name>@cell" from lstm_step),
+            # but only those that ARE batch-major values: a group's
+            # "@logits_rows" holds what "@logits" holds in another row order
             keep = set(self.output_names)
             return {
                 n: v
                 for n, v in all_outs.items()
-                if n in keep or n.split("@")[0] in keep
+                if (n in keep or n.split("@")[0] in keep)
+                and isinstance(v, SeqTensor)
             }
 
         self._fwd = jax.jit(fwd)

@@ -17,6 +17,7 @@ from paddle_tpu.core import initializers as init
 from paddle_tpu.core.batch import SeqTensor
 from paddle_tpu.layers.base import register_layer
 from paddle_tpu.ops import acc_matmul
+from paddle_tpu.utils.timers import global_stats
 
 _EPS = 1e-10
 # two-sided probability clip for the BCE family: must be representable in
@@ -69,9 +70,21 @@ def cross_entropy_apply(conf, params, inputs, ctx):
     reference MultiClassCrossEntropy (CostLayer.cpp).  When the producing
     layer's activation was softmax, the compiler exposes its pre-activation
     as `<name>@logits` and we fuse into log-softmax CE instead (stable, one
-    less kernel)."""
+    less kernel).  Where the producer is a recurrent_group with a hoisted
+    output layer it exposes the same logits as the [T*B, V] rows they were
+    computed as (`<name>@logits_rows`, recurrent_group.HoistedRows): the
+    reduction over V then runs on the rows where they lie, the ids folded
+    into their order and the per-row cost unfolded to [B, T], and nothing
+    reads (or copies) the [B, T, V] view.  Which of the two a layer traced
+    took is counted: ce_hoisted_rows_layers / ce_batch_major_layers."""
     prob, label = inputs[0], inputs[1]
     ids = _label_ids(label)
+    hoisted = ctx.outputs.get(conf.inputs[0] + "@logits_rows")
+    if hoisted is not None:
+        global_stats.incr("ce_hoisted_rows_layers")
+        cost = _fused_ce_from_logits(hoisted.rows, hoisted.fold(ids))
+        return _per_sample(hoisted.unfold(cost), prob)
+    global_stats.incr("ce_batch_major_layers")
     logits = ctx.outputs.get(conf.inputs[0] + "@logits")
     if logits is not None:
         return _per_sample(_fused_ce_from_logits(logits.data, ids), prob)

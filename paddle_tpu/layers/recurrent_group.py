@@ -735,6 +735,17 @@ def recurrent_group_apply(conf, params, inputs, ctx: ApplyContext) -> SeqTensor:
             ctx.outputs[conf.name + "@logits"] = SeqTensor(
                 rows.unfold_batch_major(lg.data, reverse), lengths
             )
+            # ... and the same values as the ROWS they were computed as, for
+            # a consumer that reduces them over the vocabulary (softmax-CE,
+            # the evaluator's argmax): it folds its narrow per-token input
+            # into the rows' order and unfolds its narrow per-row result,
+            # and the [B, T, vocab] view above stays unread.  Read, that
+            # view costs a copy of the whole array: XLA:TPU lays the GEMM's
+            # [T*B, V] output out with the rows along the lanes, which no
+            # [B, T] split of the rows is a bitcast of (PERF.md, PR 37).
+            ctx.outputs[conf.name + "@logits_rows"] = HoistedRows(
+                lg.data, rows, reverse
+            )
     else:
         ys = ys_stacked[0]
         if ys.lengths is not None:
@@ -1091,6 +1102,15 @@ class _HoistRows:
         self.t, self.b = t, b
         self.n = n if b % n == 0 else 1
 
+    # static part of a HoistedRows pytree: two traces of one shape agree
+    def __eq__(self, other) -> bool:
+        return type(other) is type(self) and (
+            (self.t, self.b, self.n) == (other.t, other.b, other.n)
+        )
+
+    def __hash__(self) -> int:
+        return hash((self.t, self.b, self.n))
+
     def fold(self, d: jnp.ndarray) -> jnp.ndarray:
         """Stacked [T, B, ...] -> rows [T*B, ...]."""
         t, b, n = self.t, self.b, self.n
@@ -1144,6 +1164,50 @@ class _HoistRows:
         if reverse:
             r = jnp.flip(r, axis=1)
         return jnp.swapaxes(r, 1, 2).reshape((b, t) + rest)
+
+    def fold_batch_major(self, d: jnp.ndarray, reverse: bool) -> jnp.ndarray:
+        """A [B, T, ...] value in the group's output order -> rows
+        [T*B, ...] (unfold_batch_major's inverse): how a per-token input of
+        a consumer of the rows, the label ids of a cost, gets their order."""
+        t, b, n = self.t, self.b, self.n
+        if n == 1:
+            d = jnp.swapaxes(d, 0, 1)
+            if reverse:
+                d = jnp.flip(d, axis=0)
+            return self.fold(d)
+        rest = d.shape[2:]
+        d = jnp.swapaxes(d.reshape((n, b // n, t) + rest), 1, 2)
+        if reverse:
+            d = jnp.flip(d, axis=1)
+        return d.reshape((t * b,) + rest)
+
+
+@jax.tree_util.register_pytree_node_class
+class HoistedRows:
+    """A hoisted epilogue's output as the [T*B, ...] rows it was computed as,
+    with their order (``<group>@logits_rows`` beside ``<group>@logits``).
+
+    ``rows`` is the one leaf; the order (a _HoistRows and the group's
+    ``reverse``) is static.  ``fold`` puts a [B, T, ...] per-token value
+    into the rows' order, ``unfold`` a per-row result [T*B, ...] back into
+    [B, T, ...]: both move one narrow value a row, where reading the rows
+    as [B, T, V] moves all of them."""
+
+    def __init__(self, rows: jnp.ndarray, order: "_HoistRows", reverse: bool):
+        self.rows, self.order, self.reverse = rows, order, bool(reverse)
+
+    def tree_flatten(self):
+        return (self.rows,), (self.order, self.reverse)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(children[0], *aux)
+
+    def fold(self, d: jnp.ndarray) -> jnp.ndarray:
+        return self.order.fold_batch_major(d, self.reverse)
+
+    def unfold(self, r: jnp.ndarray) -> jnp.ndarray:
+        return self.order.unfold_batch_major(r, self.reverse)
 
 
 def mask_like(ys: jnp.ndarray, lengths: jnp.ndarray) -> jnp.ndarray:

@@ -46,10 +46,20 @@ def default_metrics_fn(topology: Topology) -> Optional[Callable]:
                 # when the producer exposed one, so the error metric never
                 # forces the [N, V] softmax to materialize (at a 32k MT vocab
                 # that softmax is ~1 GB per step and exists ONLY for this
-                # metric — the fused CE reads logits)
-                lg = outs.get(pred_name + "@logits")
-                scores = lg.data if lg is not None else pred.data
-                err = (jnp.argmax(scores, axis=-1) != ids).astype(jnp.float32)
+                # metric — the fused CE reads logits).  Where a
+                # recurrent_group exposed it as the rows it was computed as,
+                # take the argmax over the rows and put the one id a row into
+                # [B, T] order, as the cost layer does (layers/cost.py
+                # cross_entropy_apply): no reader of the [B, T, V] view is
+                # left to keep its copy alive
+                hoisted = outs.get(pred_name + "@logits_rows")
+                if hoisted is not None:
+                    top = hoisted.unfold(jnp.argmax(hoisted.rows, axis=-1))
+                else:
+                    lg = outs.get(pred_name + "@logits")
+                    scores = lg.data if lg is not None else pred.data
+                    top = jnp.argmax(scores, axis=-1)
+                err = (top != ids).astype(jnp.float32)
                 if pred.is_seq and err.ndim == 2:
                     mask = pred.mask()
                     err = jnp.sum(err * mask) / jnp.maximum(jnp.sum(mask), 1.0)

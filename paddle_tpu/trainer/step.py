@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from paddle_tpu.core.batch import SeqTensor
 from paddle_tpu.core.compiler import CompiledNetwork, NetState, Params
 from paddle_tpu.optimizer import Optimizer, OptState
 from paddle_tpu.parallel.mesh import DATA_AXIS
@@ -230,6 +231,10 @@ def make_quantized_train_step(
             mean=True,
         )
         cost = jax.lax.pmean(cost.astype(jnp.float32), DATA_AXIS)
+        # out_specs joins the shards' outputs along axis 0, which puts
+        # batch-major values together again and nothing else: a group's
+        # "@logits_rows" (its rows' order is not the batch's) stays inside
+        outs = {k: v for k, v in outs.items() if isinstance(v, SeqTensor)}
         return grads, cost, new_state, outs
 
     smapped = jax.shard_map(

@@ -452,3 +452,168 @@ def test_indivisible_batch_falls_back_to_time_major(n):
     got = _value_grads_outs(_mesh_group(), batch, n)
     want = _value_grads_outs(_mesh_group(), batch, None)
     _assert_same(got, want)
+
+
+# ---------------------------------------------------------------------------
+# The rows path (rg.HoistedRows): a consumer that reduces the hoisted output
+# over the vocabulary reads `<group>@logits_rows`, the [T*B, V] rows in the
+# order they were computed in, folds its per-token input into that order and
+# unfolds its per-row result; `<group>@logits` stays for everyone else.
+# Withholding the rows puts cost layer and evaluator back on `@logits`.
+# ---------------------------------------------------------------------------
+
+
+def _withhold_rows(monkeypatch):
+    monkeypatch.setattr(rg, "HoistedRows", lambda *a: None)
+
+
+def _ce_counts():
+    from paddle_tpu.utils.timers import global_stats
+
+    return tuple(
+        global_stats.count(f"ce_{path}_layers")
+        for path in ("hoisted_rows", "batch_major")
+    )
+
+
+@pytest.mark.parametrize("n", MESHES)
+@pytest.mark.parametrize("reverse", [False, True], ids=["forward", "reverse"])
+@pytest.mark.parametrize("static", [False, True], ids=["plain", "static"])
+def test_rows_path_matches_logits_path(monkeypatch, n, reverse, static):
+    """Cost and every leaf's gradient through the rows against the same
+    group with the rows withheld (the cost layer then reads `@logits`)."""
+    batch = _mesh_batch()
+    got = _value_grads_outs(_mesh_group(reverse, static), batch, n)
+    hoisted = got[2]["mg@logits_rows"]
+    assert isinstance(hoisted, rg.HoistedRows)
+    assert hoisted.rows.shape == (6 * 8, 29)
+    _withhold_rows(monkeypatch)
+    want = _value_grads_outs(_mesh_group(reverse, static), batch, n)
+    assert want[2]["mg@logits_rows"] is None
+    _assert_same(got, want)
+    # the rows ARE the logits, in another order
+    np.testing.assert_allclose(
+        hoisted.unfold(hoisted.rows), want[2]["mg@logits"].data,
+        rtol=1e-5, atol=1e-6,
+    )
+
+
+@pytest.mark.parametrize("n", MESHES)
+@pytest.mark.parametrize("reverse", [False, True], ids=["forward", "reverse"])
+def test_default_evaluator_reads_the_rows(monkeypatch, n, reverse):
+    """classification_error from the argmax over the rows, its ids unfolded,
+    equals the one from the argmax over `@logits`, padding masked alike."""
+    from paddle_tpu.trainer.evaluators import default_metrics_fn
+
+    batch = _mesh_batch()
+
+    def error():
+        cost = _mesh_group(reverse)
+        outs = _value_grads_outs(cost, batch, n)[2]
+        return outs, default_metrics_fn(Topology([cost]))(outs)
+
+    outs, got = error()
+    assert isinstance(outs["mg@logits_rows"], rg.HoistedRows)
+    _withhold_rows(monkeypatch)
+    outs, want = error()
+    assert outs["mg@logits_rows"] is None
+    assert set(got) == {"classification_error"}
+    # by hand from the batch-major logits: the value both must give
+    valid = np.asarray(batch["y"].mask(bool))
+    wrong = np.asarray(jnp.argmax(outs["mg@logits"].data, -1) != batch["y"].data)
+    np.testing.assert_allclose(want["classification_error"], wrong[valid].mean(), rtol=1e-6)
+    np.testing.assert_allclose(
+        got["classification_error"], want["classification_error"], rtol=1e-6
+    )
+
+
+def _plain_softmax_cost(vocab=29):
+    reset_auto_names()
+    paddle.init(seed=21)
+    x = L.data("x", paddle.data_type.integer_value_sequence(vocab))
+    out = L.fc(L.embedding(x, size=12), size=vocab, act=A.Softmax())
+    lab = L.data("y", paddle.data_type.integer_value_sequence(vocab))
+    return L.classification_cost(input=out, label=lab)
+
+
+@pytest.mark.parametrize(
+    "build,withheld,rows_batch_major",
+    [
+        (_mesh_group, False, (1, 0)),
+        (_mesh_group, True, (0, 1)),
+        (_plain_softmax_cost, False, (0, 1)),
+    ],
+    ids=["hoisted_group", "hoisted_group_rows_withheld", "plain_fc_softmax"],
+)
+def test_cost_layer_counts_the_path_it_took(monkeypatch, build, withheld, rows_batch_major):
+    """One count a cost layer traced: ce_hoisted_rows_layers where the
+    producer exposed its rows, ce_batch_major_layers where it did not."""
+    if withheld:
+        _withhold_rows(monkeypatch)
+    cost = build()
+    net = CompiledNetwork(Topology([cost]))
+    params, state = net.init(jax.random.PRNGKey(0))
+    before = _ce_counts()
+    jax.eval_shape(
+        lambda p: net.cost(p, _mesh_batch(), state=state, rng=None, train=True)[0],
+        params,
+    )
+    after = _ce_counts()
+    assert tuple(a - b for a, b in zip(after, before)) == rows_batch_major
+
+
+@pytest.mark.parametrize("n", [1, 2, 4])
+@pytest.mark.parametrize("reverse", [False, True], ids=["forward", "reverse"])
+def test_hoisted_rows_cross_jit_as_a_pytree(n, reverse):
+    """The array is the one leaf; the order (T, B, n, reverse) is static and
+    comes back, so fold / unfold work on the far side of a jit and are each
+    other's inverse there."""
+    t, b = 5, 8
+    order = rg._HoistRows(t, b, _mesh(n))
+    x = jnp.arange(t * b * 3, dtype=jnp.float32).reshape(t * b, 3)
+    hoisted = rg.HoistedRows(x, order, reverse)
+    leaves, treedef = jax.tree_util.tree_flatten(hoisted)
+    assert len(leaves) == 1 and leaves[0] is x
+    # two traces of one shape give equal static parts: one jit cache entry
+    assert treedef == jax.tree_util.tree_structure(
+        rg.HoistedRows(x, rg._HoistRows(t, b, _mesh(n)), reverse)
+    )
+    assert treedef != jax.tree_util.tree_structure(
+        rg.HoistedRows(x, order, not reverse)
+    )
+    back = jax.jit(lambda h: jax.tree_util.tree_map(lambda a: a * 2, h))(hoisted)
+    assert isinstance(back, rg.HoistedRows)
+    assert (back.order, back.reverse) == (order, reverse)
+    np.testing.assert_array_equal(back.rows, x * 2)
+    per_token = jnp.arange(b * t, dtype=jnp.int32).reshape(b, t)
+    folded = jax.jit(lambda h, d: h.fold(d))(back, per_token)
+    assert folded.shape == (t * b,)
+    np.testing.assert_array_equal(back.unfold(folded), per_token)
+    np.testing.assert_array_equal(
+        back.unfold(back.rows), order.unfold_batch_major(x * 2, reverse)
+    )
+
+
+def test_inference_returns_batch_major_values_only():
+    """`paddle.infer` keeps a selected layer's "@" side outputs, sliced back
+    to the rows that were fed; the group's rows are its logits in an order of
+    its own and are not among them."""
+    from paddle_tpu.inference import Inference
+
+    cost = _mesh_group()
+    group = next(
+        lo for lo in cost.parents if lo.conf.type == "recurrent_group"
+    )
+    inferer = Inference(
+        output_layer=group, parameters=paddle.parameters.create(cost)
+    )
+    rng = np.random.RandomState(1)
+    samples = [(list(rng.randint(0, 29, n)),) for n in (5, 2, 4)]
+    (outs,) = list(inferer.iter_infer(input=samples))
+    assert set(outs) == {"mg", "mg@logits"}
+    assert outs["mg"].data.shape[0] == outs["mg@logits"].data.shape[0] == 3
+    # ... while the network itself exposes them, in test mode too
+    net_outs, _ = inferer.network.apply(
+        inferer._params, _mesh_batch(), state=inferer._state, train=False
+    )
+    assert isinstance(net_outs["mg@logits_rows"], rg.HoistedRows)

@@ -192,6 +192,12 @@ class _TimeMajorRows:
             r = jnp.flip(r, axis=0)
         return jnp.swapaxes(r, 0, 1)
 
+    def fold_batch_major(self, d, reverse):
+        d = jnp.swapaxes(d, 0, 1)
+        if reverse:
+            d = jnp.flip(d, axis=0)
+        return self.fold(d)
+
 
 @pytest.mark.parametrize("mesh_n", [None, 1], ids=["no_mesh", "data1"])
 def test_one_shard_lowers_to_the_time_major_program(model, mesh_n, monkeypatch):
@@ -226,3 +232,77 @@ def test_meshed_step_matches_single_device_step(model, n):
     assert any(np.abs(g).max() > 1e-4 for g in one)
     for a, b_ in zip(dp, one):
         np.testing.assert_allclose(a, b_, rtol=1e-4, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# The hoisted output layer's logits as rows (rg.HoistedRows): softmax-CE and
+# the evaluator's argmax read the [T*B, V] rows where they lie, so the
+# training step never views them as [B, T, V].
+# ---------------------------------------------------------------------------
+
+_RANK3_VOCAB = re.compile(
+    rf"stablehlo\.(?:transpose|reshape)\b.*-> tensor<\d+x\d+x{VOCAB}x[a-z]"
+)
+
+
+def _rank3_vocab_views(lowered_text):
+    """transposes and reshapes of a lowered step whose result has the
+    vocabulary as the last of three axes: the [B, T, V] (or [T, B, V]) view
+    of the hoisted rows and its cotangent's way back.  jax lowers what the
+    step's outputs need and nothing else, so a view nobody reads is not in
+    the text."""
+    return [
+        line.strip()[:160] for line in lowered_text.splitlines()
+        if _RANK3_VOCAB.search(line)
+    ]
+
+
+@pytest.mark.parametrize("mesh_n", [None, 4], ids=["no_mesh", "data4"])
+@pytest.mark.parametrize("name", ["seq2seq_fused", "gru_group_tagger"])
+def test_lowered_step_never_views_the_rows_batch_major(name, mesh_n, monkeypatch):
+    """A count that carries over to the chip: with cost layer and evaluator on
+    the rows, the lowered training step holds no rank-3 view of the logits
+    (on the chip each is a copy of the whole array, PERF.md PR 37); with the
+    rows withheld it holds them, so the count reads what it says."""
+    build, fused, _ = MODELS[name]
+    mesh = None if mesh_n is None else _mesh(mesh_n)
+    old = get_flag("fused_attention_gru")
+    set_flag("fused_attention_gru", fused)
+    try:
+        texts = []
+        for withheld in (False, True):
+            if withheld:
+                monkeypatch.setattr(rg, "HoistedRows", lambda *a: None)
+            reset_auto_names()
+            paddle.init(seed=11)
+            trainer, args = _trainer_and_args(build(), mesh)
+            texts.append(trainer._train_step.lower(*args).as_text())
+    finally:
+        set_flag("fused_attention_gru", old)
+    assert _rank3_vocab_views(texts[0]) == []
+    assert _rank3_vocab_views(texts[1])
+
+
+@pytest.mark.parametrize("n", [2, 4])
+def test_quantized_allreduce_step_keeps_the_rows_inside_its_shards(n):
+    """The quantized-allreduce step runs the network inside a shard_map and
+    joins the shards' outputs along the batch; the rows' order is not the
+    batch's, so they stay inside and the evaluator outside reads `@logits`:
+    cost and classification_error are the plain data-parallel step's."""
+    got = {}
+    for quantized in (False, True):
+        old = get_flag("quantized_allreduce")
+        set_flag("quantized_allreduce", quantized)
+        try:
+            reset_auto_names()
+            paddle.init(seed=11)
+            trainer, args = _trainer_and_args(_seq2seq(), _mesh(n))
+            metrics = trainer._train_step(*args)[3]
+        finally:
+            set_flag("quantized_allreduce", old)
+        got[quantized] = {k: float(metrics[k]) for k in ("cost", "classification_error")}
+    assert 0.0 < got[True]["classification_error"] <= 1.0
+    np.testing.assert_allclose(got[True]["cost"], got[False]["cost"], rtol=1e-5)
+    np.testing.assert_allclose(
+        got[True]["classification_error"], got[False]["classification_error"], rtol=1e-6
+    )

@@ -10,6 +10,7 @@ library is loaded by the one that runs this one.
 
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -175,3 +176,54 @@ def test_a_data_parallel_step_with_the_looped_decoder_compiles_for_four_chips(v5
     assert [a - b for a, b in zip(count(), before)] == [1, 0, 2]  # one pass traced, two run
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 3 and " all-reduce" in text
+
+
+@pytest.mark.parametrize("rows", ["exposed", "withheld"])
+def test_the_seq2seq_step_compiled_for_a_v5e_copies_its_logits_only_for_a_batch_major_reader(v5e, monkeypatch, rows):
+    """XLA:TPU lays the hoisted output layer's [T*B, V] product out with the
+    rows along the lanes; a reader of its [B, T, V] view costs a `copy` of the
+    whole array into another tiling (`nmt-train`: 1.72 GB, 5.7 ms a step,
+    PERF.md PR 37).  With softmax-CE and the evaluator's argmax on the rows
+    (`<group>@logits_rows`) the compiled training step holds no copy of an
+    array of T*B x V elements; with the rows withheld it holds them, at these
+    widths too (T = 56 as the cell pads to, so that no [B, T] split of the
+    rows tiles the sublanes)."""
+    import importlib
+
+    from jax.sharding import SingleDeviceSharding
+
+    import paddle_tpu as paddle
+    from paddle_tpu.core.batch import SeqTensor
+    from paddle_tpu.core.compiler import CompiledNetwork
+    from paddle_tpu.core.topology import Topology, reset_auto_names
+    from paddle_tpu.models.seq2seq import seq2seq_cost
+    from paddle_tpu.trainer.evaluators import default_metrics_fn
+    from paddle_tpu.trainer.step import make_train_step
+    from paddle_tpu.utils.timers import global_stats
+
+    if rows == "withheld":
+        rg = importlib.import_module("paddle_tpu.layers.recurrent_group")
+        monkeypatch.setattr(rg, "HoistedRows", lambda *a: None)
+    vocab, b, t = 1000, 128, 56
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    reset_auto_names()
+    cost, _ = seq2seq_cost(vocab, vocab, word_dim=64, hidden_dim=64)
+    net = CompiledNetwork(Topology([cost]), compute_dtype=jnp.bfloat16)
+    opt = paddle.optimizer.Adam(learning_rate=1e-3)
+    params, state = jax.eval_shape(net.init, jax.random.PRNGKey(0))
+    ids = SeqTensor(jax.ShapeDtypeStruct((b, t), jnp.int32), jax.ShapeDtypeStruct((b,), jnp.int32))
+    batch = {name: ids for name in ("src_word", "trg_word", "trg_next")}
+    args = (params, state, jax.eval_shape(opt.init, params), batch, jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    placed = jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), args)
+
+    count = lambda: [global_stats.count(f"ce_{path}_layers") for path in ("hoisted_rows", "batch_major")]
+    before = count()
+    step = make_train_step(net, opt, extra_metrics=default_metrics_fn(net.topology))
+    text = step.trace(*placed).lower(lowering_platforms=("tpu",)).compile().as_text()
+    assert [a - b_ for a, b_ in zip(count(), before)] == ([1, 0] if rows == "exposed" else [0, 1])
+    copied = []
+    for line in text.splitlines():
+        shape = re.search(r"= \w+\[([0-9,]+)\]\S* copy\(", line)
+        if shape and np.prod([int(d) for d in shape.group(1).split(",")]) == vocab * b * t:
+            copied.append(line.strip()[:120])
+    assert bool(copied) == (rows == "withheld"), copied

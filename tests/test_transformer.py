@@ -235,3 +235,44 @@ def test_inside_a_shard_map_the_kernels_are_called_bare_only_where_it_holds_ever
                                  axis_names=set(held), check_vma=False), jnp.ones((4, 8)))
     (got, why), = seen
     assert got == taken and (why is None) == taken
+
+
+def test_the_transformers_cost_layer_reads_batch_major_logits_and_folds_no_label():
+    """The Transformer's output layer is a plain `fc` softmax: no group
+    exposes rows, so softmax-CE and the evaluator take `@logits` as they
+    always did, say so (`ce_batch_major_layers` 1, `ce_hoisted_rows_layers`
+    0, one count a cost layer traced), and the lowered training step moves
+    no [B, T] integer array into another order (the fold of the label ids
+    that the rows path of layers/cost.py makes)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.core.batch import SeqTensor
+    from paddle_tpu.core.compiler import CompiledNetwork
+    from paddle_tpu.core.topology import Topology
+    from paddle_tpu.trainer.evaluators import default_metrics_fn
+    from paddle_tpu.trainer.step import make_train_step
+    from paddle_tpu.utils.timers import global_stats
+
+    reset_auto_names()
+    b, t = 3, 5
+    cost, _ = transformer_cost(50, 50, d_model=16, n_heads=2, n_layers=1, d_ff=32)
+    net = CompiledNetwork(Topology([cost]), compute_dtype=jnp.bfloat16)
+    opt = paddle.optimizer.Adam(learning_rate=1e-3)
+    params, state = net.init(jax.random.PRNGKey(0))
+    ids = SeqTensor(jnp.ones((b, t), jnp.int32), jnp.full((b,), t, jnp.int32))
+    batch = {name: ids for name in ("src_word", "trg_word", "trg_next")}
+    count = lambda: [global_stats.count(f"ce_{path}_layers") for path in ("hoisted_rows", "batch_major")]
+    before = count()
+    step = make_train_step(net, opt, extra_metrics=default_metrics_fn(net.topology))
+    text = step.lower(params, state, opt.init(params), batch, jax.random.PRNGKey(0)).as_text()
+    assert [a - b_ for a, b_ in zip(count(), before)] == [0, 1]
+    outs = jax.eval_shape(lambda p: net.apply(p, batch, state=state, train=True)[0], params)
+    assert not [name for name in outs if name.endswith("@logits_rows")]
+    moved = [
+        line.strip()[:140] for line in text.splitlines()
+        if re.search(rf"stablehlo\.transpose.*\(tensor<{b}x{t}xi32>\)", line)
+        or re.search(rf"\(tensor<{b}x{t}xi32>\) -> tensor<{b * t}xi32>", line)
+    ]
+    assert moved == []
