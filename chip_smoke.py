@@ -32,7 +32,6 @@ from __future__ import annotations
 import json
 import os
 import sys
-import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -42,39 +41,28 @@ NMT_FEEDING = {"src_word": 0, "trg_word": 1, "trg_next": 2}
 
 
 class CompileMeter:
-    """Counts and times this process's XLA compiles through jax.monitoring
-    (listeners cannot be unregistered: make one per process)."""
-
-    _TIMED = (
-        "/jax/core/compile/jaxpr_trace_duration",
-        "/jax/core/compile/jaxpr_to_mlir_module_duration",
-        "/jax/core/compile/backend_compile_duration",
-    )
+    """This process's XLA compiles, read off the program's own counters: the
+    one jax.monitoring listener is ``paddle_tpu.utils.compile_cache``'s,
+    installed when ``paddle_tpu`` is imported, and it keeps ``jit/trace``,
+    ``jit/lower``, ``jit/compile`` and ``jit/cache_hit`` in ``global_stats``."""
 
     def __init__(self) -> None:
-        import jax
-
-        self._lock = threading.Lock()  # the serving step thread compiles too
-        self.compiles = 0
-        self.seconds = 0.0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event: str, secs: float, **_: Any) -> None:
-        if event in self._TIMED:
-            with self._lock:
-                self.seconds += secs
-                self.compiles += event == self._TIMED[-1]
-
-    def _event(self, event: str, **_: Any) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            with self._lock:
-                self.cache_hits += 1
+        import paddle_tpu  # noqa: F401  (installs the listener)
 
     def snapshot(self) -> Tuple[int, float, int]:
-        with self._lock:
-            return self.compiles, self.seconds, self.cache_hits
+        """-> (backend compiles, loads from the cache among them; seconds of
+        trace + lowering + compile; persistent-cache hits)."""
+        from paddle_tpu.utils.timers import global_stats
+
+        stats = global_stats.summary()
+        seconds = sum(
+            stats.get("jit/" + phase, {}).get("total", 0.0)
+            for phase in ("trace", "lower", "compile")
+        )
+        return (
+            global_stats.count("jit/compile"), seconds,
+            global_stats.count("jit/cache_hit"),
+        )
 
 
 def device_fields() -> Dict[str, Any]:

@@ -16,6 +16,10 @@ User surface mirrors ``paddle.v2``::
 
 from __future__ import annotations
 
+import time as _time
+
+_IMPORT_T0 = _time.monotonic()  # the span `import` runs from this line to the last
+
 from paddle_tpu import activation  # noqa: F401
 from paddle_tpu import attr  # noqa: F401
 from paddle_tpu import dataset  # noqa: F401
@@ -68,48 +72,65 @@ def init(
     log_period=50); unknown names are accepted-and-ignored like the
     reference's tolerant command-line init.
     """
-    import random
+    from paddle_tpu import obs as _obs
 
-    import numpy as np
+    with _obs.span("init", cat="setup"):
+        import random
 
-    from paddle_tpu.utils import flags as _flags
+        import numpy as np
 
-    # Only arguments the caller actually passed enter the explicit layer —
-    # otherwise init()'s python defaults would mask PADDLE_TPU_* env
-    # overrides (the documented defaults < env < explicit precedence).
-    explicit = {
-        k: v
-        for k, v in dict(
-            use_tpu=use_tpu, trainer_count=trainer_count, seed=seed
-        ).items()
-        if v is not None
-    }
-    if "use_tpu" in explicit:
-        explicit["use_tpu"] = bool(explicit["use_tpu"])
-    _flags.set_flags(**explicit)
-    seed_val = _flags.get_flag("seed")
-    random.seed(seed_val)
-    np.random.seed(seed_val)
-    for k, v in kwargs.items():
-        try:
-            _flags.set_flag(k, v)
-        except KeyError:
-            pass  # v1 configs pass gpu-era flags; accept silently
-    # compute_dtype comes from THIS call's argument, else the flag plane
-    # (env PADDLE_TPU_COMPUTE_DTYPE or an explicit flags.set_flag).  init
-    # never WRITES the flag: the argument is per-call configuration, so a
-    # later bare init() (or set_default_compute_dtype(None)) is not
-    # silently overridden by an earlier call's choice.
-    dtype_src = (
-        compute_dtype
-        if compute_dtype is not None
-        else _flags.get_flag("compute_dtype")
-    )
-    if dtype_src:
-        from paddle_tpu.core.compiler import set_default_compute_dtype
+        from paddle_tpu.utils import flags as _flags
 
-        set_default_compute_dtype(dtype_src)
-    if _flags.get_flag("check_nans"):
-        from paddle_tpu.utils.profiler import enable_nan_checks
+        # Only arguments the caller actually passed enter the explicit layer —
+        # otherwise init()'s python defaults would mask PADDLE_TPU_* env
+        # overrides (the documented defaults < env < explicit precedence).
+        explicit = {
+            k: v
+            for k, v in dict(
+                use_tpu=use_tpu, trainer_count=trainer_count, seed=seed
+            ).items()
+            if v is not None
+        }
+        if "use_tpu" in explicit:
+            explicit["use_tpu"] = bool(explicit["use_tpu"])
+        _flags.set_flags(**explicit)
+        seed_val = _flags.get_flag("seed")
+        random.seed(seed_val)
+        np.random.seed(seed_val)
+        for k, v in kwargs.items():
+            try:
+                _flags.set_flag(k, v)
+            except KeyError:
+                pass  # v1 configs pass gpu-era flags; accept silently
+        # compute_dtype comes from THIS call's argument, else the flag plane
+        # (env PADDLE_TPU_COMPUTE_DTYPE or an explicit flags.set_flag).  init
+        # never WRITES the flag: the argument is per-call configuration, so a
+        # later bare init() (or set_default_compute_dtype(None)) is not
+        # silently overridden by an earlier call's choice.
+        dtype_src = (
+            compute_dtype
+            if compute_dtype is not None
+            else _flags.get_flag("compute_dtype")
+        )
+        if dtype_src:
+            from paddle_tpu.core.compiler import set_default_compute_dtype
 
-        enable_nan_checks(True)
+            set_default_compute_dtype(dtype_src)
+        if _flags.get_flag("check_nans"):
+            from paddle_tpu.utils.profiler import enable_nan_checks
+
+            enable_nan_checks(True)
+
+
+def _finish_import() -> None:
+    """The process's one jax.monitoring listener (utils/compile_cache.py),
+    then the span ``import``: this file from its first line to here, on the
+    tracer's own clock."""
+    from paddle_tpu import obs as _obs
+    from paddle_tpu.utils.compile_cache import install_jit_listener
+
+    install_jit_listener()
+    _obs.complete("import", "setup", _time.monotonic() - _IMPORT_T0)
+
+
+_finish_import()

@@ -34,6 +34,7 @@ from typing import Any, Optional
 
 from paddle_tpu.obs.tracer import (  # noqa: F401
     Tracer,
+    complete,
     flight_dump,
     instant,
     next_rpc_id,
@@ -46,6 +47,7 @@ __all__ = [
     "tracer",
     "span",
     "instant",
+    "complete",
     "flight_dump",
     "next_rpc_id",
     "write_stats_json",

@@ -50,8 +50,8 @@ def load_trace(path: str) -> Dict[str, Any]:
 
 def validate_trace(obj: Dict[str, Any]) -> List[str]:
     """Schema problems of one trace object (empty list = valid):
-    required keys on every event, well-formed args, balanced B/E pairing
-    per (pid, tid) with matching names."""
+    required keys on every event, well-formed args, a ``dur`` on every X,
+    balanced B/E pairing per (pid, tid) with matching names."""
     problems: List[str] = []
     evs = obj.get("traceEvents")
     if not isinstance(evs, list):
@@ -68,7 +68,12 @@ def validate_trace(obj: Dict[str, Any]) -> List[str]:
             problems.append(f"event {i}: args is not an object")
         ph = ev.get("ph")
         key = (ev.get("pid"), ev.get("tid"))
-        if ph == "B":
+        if ph == "X":
+            # a complete event (Tracer.complete) pairs with nothing: its
+            # length rides beside its start
+            if not isinstance(ev.get("dur"), (int, float)):
+                problems.append(f"event {i}: X without a numeric dur")
+        elif ph == "B":
             stacks.setdefault(key, []).append(ev.get("name"))
         elif ph == "E":
             stack = stacks.setdefault(key, [])

@@ -23,9 +23,11 @@ Design:
   init (pragma'd) purely so the merger can coarse-align processes that
   share no RPC edge.
 * **Chrome-trace-event JSON** (``dump``): the per-process file opens
-  directly in Perfetto / chrome://tracing.  Events carry ``ph`` (B/E/i),
-  ``ts`` (µs), ``pid``, ``tid``, ``name``, ``cat`` (the plane: trainer /
-  serving / master / rpc / elastic) and ``args`` — correlation ids
+  directly in Perfetto / chrome://tracing.  Events carry ``ph`` (B/E/i,
+  or X with a ``dur`` for an interval recorded at its end,
+  :meth:`Tracer.complete`), ``ts`` (µs), ``pid``, ``tid``, ``name``,
+  ``cat`` (the plane: trainer / serving / master / rpc / elastic / setup /
+  jit) and ``args`` — correlation ids
   (``req`` for a serving request, ``task`` for an elastic task, ``rpc``
   for an RPC exchange) ride in ``args`` so one request's
   submit→queued→admit→prefill→decode→deliver spans line up across
@@ -69,6 +71,7 @@ __all__ = [
     "tracer",
     "span",
     "instant",
+    "complete",
     "next_rpc_id",
     "flight_dump",
 ]
@@ -97,8 +100,11 @@ class Tracer:
 
         self._clock = clock  # monotonic by contract (rule A205)
         self._lock = make_lock("obs-tracer")
-        # tid -> deque of (ph, ts_us, name, cat, args); guarded by _lock
+        # tid -> deque of (ph, ts_us, name, cat, args, dur_us); guarded by
+        # _lock; dur_us is None but for phase X
         self._rings: Dict[int, collections.deque] = {}
+        # tid -> events the full ring has dropped; guarded by _lock
+        self._evicted: Dict[int, int] = {}
         self._thread_names: Dict[int, str] = {}  # guarded by _lock
         self._ring_events = int(
             ring_events if ring_events is not None
@@ -210,13 +216,18 @@ class Tracer:
 
     # -- recording -------------------------------------------------------
     def _emit(self, ph: str, name: str, cat: str,
-              args: Optional[Dict[str, Any]]) -> Optional[float]:
+              args: Optional[Dict[str, Any]],
+              seconds: Optional[float] = None) -> Optional[float]:
         """Records one event; returns the clock reading (seconds) it was
-        stamped with, None when disarmed."""
+        taken at, None when disarmed.  ``seconds`` (phase X only) is how
+        long ago the event's interval began."""
         if not self._recording:
             return None
         now = self._clock()
-        ts_us = now * _US
+        ts_us, dur_us = now * _US, None
+        if seconds is not None:
+            dur_us = seconds * _US
+            ts_us -= dur_us
         tid = threading.get_ident()
         with self._lock:
             ring = self._rings.get(tid)
@@ -224,13 +235,25 @@ class Tracer:
                 ring = collections.deque(maxlen=self._ring_events)
                 self._rings[tid] = ring
                 self._thread_names[tid] = threading.current_thread().name
-            ring.append((ph, ts_us, name, cat, args))
+            elif len(ring) == ring.maxlen:
+                self._evicted[tid] = self._evicted.get(tid, 0) + 1
+            ring.append((ph, ts_us, name, cat, args, dur_us))
         return now
 
     def instant(self, name: str, cat: str = "host", **args: Any) -> None:
         """One point-in-time event (ph 'i') — lifecycle transitions
         (submit / shed / fence-release) that have no duration."""
         self._emit("i", name, cat, args or None)
+
+    def complete(self, name: str, cat: str, seconds: float,
+                 **args: Any) -> None:
+        """One FINISHED interval as one event (ph 'X'): it ended now and
+        took ``seconds``, so it began at this tracer's own clock minus
+        ``seconds``.  For a listener that learns a duration only at its
+        end (jax.monitoring's compile phases): the interval lands where it
+        happened, under whatever span is open, and no wall clock stamps
+        it (rule A205)."""
+        self._emit("X", name, cat, args or None, float(seconds))
 
     def begin(self, name: str, cat: str = "host", **args: Any) -> None:
         self._emit("B", name, cat, args or None)
@@ -263,10 +286,21 @@ class Tracer:
                 ctx.__exit__(None, None, None)
             times[1] = self._emit("E", name, cat, None)
 
+    def evicted(self, tid: Optional[int] = None) -> int:
+        """Events the ring of thread ``tid`` (default: the caller's) has
+        dropped since it was made or :meth:`reset`: 0 says the ring still
+        holds everything the thread emitted, which is what a reader of a
+        whole phase (set-up) must know before it sums what it finds."""
+        if tid is None:
+            tid = threading.get_ident()
+        with self._lock:
+            return self._evicted.get(tid, 0)
+
     def reset(self) -> None:
         with self._lock:
             self._rings.clear()
             self._thread_names.clear()
+            self._evicted.clear()
 
     # -- export ----------------------------------------------------------
     def _snapshot(self):
@@ -281,7 +315,7 @@ class Tracer:
         rings, names = self._snapshot()
         evs: List[Dict[str, Any]] = []
         for tid, ring in rings.items():
-            for ph, ts_us, name, cat, args in ring:
+            for ph, ts_us, name, cat, args, dur_us in ring:
                 ev: Dict[str, Any] = {
                     "ph": ph,
                     "ts": round(ts_us, 3),
@@ -290,6 +324,8 @@ class Tracer:
                     "name": name,
                     "cat": cat,
                 }
+                if dur_us is not None:
+                    ev["dur"] = round(dur_us, 3)
                 if args:
                     ev["args"] = dict(args)
                 evs.append(ev)
@@ -384,4 +420,5 @@ class Tracer:
 tracer = Tracer()
 span = tracer.span
 instant = tracer.instant
+complete = tracer.complete
 flight_dump = tracer.flight_dump

@@ -18,6 +18,7 @@ from typing import Dict, Iterator, Optional, Tuple
 import jax
 import numpy as np
 
+from paddle_tpu import obs as _obs
 from paddle_tpu.core.compiler import CompiledNetwork, NetState, Params
 from paddle_tpu.core.topology import Topology
 
@@ -343,17 +344,23 @@ class DetachedParameters:
 
 def create(cost_or_topology, seed: int = 0, dtype=None) -> Parameters:
     """paddle.parameters.create(cost) equivalent."""
-    from paddle_tpu.core.topology import LayerOutput
-
-    if isinstance(cost_or_topology, Topology):
-        topo = cost_or_topology
-    else:
-        topo = Topology(cost_or_topology)
-    network = CompiledNetwork(topo, dtype=dtype) if dtype else CompiledNetwork(topo)
-    return create_from_network(network, seed)
+    # obs: the start-up layer's span over the graph's compile and the
+    # initial values (one eager program per distinct initializer shape)
+    with _obs.span("parameters_create", cat="setup"):
+        if isinstance(cost_or_topology, Topology):
+            topo = cost_or_topology
+        else:
+            topo = Topology(cost_or_topology)
+        network = CompiledNetwork(topo, dtype=dtype) if dtype else CompiledNetwork(topo)
+        return _init_values(network, seed)
 
 
 def create_from_network(network: CompiledNetwork, seed: int = 0) -> Parameters:
+    with _obs.span("parameters_create", cat="setup"):
+        return _init_values(network, seed)
+
+
+def _init_values(network: CompiledNetwork, seed: int) -> Parameters:
     rng = jax.random.PRNGKey(seed)
     params, state = network.init(rng)
     return Parameters(network, params, state)

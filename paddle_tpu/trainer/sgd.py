@@ -157,6 +157,19 @@ class SGD:
         seed: int = 0,
         evaluators: Optional[Sequence] = None,
     ):
+        # obs: the start-up layer's span over everything a trainer builds
+        # before its first batch; children compile_network (where this
+        # builds one), make_train_step, make_eval_step, optimizer_init
+        with _obs.span("trainer_build", cat="setup"):
+            self._build(
+                cost, parameters, update_equation, extra_layers, mesh, seed,
+                evaluators,
+            )
+
+    def _build(
+        self, cost, parameters, update_equation, extra_layers, mesh, seed,
+        evaluators,
+    ) -> None:
         self.evaluators = list(evaluators or [])
         self._seed = seed  # also keys the pass-cache replay shuffle
         if isinstance(cost, Topology) and not extra_layers and not self.evaluators:
@@ -179,9 +192,7 @@ class SGD:
             # topology-free bag (DetachedParameters); build real params
             # for THIS topology and merge the values in by name
             detached = parameters
-            parameters = create_from_network(
-                CompiledNetwork(self.topology), seed
-            )
+            parameters = create_from_network(self._compile_network(), seed)
             detached.merge_into(parameters)
         # Structural comparison (serialize covers types/sizes/attrs) — name
         # tuples alone would wrongly reuse a different network whose layers
@@ -195,7 +206,7 @@ class SGD:
             self.network = parameters.network
             self.parameters = parameters
         else:
-            self.network = CompiledNetwork(self.topology)
+            self.network = self._compile_network()
             if parameters is not None:
                 # Same cost graph extended with evaluators/extra layers is
                 # fine (the extras are param-free); parameters built for a
@@ -244,16 +255,19 @@ class SGD:
             self.parameters.params = apply_prune_masks(
                 self.parameters.params, self._prune_masks
             )
-        self._train_step = make_train_step(
-            self.network, self.optimizer, self.mesh, self._metrics_fn,
-            infer_param_shardings=self._model_sharded,
-            prune_masks=self._prune_masks,
-        )
-        self._eval_step = make_eval_step(
-            self.network, self.mesh, self._metrics_fn,
-            infer_param_shardings=self._model_sharded,
-        )
-        self._opt_state = self.optimizer.init(self.parameters.params)
+        with _obs.span("make_train_step", cat="setup"):
+            self._train_step = make_train_step(
+                self.network, self.optimizer, self.mesh, self._metrics_fn,
+                infer_param_shardings=self._model_sharded,
+                prune_masks=self._prune_masks,
+            )
+        with _obs.span("make_eval_step", cat="setup"):
+            self._eval_step = make_eval_step(
+                self.network, self.mesh, self._metrics_fn,
+                infer_param_shardings=self._model_sharded,
+            )
+        with _obs.span("optimizer_init", cat="setup"):
+            self._opt_state = self.optimizer.init(self.parameters.params)
         self._rng = jax.random.PRNGKey(seed + 1)
         self._step_count = 0
         # lengths (ms) of the last steps: the slow-step record's yardstick
@@ -279,6 +293,10 @@ class SGD:
         self._width_resolved = not self.network.has_dynamic_widths
 
     # ------------------------------------------------------------------
+    def _compile_network(self) -> CompiledNetwork:
+        with _obs.span("compile_network", cat="setup"):
+            return CompiledNetwork(self.topology)
+
     def _build_metrics_fn(self):
         default = default_metrics_fn(self.topology)
         if not self.evaluators:
@@ -426,7 +444,32 @@ class SGD:
         reader the resumed trajectory matches an uninterrupted run
         bit-for-bit.  (A ``cache_pass_in_mem`` run resumes from the
         checkpoint but streams its remaining passes — the interrupted
-        process's device-resident capture cannot be reconstructed.)"""
+        process's device-resident capture cannot be reconstructed.)
+
+        obs: one span ``train`` (cat ``trainer``, ``passes``) around the
+        whole call, over a child ``train_prepare`` (cat ``setup``) from
+        entry to the first iteration of the first pass (the feeder, the
+        recovery plane, the pass cache, the reader and its prefetch
+        thread), then the ``step`` spans of the loop."""
+        with _obs.span(
+            "train", cat="trainer", passes=num_passes
+        ), contextlib.ExitStack() as prepare:
+            prepare.enter_context(_obs.span("train_prepare", cat="setup"))
+            self._train(
+                prepare.close, reader, num_passes, event_handler, feeding,
+                save_dir, saving_period, saving_period_by_batches, start_pass,
+                show_parameter_stats_period, async_load_data, checkpoint_dir,
+                checkpoint_period_batches, resume,
+            )
+
+    def _train(
+        self, prepared: Callable[[], None], reader, num_passes, event_handler,
+        feeding, save_dir, saving_period, saving_period_by_batches,
+        start_pass, show_parameter_stats_period, async_load_data,
+        checkpoint_dir, checkpoint_period_batches, resume,
+    ) -> None:
+        """``train``'s body; ``prepared()`` ends the ``train_prepare`` span
+        (idempotent: called before each pass's loop, it acts once)."""
         if event_handler is None:
             event_handler = lambda e: None
         import itertools
@@ -815,6 +858,7 @@ class SGD:
             replay = deque()
             batch_id = skip - 1
             in_flight: Optional[_IssuedStep] = None  # dispatched, unsettled
+            prepared()
             while True:
                 bid = replay[0][1] if replay else batch_id + 1
                 with _step_span(pass_id, bid, self._step_ms) as phase:

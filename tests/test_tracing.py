@@ -774,10 +774,13 @@ def test_step_span_partitions_every_iteration(private_tracer, tmp_path, async_lo
     by_thread = _spans_by_thread(private_tracer)
     loop = next(s for s in by_thread.values() if any(x[0] == "step" for x in s))
     steps = [i for i, s in enumerate(loop) if s[0] == "step"]
-    # one `step` per iteration, top level, back to back in batch order; the
-    # last is the iteration that found the pass exhausted
+    # one `step` per iteration, each a child of the call's one `train` span
+    # (PR 38), back to back in batch order; the last is the iteration that
+    # found the pass exhausted
     assert [loop[i][3]["b"] for i in steps] == list(range(_N_BATCHES + 1))
-    assert all(loop[i][4] == 0 and loop[i][3]["p"] == 0 for i in steps)
+    (train,) = [i for i, s in enumerate(loop) if s[0] == "train"]
+    assert loop[train][4] == 0 and loop[train][3] == {"passes": 1}
+    assert all(loop[i][5] == train and loop[i][3]["p"] == 0 for i in steps)
     for i in steps:
         name, t0, t1, args, _, _ = loop[i]
         kids = [s for s in loop if s[5] == i]

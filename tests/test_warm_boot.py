@@ -8,10 +8,13 @@ sentinel) is compiled anew and never served another program's executable.
 One fixture boots a child script in fresh processes: all kinds against an
 empty cache directory, then all kinds again against the directory the first
 boot filled; and, for each change, the changed program once against that
-filled directory and once against an empty one of its own.  The child counts
-backend compiles and persistent-cache hits with ``jax.monitoring`` listeners
-(a backend-compile event fires for a hit too, so what was really compiled is
-the difference).
+filled directory and once against an empty one of its own.  The child reads
+the counters of the program's own ``jax.monitoring`` listener
+(``utils/compile_cache.py``: ``jit/compile``, ``jit/cache_hit``,
+``jit/cache_miss`` in ``global_stats``; a backend-compile event fires for a
+hit too, so what was really compiled is the difference), which makes every
+case here a test of that listener: ``cache`` reads miss on the empty
+directory and hit on the filled one.
 """
 
 import json
@@ -37,7 +40,8 @@ CHANGED_KIND = "train:demo_mnist_mlp"
 CHILD = r'''
 """One boot: builds every kind of program the system dispatches, at toy
 widths, and prints one JSON object {kind: {"backend_compiles", "cache_hits",
-"value"}} as its last line.  argv: <repo> <tmp dir> [--only KIND] [--change C]"""
+"cache_misses", "value"}} as its last line.
+argv: <repo> <tmp dir> [--only KIND] [--change C]"""
 import json
 import os
 import sys
@@ -53,25 +57,10 @@ import numpy as np
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
-COUNTS = {"backend_compiles": 0, "cache_hits": 0}
-
-
-def _duration(event, secs, **_):
-    if event == "/jax/core/compile/backend_compile_duration":
-        COUNTS["backend_compiles"] += 1
-
-
-def _event(event, **_):
-    if event == "/jax/compilation_cache/cache_hits":
-        COUNTS["cache_hits"] += 1
-
-
-jax.monitoring.register_event_duration_secs_listener(_duration)
-jax.monitoring.register_event_listener(_event)
-
 import paddle_tpu as paddle
 from paddle_tpu.core.topology import reset_auto_names
 from paddle_tpu.utils import flags
+from paddle_tpu.utils.timers import global_stats
 from paddle_tpu.v1_compat import make_optimizer, parse_config
 
 paddle.init(compute_dtype="bfloat16" if CHANGE == "bfloat16" else "float32", seed=0)
@@ -89,12 +78,20 @@ def exact(x):
     return [a.dtype.str, list(a.shape), a.tobytes().hex()]
 
 
+def counts():
+    """What the program's own listener (utils/compile_cache.py) has counted:
+    a backend-compile event fires for a load from the cache too."""
+    return {"backend_compiles": global_stats.count("jit/compile"),
+            "cache_hits": global_stats.count("jit/cache_hit"),
+            "cache_misses": global_stats.count("jit/cache_miss")}
+
+
 def kind(name):
     def wrap(fn):
         if ONLY in (None, name):
-            before = dict(COUNTS)
+            before = counts()
             value = fn()
-            RESULTS[name] = {k: COUNTS[k] - before[k] for k in COUNTS}
+            RESULTS[name] = {k: n - before[k] for k, n in counts().items()}
             RESULTS[name]["value"] = value
         return fn
     return wrap
@@ -381,6 +378,8 @@ def test_a_second_process_loads_the_program_and_compiles_nothing(boots, kind):
     cold, warm = boots["cold"][kind], boots["warm"][kind]
     assert _compiled(cold) >= 1, cold
     assert _compiled(warm) == 0 and warm["cache_hits"] >= 1, warm
+    # every compile asked the cache, and was counted as the one or the other
+    assert cold["cache_misses"] == _compiled(cold) and warm["cache_misses"] == 0
     assert warm["value"] == cold["value"]
 
 
