@@ -1012,9 +1012,17 @@ def moe_topk(
     computed in passes over at most `layers.moe.held_rows_bound` sorted
     (token, choice) rows at a time: twice the even share of the held
     experts, from the shapes; rows beyond it take further passes and none
-    is left out.  Counters ride the aux outputs ``<name>@rows_held``,
-    ``<name>@rows_over_bound`` (the passes beyond the first) and
-    ``<name>@rows_dropped`` (0 by construction)."""
+    is left out.  A pass's two grouped products (rows sorted by expert times
+    each expert's matrix) and their gradients run as the Pallas kernels of
+    ``ops/grouped_product.py`` on the TPU backend, where their 128-row tile
+    divides the pass (from 512 rows on it does) and the program is one
+    device's; everywhere else as ``jax.lax.ragged_dot``, whose TPU kernel is
+    tiled for far more rows an expert than one holds here (layers/moe.py has
+    the sweep).  No flag chooses: ``moe_grouped_kernel_layers`` /
+    ``moe_grouped_xla_layers`` count the layers traced on each.  Counters
+    ride the aux outputs ``<name>@rows_held``, ``<name>@rows_over_bound``
+    (the passes beyond the first) and ``<name>@rows_dropped`` (0 by
+    construction)."""
     if score_fn not in ("sigmoid", "softmax"):
         raise ValueError(f"moe_topk: no score function {score_fn!r}")
     lo, hi = experts_held or (0, num_experts)

@@ -215,14 +215,98 @@ def held_rows_bound(n, k, held, num_experts):
     return min(pairs, -(-want // tile) * tile)
 
 
-def _experts(f_act, xs, w1, w2, sizes, live):
+# -- the grouped product: rows sorted by group times each group's matrix ------
+#
+# One contract, two kernels.  `dot(xs [rows, K], w [groups, K, N], sizes
+# [groups] int32) -> [rows, N]` in xs' type with float32 sums; rows past the
+# last group are UNDEFINED (whatever the buffer held, NaN included) and so are
+# their gradients, which is why `_experts` cuts them out on both sides.
+#
+# `_xla_dot`: `jax.lax.ragged_dot`, which XLA:TPU rewrites into a kernel of its
+# own (`ragged-dot-none`, tiles of 512 x 128 x 128 whatever the rows a group
+# holds).  It takes every shape, type and mesh, and is the path of every
+# backend but the TPU.
+# `grouped_product.grouped_dot`: the Pallas kernels of `ops/grouped_product.py` (the product;
+# the row gradient, the same kernel on the matrices read transposed; the
+# matrices' gradient), whose grid follows the row tiles that hold rows: an
+# empty group costs nothing, a nearly empty layer a handful of tile visits.
+#
+# Which of the two a layer takes is chosen in `_grouped_dot`, at trace time,
+# from what the code can observe, and counted there.
+#
+# The sweep that chose (scripts/grouped_product_sweep.py; my chip runs, PR 39:
+# `chiprun_out/p39_sweep4.json`, the own kernels' `p39_sweep5.json`; one v5e
+# chip, bfloat16, a pass of 3,072 rows over 8 held experts, the cell's two
+# products: w1 = [3072, 2688] x [8, 2688, 1856], w2 = [3072, 1856] x [8, 1856,
+# 2688]).  ms of the kernel alone, from the device's profile, by routing: even
+# (192 rows an expert) / full (384) / starved (0-16).  `megablox` is the
+# grouped product jax ships (`jax.experimental.pallas.ops.tpu.megablox`), its
+# tiles (rows x K x N) of the product at hand, K for the whole dimension:
+#
+#                    XLA's            megablox         megablox         megablox         megablox         megablox         ops/grouped_
+#                    ragged-dot       128 x 128 x 128  512 x 512 x 512* 256 x 512 x 512  256 x K x 1024*  128 x K x 1024*  product.py
+#   w1 product       1.00/1.20/0.60   1.13/1.70/0.42   0.42/0.50/0.25   0.33/0.45/0.17   0.20/0.26/0.10   0.14/0.20/0.06   0.18/0.23/0.09
+#   w1 row gradient  1.18/1.42/0.71   1.35/2.02/0.51   0.42/0.51/0.25   0.35/0.46/0.17   0.19/0.26/0.10   0.13/0.20/0.05   0.19/0.25/0.09
+#   w1 matrices'     1.61/2.13/1.15   2.18/3.28/0.90   0.51/0.60/0.34   0.34/0.43/0.21   0.31/0.39/0.20   0.20/0.27/0.11   0.22/0.28/0.14
+#   w2 product       1.18/1.42/0.71   1.52/2.28/0.58   0.39/0.47/0.24   0.32/0.43/0.16   0.21/0.28/0.11   0.18/0.25/0.07   0.18/0.24/0.09
+#   w2 row gradient  1.00/1.20/0.60   1.56/2.34/0.59   0.38/0.46/0.23   0.33/0.44/0.17   0.19/0.25/0.09   0.16/0.22/0.07   0.17/0.23/0.09
+#   w2 matrices'     1.38/1.71/1.06   2.10/3.16/0.90   0.48/0.57/0.33   0.31/0.40/0.20   0.29/0.37/0.19   0.22/0.29/0.15   0.22/0.29/0.14
+#   (* the matrices' gradient at 512 x 512 x 512 as is, else 512 x 512 x 1024 for the products; 256 x 512 x 1024 and
+#    256 x 1024 x 1024; 128 x K x 512 for w1, whose 128 x K x 1024 is over the 16 MiB of VMEM a kernel gets unasked)
+#
+# What it says.  (a) The row tile: 128, in every routing; 256 or 512 rows
+# multiply the padding of every group that does not end on a tile, 64 gains
+# nothing.  (b) The whole K in one block: the block of a group's matrix then
+# stays in VMEM over the row tiles the group spans and is read once, and the
+# kernel runs at what the product must read (108 MB in the even routing: 0.13
+# ms at 819 GB/s); with K cut every visit reads its [K, tn] column again.
+# (c) N as wide as the block's bytes allow (`ops/grouped_product._BLOCK_BYTES`:
+# 12 MiB, which holds a whole matrix of the cell; 6 and 3 MiB cost 0.01-0.04
+# ms a call).  (d) The shipped kernels' stock tiling loses to XLA's kernel;
+# tiled for the shape they win by 5 to 8 times in the even routing, and by
+# more where a layer is starved.  (e) The program's own kernels are the same
+# layout and as fast in the step; the shipped ones, wired in first, cost the
+# cell's warm boot 3.8 s (they work out their tiles' metadata with `jnp.repeat`,
+# `histogram` and `roll` inside each jitted kernel, traced and lowered a kernel),
+# the own ones 1 s (PERF.md section 6, PR 39).  An operand whose last
+# dimension is no multiple of 128 (the 1,856 of w1) is copied into the
+# row-major tiling before either path's kernel (0.14 ms alone; in the step the
+# cast of the float32 master writes it).
+
+
+def _xla_dot(xs, w, sizes):
+    return jax.lax.ragged_dot(xs, w, sizes, preferred_element_type=xs.dtype)
+
+
+def _grouped_dot(rows, d, h, dtypes, mesh):
+    """-> (dot, why): the grouped product a layer takes for passes of `rows`
+    rows between widths d and h and operands of `dtypes`, and where that is
+    `_xla_dot`, why.  The kernels where the backend is the TPU, their row
+    tile divides the pass (from 512 rows on `held_rows_bound` gives whole
+    tiles; the few rows of a small layer keep XLA's), the types are ones
+    they take and the program is one device's: XLA partitions no Mosaic
+    kernel, and no job runs this layer on a mesh yet.  Counted:
+    `moe_grouped_kernel_layers` / `moe_grouped_xla_layers`, one a layer traced."""
+    from paddle_tpu.utils.timers import global_stats
+
+    if jax.default_backend() != "tpu":
+        why = f"the backend is {jax.default_backend()!r}, not 'tpu'"
+    elif mesh is not None and mesh.size > 1:
+        why = f"the mesh {dict(mesh.shape)} holds {mesh.size} devices"
+    else:
+        from paddle_tpu.ops import grouped_product  # Pallas is imported where it is used
+
+        why = grouped_product.supported(rows, d, h, dtypes)
+    global_stats.incr("moe_grouped_xla_layers" if why else "moe_grouped_kernel_layers")
+    return (_xla_dot if why else grouped_product.grouped_dot), why
+
+
+def _experts(f_act, xs, w1, w2, sizes, live, dot=_xla_dot):
     """The two grouped products over rows sorted by expert.  Rows past the
     last group are neither computed nor defined: they are cut out on both
     sides of each product, so nothing flows back through them either."""
-    hmid = jax.lax.ragged_dot(xs, w1, sizes, preferred_element_type=xs.dtype)
-    hmid = f_act(jnp.where(live, hmid, 0))
-    ys = jax.lax.ragged_dot(hmid, w2, sizes, preferred_element_type=xs.dtype)
-    return jnp.where(live, ys, 0)
+    hmid = f_act(jnp.where(live, dot(xs, w1, sizes), 0))
+    return jnp.where(live, dot(hmid, w2, sizes), 0)
 
 
 def _further_passes(rows, bound):
@@ -249,12 +333,13 @@ def _passes(k, bound, order, group_sizes):
     return pass_rows, 1 + _further_passes(rows, bound)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _held_experts(f_act, k, bound, tokens, w1, w2, weights, order, group_sizes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _held_experts(f_act, k, bound, dot, tokens, w1, w2, weights, order, group_sizes):
     """sum over a token's held choices e of weights[token, e] E_e(token), [N, D].
 
     `order` lists the (token, choice) pairs sorted by expert, the held ones
-    first; `group_sizes` counts each held expert's.  The rows are worked
+    first; `group_sizes` counts each held expert's; `dot` is the grouped
+    product (`_grouped_dot`).  The rows are worked
     through in passes of `bound`: the first always, each further one only
     while rows are left, so the arrays are `bound` rows whatever the
     routing and the cost follows the rows that exist.  A pass gathers its
@@ -262,27 +347,42 @@ def _held_experts(f_act, k, bound, tokens, w1, w2, weights, order, group_sizes):
     token (a scatter-add of `bound` rows; float32 sums).
 
     Nothing but the arguments is kept for the way back: each pass is
-    computed again there, one at a time."""
-    return _held_experts_fwd(f_act, k, bound, tokens, w1, w2, weights, order, group_sizes)[0]
+    computed again there, one at a time.
+
+    The way forward and the way back are jitted functions of their own, so
+    the expert layers of one shape share ONE trace and ONE lowering of each
+    (XLA inlines them under each layer's scope): a model's start pays the
+    Python of one layer's passes and kernels, not of every layer's (PERF.md
+    section 6, PR 39)."""
+    return _forward(f_act, k, bound, dot, tokens, w1, w2, weights, order, group_sizes)
 
 
-def _held_experts_fwd(f_act, k, bound, tokens, w1, w2, weights, order, group_sizes):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _forward(f_act, k, bound, dot, tokens, w1, w2, weights, order, group_sizes):
     pass_rows, passes = _passes(k, bound, order, group_sizes)
     wflat = weights.reshape(-1)
 
     def add_pass(b, out):
         pair, tok, live, sizes = pass_rows(b)
-        ys = _experts(f_act, jnp.where(live, tokens[tok], 0), w1, w2, sizes, live)
+        ys = _experts(f_act, jnp.where(live, tokens[tok], 0), w1, w2, sizes, live, dot)
         return out.at[tok].add(ys.astype(jnp.float32) * wflat[pair][:, None])
 
     out = add_pass(0, jnp.zeros((tokens.shape[0], w2.shape[-1]), jnp.float32))
     if bound < order.shape[0]:
         out = jax.lax.fori_loop(1, passes, add_pass, out)
-    return out.astype(tokens.dtype), (tokens, w1, w2, weights, order, group_sizes)
+    return out.astype(tokens.dtype)
 
 
-def _held_experts_bwd(f_act, k, bound, res, g):
-    tokens, w1, w2, weights, order, group_sizes = res
+def _held_experts_fwd(f_act, k, bound, dot, *args):
+    return _forward(f_act, k, bound, dot, *args), args
+
+
+def _held_experts_bwd(f_act, k, bound, dot, res, g):
+    return (*_backward(f_act, k, bound, dot, *res, g), None, None)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _backward(f_act, k, bound, dot, tokens, w1, w2, weights, order, group_sizes, g):
     # as `jax.checkpoint` does for what it computes again: the way back reads
     # its own copies of the arguments, so none made for the way forward (the
     # weights as the loop holds them) has to live until here
@@ -292,7 +392,7 @@ def _held_experts_bwd(f_act, k, bound, res, g):
 
     def pass_grads(b, g_tokens, g_wflat):
         pair, tok, live, sizes = pass_rows(b)
-        ys, back = jax.vjp(lambda xs, w1, w2: _experts(f_act, xs, w1, w2, sizes, live),
+        ys, back = jax.vjp(lambda xs, w1, w2: _experts(f_act, xs, w1, w2, sizes, live, dot),
                            jnp.where(live, tokens[tok], 0), w1, w2)
         g_rows = g[tok].astype(jnp.float32)
         g_xs, g_w1, g_w2 = back((g_rows * wflat[pair][:, None]).astype(ys.dtype))
@@ -309,22 +409,24 @@ def _held_experts_bwd(f_act, k, bound, res, g):
         grads = jax.lax.fori_loop(1, passes, further, grads)
     g_tokens, g_wflat, g_w1, g_w2 = grads
     return (g_tokens.astype(tokens.dtype), g_w1, g_w2,
-            g_wflat.reshape(weights.shape).astype(weights.dtype), None, None)
+            g_wflat.reshape(weights.shape).astype(weights.dtype))
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
-def _route(tokens, params, k, score_fn, scaling):
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _route(tokens, router, router_bias, k, score_fn, scaling):
     """-> (chosen expert ids [N, k] int32, their weights [N, k] float32).
-    Scores in float32 over all the router's outputs."""
-    logits = jnp.matmul(tokens, params["router"].astype(tokens.dtype),
+    Scores in float32 over all the router's outputs.  Jitted, as the passes
+    are: the layers of one shape share one trace of it."""
+    logits = jnp.matmul(tokens, router.astype(tokens.dtype),
                         preferred_element_type=jnp.float32)
     if score_fn == "sigmoid":
         scores = jax.nn.sigmoid(logits)
     else:
         scores = jax.nn.softmax(logits, axis=-1)
-    _, chosen = jax.lax.top_k(scores + params["router_bias"].astype(jnp.float32), k)
+    _, chosen = jax.lax.top_k(scores + router_bias.astype(jnp.float32), k)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20) * scaling
     return chosen.astype(jnp.int32), weights
@@ -345,8 +447,8 @@ def moe_topk_apply(conf, params, inputs, ctx: ApplyContext):
     valid = _valid_tokens(x)
 
     with jax.named_scope("moe_route"):
-        chosen, weights = _route(tokens, params, k, conf.attr("score_fn", "sigmoid"),
-                                 conf.attr("scaling", 1.0))
+        chosen, weights = _route(tokens, params["router"], params["router_bias"], k,
+                                 conf.attr("score_fn", "sigmoid"), conf.attr("scaling", 1.0))
         here = (chosen >= lo) & (chosen < hi)
         if valid is not None:  # a padded position asks nothing of any expert
             here = here & (valid[:, None] > 0)
@@ -357,8 +459,10 @@ def moe_topk_apply(conf, params, inputs, ctx: ApplyContext):
         rows = jnp.sum(group_sizes)
 
     bound = held_rows_bound(n, k, held, conf.attrs["num_experts"])
+    dot, _ = _grouped_dot(bound, d, conf.attrs["expert_hidden"],
+                          (tokens.dtype, params["w1"].dtype), ctx.mesh)
     with jax.named_scope("moe_experts"):
-        out = _held_experts(f_act, k, bound, tokens, params["w1"], params["w2"],
+        out = _held_experts(f_act, k, bound, dot, tokens, params["w1"], params["w2"],
                             jnp.where(here, weights, 0.0), order, group_sizes)
 
     if "shared_w1" in params:

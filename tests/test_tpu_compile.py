@@ -227,3 +227,55 @@ def test_the_seq2seq_step_compiled_for_a_v5e_copies_its_logits_only_for_a_batch_
         if shape and np.prod([int(d) for d in shape.group(1).split(",")]) == vocab * b * t:
             copied.append(line.strip()[:120])
     assert bool(copied) == (rows == "withheld"), copied
+
+
+def test_the_hybrid_step_compiled_for_a_v5e_takes_the_grouped_kernels_and_lowers_them_once_for_all_layers(v5e, monkeypatch):
+    """`nemotron-train-2k`'s expert layers at the cell's widths (2 rows of
+    2,048 tokens, top 6 of 128 experts, 8 held, 2688 x 1856: passes of 3,072
+    rows) in a training step compiled for one described v5e: every layer
+    takes the Pallas grouped products (`moe_grouped_kernel_layers` n / 0, no
+    `ragged-dot` left in the program), whose blocks fit the chip's VMEM inside
+    the step.  The kernels (product, row gradient, matrices' gradient, of w1
+    and of w2) are jitted functions, so the lowered module holds the same few
+    whatever the number of layers (a lowering costs a warm boot its Python:
+    PERF.md section 6, PR 35 and 39), where the compiled program holds every
+    call site's: a layer's forward 2, recomputed forward 2, row gradients 2,
+    matrices' gradients 2, and the same again in the body of each loop over
+    the passes beyond the first."""
+    from jax.sharding import SingleDeviceSharding
+
+    import paddle_tpu as paddle
+    from paddle_tpu.core.batch import SeqTensor
+    from paddle_tpu.core.compiler import CompiledNetwork
+    from paddle_tpu.core.topology import Topology, reset_auto_names
+    from paddle_tpu.models.hybrid_lm import hybrid_lm_cost
+    from paddle_tpu.trainer.step import make_train_step
+    from paddle_tpu.utils.timers import global_stats
+
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    count = lambda: [global_stats.count(f"moe_grouped_{path}_layers") for path in ("kernel", "xla")]
+
+    def lowered(pattern):
+        reset_auto_names()
+        cost, _ = hybrid_lm_cost(
+            pattern, 256, 2688, mamba_heads=2, mamba_head_dim=8, mamba_groups=1, state_size=8,
+            attn_heads=2, attn_kv_heads=1, attn_head_dim=128, num_experts=128, experts_per_token=6,
+            expert_hidden=1856, shared_hidden=128, experts_held=(0, 8))
+        net = CompiledNetwork(Topology([cost]), compute_dtype=jnp.bfloat16)
+        opt = paddle.optimizer.Adam(learning_rate=1e-4)
+        params, state = jax.eval_shape(net.init, jax.random.PRNGKey(0))
+        ids = SeqTensor(jax.ShapeDtypeStruct((2, 2048), jnp.int32), jax.ShapeDtypeStruct((2,), jnp.int32))
+        args = (params, state, jax.eval_shape(opt.init, params), {"word": ids, "next_word": ids},
+                jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+        placed = jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), args)
+        before = count()
+        low = make_train_step(net, opt).trace(*placed).lower(lowering_platforms=("tpu",))
+        assert [a - b for a, b in zip(count(), before)] == [len(pattern), 0]
+        return low
+
+    one, two = lowered("E"), lowered("EE")
+    kernels = one.as_text().count("tpu_custom_call")
+    assert 6 <= kernels <= 12 and two.as_text().count("tpu_custom_call") == kernels
+    text = two.compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * 2 * 8 and "ragged-dot" not in text

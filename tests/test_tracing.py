@@ -1021,7 +1021,10 @@ def test_attention_gru_core_scope_in_forward_and_backward():
 
 def _hybrid_step_names():
     """The names in the lowered train step of a toy hybrid decoder (pattern
-    MEM*E, bfloat16 compute), and a run of the same network's forward pass."""
+    MEM*E, bfloat16 compute), a run of the same network's forward pass, and
+    the operations' names in the COMPILED step: a jitted function that several
+    layers share is lowered once, its operations named from its own entry on,
+    and XLA puts each call site's scopes before them where it inlines it."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1048,10 +1051,11 @@ def _hybrid_step_names():
     rows = [tuple(list(rng.randint(2, 50, 10)) for _ in range(2)) for _ in range(3)]
     batch = trainer._make_feeder({"word": 0, "next_word": 1})(rows)
     p = trainer.parameters
-    names = _scope_names(trainer._train_step.lower(
-        p.params, p.state, trainer._opt_state, batch, jax.random.PRNGKey(0)))
+    import re
+
+    lowered = trainer._train_step.lower(p.params, p.state, trainer._opt_state, batch, jax.random.PRNGKey(0))
     outs, _ = trainer.network.apply(p.params, batch, state=p.state, train=True)
-    return names, outs
+    return _scope_names(lowered), outs, set(re.findall(r'op_name="([^"]+)"', lowered.compile().as_text()))
 
 
 @pytest.fixture(scope="module")
@@ -1060,7 +1064,7 @@ def hybrid_step():
 
 
 def test_hybrid_decoder_step_has_no_operation_outside_a_scope(hybrid_step):
-    names, _ = hybrid_step
+    names, _, _ = hybrid_step
     for scope in ("mamba2:l0_mamba", "rms_norm:l0_norm", "moe_topk:l1_moe",
                   "multi_head_attention:l3_attn", "cast:l0_mamba", "optimizer:adam/"):
         assert any(scope in n for n in names), scope
@@ -1078,7 +1082,7 @@ def test_hybrid_decoder_inner_scopes_stay_within_their_layer(hybrid_step, inner,
     """Forward and backward: a scope without a colon, so the layer stays the
     operations' innermost `type:name` scope (what `ssd_scan_roofline` and
     `moe_experts_roofline` read beside `ssm_layers_share`, `moe_layers_share`)."""
-    names, _ = hybrid_step
+    names, _, _ = hybrid_step
     mine = [n for n in names if f"/{inner}/" in n]
     assert [n for n in mine if "transpose(" not in n], inner
     # the route's choice has no gradient of its own; the rest run both ways
@@ -1094,7 +1098,7 @@ def test_expert_layer_counters_ride_the_aux_outputs(hybrid_step):
 
     from paddle_tpu.layers.moe import held_rows_bound
 
-    _, outs = hybrid_step
+    _, outs, _ = hybrid_step
     for name in ("l1_moe", "l4_moe"):
         held, over, dropped = (np.asarray(outs[f"{name}@{k}"].data)
                                for k in ("rows_held", "rows_over_bound", "rows_dropped"))
@@ -1112,10 +1116,17 @@ def test_the_held_experts_passes_run_under_the_layers_scopes(hybrid_step):
     """The loop over the passes beyond the first, forward and backward, and
     what runs inside it: `moe_topk:<name>` then `moe_experts`, as the
     operations of the first pass (what `moe_layers_share` and
-    `moe_experts_roofline` read)."""
-    names, _ = hybrid_step
-    loops = [n for n in names if n.endswith("/while") or "/while/body/" in n]
+    `moe_experts_roofline` read).  The passes are two jitted functions that
+    the expert layers share (`_forward`, `_backward`: lowered once, called
+    under each layer's scopes), so the layers' names are read where XLA has
+    inlined them: in the compiled step, every layer's own."""
+    names, _, compiled = hybrid_step
+    for layer in ("l1_moe", "l4_moe"):
+        assert f"jit(step)/jvp(moe_topk:{layer})/moe_experts/jit(_forward)" in names
+        assert f"jit(step)/transpose(jvp(moe_topk:{layer}))/moe_experts/jit(_backward)" in names
+    assert {"while/body/ragged_dot_general", "while/body/transpose(jvp())/ragged_dot_general"} <= names
+    loops = [n for n in compiled if n.endswith("/while") or "/while/body/" in n]
     mine = [n for n in loops if "/moe_experts/" in n]
     assert [n for n in mine if "transpose(" in n] and [n for n in mine if "transpose(" not in n]
     assert all("moe_topk:" in n.split("/moe_experts/")[0] for n in mine)
-    assert [n for n in mine if "ragged_dot" in n]
+    assert {n.split("moe_topk:")[1][:6] for n in mine} == {"l1_moe", "l4_moe"}
