@@ -323,7 +323,7 @@ def recompile_audit(
     # per (slot, axis>=1): the set of extents observed across keys
     extents: Dict[Tuple[str, int], set] = {}
     for key in keys:
-        for name, shape, _dtype in key:
+        for name, shape, *_ in key:
             for axis, ext in enumerate(shape):
                 if axis >= 1:
                     extents.setdefault((name, axis), set()).add(int(ext))

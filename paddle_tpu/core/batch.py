@@ -10,10 +10,25 @@ All layer implementations consume and produce SeqTensors.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+
+
+class RowBound(NamedTuple):
+    """A static bound on the true tokens of a sequence slot: split its
+    ``batch`` rows into ``shards`` equal blocks of consecutive rows (the
+    blocks a data mesh gives each device), and no block holds more than
+    ``rows`` of its T x batch/shards positions inside a sequence.  The feeder
+    sets it (:func:`row_bound`) on the slots a recurrent_group with a hoisted
+    epilogue scans; the group then runs that epilogue on ``rows`` rows a
+    shard instead of all of them (layers/recurrent_group.py
+    ``_HoistRows.kept``)."""
+
+    batch: int
+    shards: int
+    rows: int
 
 
 @jax.tree_util.register_pytree_node_class
@@ -33,7 +48,8 @@ class SeqTensor:
                  ordinary [B, T, ...] sequence.
     """
 
-    def __init__(self, data, lengths=None, sub_lengths=None, sparse_ids=False):
+    def __init__(self, data, lengths=None, sub_lengths=None, sparse_ids=False,
+                 row_bound=None):
         self.data = data
         self.lengths = lengths
         self.sub_lengths = sub_lengths
@@ -42,15 +58,18 @@ class SeqTensor:
         # feeder, consumed by fc/mixed projections via
         # layers.base.is_sparse_ids (exact dispatch, no shape heuristics)
         self.sparse_ids = sparse_ids
+        # a RowBound on the true tokens of a data slot, or None — set by the
+        # feeder, static like sparse_ids, so that two bounds are two shapes
+        self.row_bound = row_bound
 
     # -- pytree protocol ----------------------------------------------------
     def tree_flatten(self):
         children = (self.data, self.lengths, self.sub_lengths)
-        return children, self.sparse_ids
+        return children, (self.sparse_ids, self.row_bound)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children, sparse_ids=bool(aux))
+        return cls(*children, sparse_ids=bool(aux[0]), row_bound=aux[1])
 
     # -- helpers ------------------------------------------------------------
     @property
@@ -152,15 +171,39 @@ def ladder_len(n: int, ladder: Sequence[int] = DEFAULT_LADDER) -> int:
     return ((n + top - 1) // top) * top
 
 
+def row_bound(lengths, t: int, shards: int = 1) -> Optional[RowBound]:
+    """The RowBound of a [B] lengths vector padded to T, over ``shards``
+    blocks of rows (one block where they do not divide B, as the hoisted
+    rows' order has it): the largest block's token count rounded up to a
+    rung, the smallest multiple of ceil(T*B/shards / 16) that covers it,
+    rounded up to 128 rows — at most 16 bounds for one (T, B), as the time
+    ladder bounds T.  None where that leaves no row of a block out."""
+    import numpy as np
+
+    b = len(lengths)
+    n = shards if shards > 1 and b % shards == 0 else 1
+    block = int(t) * b // n
+    if not block:
+        return None
+    count = int(np.minimum(np.asarray(lengths), t).reshape(n, -1).sum(1).max())
+    unit = -(-block // 16)
+    rung = max(-(-count // unit), 1) * unit
+    rows = -(-rung // 128) * 128
+    return RowBound(b, n, rows) if rows < block else None
+
+
 def batch_shape_key(batch: Batch) -> tuple:
     """Hashable shape signature of a feeder batch — exactly the part of the
-    jit cache key the feed controls (slot names, data shapes, dtypes).  Two
-    batches with equal keys dispatch to the same compiled executable."""
+    jit cache key the feed controls (slot names, data shapes, dtypes, and a
+    slot's RowBound where it has one).  Two batches with equal keys dispatch
+    to the same compiled executable."""
     key = []
     for name in sorted(batch):
         t = batch[name]
         data = t.data if hasattr(t, "data") else t
-        key.append((name, tuple(int(d) for d in data.shape), str(data.dtype)))
+        entry = (name, tuple(int(d) for d in data.shape), str(data.dtype))
+        bound = getattr(t, "row_bound", None)
+        key.append(entry if bound is None else entry + (bound,))
     return tuple(key)
 
 
@@ -188,7 +231,7 @@ def canonicalize_batch(
     both S (axis 1) and T (axis 2).  Lengths are untouched — the new
     positions are beyond every sample's valid range, so masks, cost sums and
     the scan early-exit (layers/recurrent_group.py) all treat them as dead
-    padding.  Zero-pad is correct for every slot kind here: the added
+    padding, and a slot's RowBound still holds.  Zero-pad is correct for every slot kind here: the added
     positions are whole masked-out timesteps, not intra-step nnz slots (the
     feeder's sparse-ids sentinel concern)."""
     out: Batch = {}
@@ -211,7 +254,8 @@ def canonicalize_batch(
         else:
             data = _pad_axis(t.data, 1, ladder_len(t.data.shape[1], ladder))
         out[name] = SeqTensor(
-            data, t.lengths, sub_lengths, sparse_ids=t.sparse_ids
+            data, t.lengths, sub_lengths, sparse_ids=t.sparse_ids,
+            row_bound=t.row_bound,
         )
     return out
 

@@ -416,6 +416,16 @@ class CompiledNetwork:
         return params
 
     # ------------------------------------------------------------------
+    def row_bound_slots(self) -> Tuple[str, ...]:
+        """The data slots whose lengths this network's recurrent_groups with
+        a hoisted epilogue scan: a feed that puts a RowBound on them
+        (reader.feeder.DataFeeder) lets those groups run the epilogue on
+        the rows that hold a token alone.  Empty for a network without such
+        a group, whose batches then carry no bound."""
+        from paddle_tpu.layers.recurrent_group import row_bound_slots
+
+        return row_bound_slots(self.topology)
+
     @property
     def has_dynamic_widths(self) -> bool:
         """Any fc / matrix projection stacked on a dynamic-width input

@@ -75,8 +75,12 @@ def cross_entropy_apply(conf, params, inputs, ctx):
     computed as (`<name>@logits_rows`, recurrent_group.HoistedRows): the
     reduction over V then runs on the rows where they lie, the ids folded
     into their order and the per-row cost unfolded to [B, T], and nothing
-    reads (or copies) the [B, T, V] view.  Which of the two a layer traced
-    took is counted: ce_hoisted_rows_layers / ce_batch_major_layers."""
+    reads (or copies) the [B, T, V] view.  Those rows may be the ones that
+    hold a token alone, R a shard, where the feeder bounded the scanned
+    slot's tokens (core.batch.RowBound): a fill row past a shard's count
+    is dropped by the unfold, so its cost, and its gradient, reach no sum.
+    Which of the two a layer traced took is counted:
+    ce_hoisted_rows_layers / ce_batch_major_layers."""
     prob, label = inputs[0], inputs[1]
     ids = _label_ids(label)
     hoisted = ctx.outputs.get(conf.inputs[0] + "@logits_rows")

@@ -32,6 +32,7 @@ from paddle_tpu.core.topology import LayerConf, LayerOutput, Topology, auto_name
 from paddle_tpu.layers.base import ApplyContext, register_layer
 from paddle_tpu.ops import acc_einsum
 from paddle_tpu.parallel.mesh import DATA_AXIS
+from paddle_tpu.utils.timers import global_stats
 
 
 class StaticInput:
@@ -486,18 +487,9 @@ def recurrent_group_apply(conf, params, inputs, ctx: ApplyContext) -> SeqTensor:
     # reference evaluating output frames via SequenceToBatch re-batching.
     # Disabled for nested inputs and sequence-valued memories, whose step
     # outputs are not plain [B, D] rows.
-    # both hoists assume plain [B, D] per-step rows and non-seq carries
     rows = _HoistRows(t_max, b, ctx.mesh)
-    rows_hoistable = not any(sub_scanned) and not any(
-        m.attrs.get("is_seq") for m in memories
-    )
-    epilogue = None
-    frontier = (out_name,)
-    if rows_hoistable:
-        static_seq = {p for (p, is_seq) in static_info if is_seq}
-        epilogue, frontier = _split_epilogue(
-            sub_topo, memories, out_name, static_seq
-        )
+    rows_hoistable = _rows_hoistable(a)
+    epilogue, frontier = _epilogue_of(a)
     static_names = {p for (p, _s) in static_info}
     if epilogue is not None:
         # validate by a ONE-step abstract eval (shapes only) that every
@@ -704,36 +696,57 @@ def recurrent_group_apply(conf, params, inputs, ctx: ApplyContext) -> SeqTensor:
             ctx.new_state[conf.name] = sub_state_out
 
     if epilogue is not None:
-        # run the hoisted suffix once over the whole stacked sequence, time
-        # folded into the batch in _HoistRows' order (rowwise layers only,
-        # so the [T*B] rows are independent)
+        # run the hoisted suffix once over the stacked sequence, time folded
+        # into the batch in _HoistRows' order (rowwise layers only, so the
+        # [T*B] rows are independent) — on the rows that hold a token alone
+        # where the feed bounds their number (_kept_positions), else on all
+        pos = _kept_positions(ctx, rows, valid, lengths)
+        kept = rows.t * rows.b if pos is None else pos.size
+        global_stats.incr(
+            "hoisted_rows_full_layers" if pos is None
+            else "hoisted_rows_compacted_layers"
+        )
+        global_stats.observe(
+            "hoisted_rows_kept_share", 100.0 * kept / (rows.t * rows.b)
+        )
+
+        def keep(r):
+            return r if pos is None else rows.take(r, pos)
+
+        def put(r):
+            return r if pos is None else rows.put(r, pos)
+
         preset = {}
         for n, st in zip(frontier_scan, ys_stacked):
-            preset[n] = SeqTensor(rows.fold(st.data))
+            preset[n] = SeqTensor(keep(rows.fold(st.data)))
         for n in frontier:
             if n in preset:
                 continue
             if _pro_producer(n) in prologue:
-                preset[n] = pro_outs[n]  # already rows, in the same order
+                # already rows, in the same order
+                preset[n] = SeqTensor(keep(pro_outs[n].data))
             elif n in scan_names:
                 # the scan input itself: already held time-major in xs
                 preset[n] = SeqTensor(
-                    rows.fold(xs[scan_names.index(n)].data)
+                    keep(rows.fold(xs[scan_names.index(n)].data))
                 )
             else:  # step-invariant static: broadcast per step, don't stack
-                preset[n] = SeqTensor(rows.tile(static_batch[n].data))
+                preset[n] = SeqTensor(keep(rows.tile(static_batch[n].data)))
         epi_outs, _ = subnet.apply(
             params, {}, state=sub_state0, train=ctx.train, rng=None,
             only=epilogue, preset=preset,
         )
-        ys = rows.unfold_batch_major(epi_outs[out_name].data, reverse)
+        # the [B, T, ...] views: scattered back from the kept rows, which
+        # costs a write of the whole array — and nothing in a training step
+        # reads them, so XLA removes them there
+        ys = rows.unfold_batch_major(put(epi_outs[out_name].data), reverse)
         lg = epi_outs.get(out_name + "@logits")
         if lg is not None:
             # expose the hoisted softmax's pre-activation at the GROUP level
             # so a downstream cross_entropy fuses into log-softmax CE and
             # the [B, T, vocab] probabilities dead-code-eliminate entirely
             ctx.outputs[conf.name + "@logits"] = SeqTensor(
-                rows.unfold_batch_major(lg.data, reverse), lengths
+                rows.unfold_batch_major(put(lg.data), reverse), lengths
             )
             # ... and the same values as the ROWS they were computed as, for
             # a consumer that reduces them over the vocabulary (softmax-CE,
@@ -744,7 +757,7 @@ def recurrent_group_apply(conf, params, inputs, ctx: ApplyContext) -> SeqTensor:
             # [T*B, V] output out with the rows along the lanes, which no
             # [B, T] split of the rows is a bitcast of (PERF.md, PR 37).
             ctx.outputs[conf.name + "@logits_rows"] = HoistedRows(
-                lg.data, rows, reverse
+                lg.data, rows, reverse, pos
             )
     else:
         ys = ys_stacked[0]
@@ -1004,6 +1017,64 @@ def _split_epilogue(sub_topo, memories, out_name, static_seq):
     return epilogue, tuple(frontier)
 
 
+def _rows_hoistable(a) -> bool:
+    """Both hoists assume plain [B, D] per-step rows and non-seq carries:
+    not for nested inputs or sequence-valued memories."""
+    return not any(a.get("_sub_scanned", ())) and not any(
+        m.attrs.get("is_seq") for m in a["_memories"]
+    )
+
+
+def _epilogue_of(a):
+    """_split_epilogue of a group's attrs: (None, (output,)) where the rows
+    do not hoist at all."""
+    if not _rows_hoistable(a):
+        return None, (a["_output"],)
+    static_seq = {p for (p, is_seq) in a["_static_placeholders"] if is_seq}
+    return _split_epilogue(
+        a["_sub_topology"], a["_memories"], a["_output"], static_seq
+    )
+
+
+def row_bound_slots(topology) -> tuple:
+    """The data slots whose lengths the topology's recurrent_groups with a
+    hoisted epilogue scan: from a group's first scanned input back through
+    rowwise layers, which keep their input's lengths, to a data layer.  The
+    feed bounds those slots' true tokens (core.batch.RowBound) and such a
+    group finds the bound by the lengths it scans (_kept_positions), so a
+    slot named here that the group does not in fact scan costs shapes, not
+    correctness."""
+    layers = topology.layers
+    slots = []
+    for conf in layers.values():
+        if conf.type != "recurrent_group" or _epilogue_of(conf.attrs)[0] is None:
+            continue
+        name = conf.inputs[0]
+        while layers[name].type in _HOIST_ROWWISE:
+            name = layers[name].inputs[0]
+        if layers[name].type == "data" and name not in slots:
+            slots.append(name)
+    return tuple(slots)
+
+
+def _kept_positions(ctx, rows, valid, lengths):
+    """Where each shard's rows that hold a token lie (_HoistRows.kept), or
+    None (run on every row).  The bound comes from the feed: the RowBound of
+    a data slot whose lengths ARE the ones this group scans (the same traced
+    array, so the bound counts exactly the rows `valid` marks), made for
+    this batch size and this row order's shards, and leaving rows out."""
+    for v in ctx.outputs.values():
+        bound = getattr(v, "row_bound", None)
+        if (
+            bound is not None
+            and v.lengths is lengths
+            and (bound.batch, bound.shards) == (rows.b, rows.n)
+            and bound.rows < rows.t * rows.b // rows.n
+        ):
+            return rows.kept(valid, bound.rows)
+    return None
+
+
 def _seq_memory_widths(
     conf, subnet, params, memories, scan_names, static_batch, xs,
     ctx, sub_state0, b,
@@ -1095,7 +1166,13 @@ class _HoistRows:
     (row (s*T + t)*B/n + b'): a chip's rows are one contiguous block, the
     merged axis is sharded as B was, and each chip runs the one-chip program
     on its own B/n rows.  n = 1 (no mesh, a data axis of one, or a B that n
-    does not divide) is exactly the time-major reshape."""
+    does not divide) is exactly the time-major reshape.
+
+    Where the feed bounds the true tokens (core.batch.RowBound, which the
+    trainer's feeder puts on the slot the group scans), the epilogue runs
+    on the rows that hold a token alone, R a shard in the same order
+    (``kept`` / ``take`` / ``put`` below); the fill rows past a shard's
+    count reach no sum."""
 
     def __init__(self, t: int, b: int, mesh) -> None:
         n = 1 if mesh is None else mesh.shape.get(DATA_AXIS, 1)
@@ -1145,6 +1222,57 @@ class _HoistRows:
             r = jnp.swapaxes(r.reshape((n, t, b // n) + rest), 0, 1)
         return r.reshape((t, b) + rest)
 
+    # -- the rows that hold a token ---------------------------------------
+    # A feed that bounds them (core.batch.RowBound: at most R of each
+    # shard's T*B/n rows) lets the epilogue run on n*R rows, shard-major as
+    # above: shard s's kept rows are rows s*R ... s*R+R-1, gathered from its
+    # own block only, so no row crosses a shard.  `pos` [n, R] holds each
+    # kept row's position inside its shard's block; a shard with fewer than
+    # R tokens fills its tail with T*B/n, one past the block.  A fill row
+    # reads (clipped) the block's last row, a finite value, and is dropped
+    # on the way back: the cost of a row put back is 0 there, so a fill
+    # row's every cotangent is 0 and it adds nothing to any sum.
+
+    def kept(self, valid: jnp.ndarray, bound: int) -> jnp.ndarray:
+        """[T, B] validity in the stacked order -> pos [n, bound] int32: a
+        valid row's place among its shard's kept rows is the count of valid
+        rows before it (one cumsum and one scatter a shard)."""
+        block = self.t * self.b // self.n
+        m = self.fold(valid).reshape(self.n, block)
+        rows = jnp.arange(block, dtype=jnp.int32)
+
+        def shard(v):
+            slot = jnp.where(v, jnp.cumsum(v, dtype=jnp.int32) - 1, bound)
+            return jnp.full((bound,), block, jnp.int32).at[slot].set(
+                rows, mode="drop"
+            )
+
+        return jax.vmap(shard)(m)
+
+    def take(self, r: jnp.ndarray, pos: jnp.ndarray) -> jnp.ndarray:
+        """Rows [T*B, ...] -> the kept rows [n*R, ...]."""
+        n, k = pos.shape
+        rest = r.shape[1:]
+        idx = pos.reshape((n, k) + (1,) * len(rest))
+        out = jnp.take_along_axis(
+            r.reshape((n, -1) + rest), idx, axis=1, mode="clip"
+        )
+        return out.reshape((n * k,) + rest)
+
+    def put(self, r: jnp.ndarray, pos: jnp.ndarray) -> jnp.ndarray:
+        """Kept rows [n*R, ...] -> rows [T*B, ...] (take's inverse on the
+        rows that hold a token), zero on every other row."""
+        n, k = pos.shape
+        rest = r.shape[1:]
+        block = self.t * self.b // n
+
+        def shard(p, v):
+            z = jnp.zeros((block,) + rest, v.dtype)
+            return z.at[p].set(v, mode="drop")
+
+        out = jax.vmap(shard)(pos, r.reshape((n, k) + rest))
+        return out.reshape((n * block,) + rest)
+
     def unfold_batch_major(self, r: jnp.ndarray, reverse: bool) -> jnp.ndarray:
         """Rows [T*B, ...] -> the group's [B, T, ...] output, time put back
         in order for a reverse group.  Under a mesh this is ONE transpose
@@ -1184,29 +1312,39 @@ class _HoistRows:
 
 @jax.tree_util.register_pytree_node_class
 class HoistedRows:
-    """A hoisted epilogue's output as the [T*B, ...] rows it was computed as,
-    with their order (``<group>@logits_rows`` beside ``<group>@logits``).
+    """A hoisted epilogue's output as the rows it was computed as, with
+    their order (``<group>@logits_rows`` beside ``<group>@logits``): all
+    [T*B, ...] rows, or, where the feed bounded the true tokens
+    (core.batch.RowBound, set by the trainer's feeder on the slot the group
+    scans), the [n*R, ...] rows that hold a token, R a shard, whose
+    positions ``pos`` [n, R] are the second leaf (None for all rows).
 
-    ``rows`` is the one leaf; the order (a _HoistRows and the group's
-    ``reverse``) is static.  ``fold`` puts a [B, T, ...] per-token value
-    into the rows' order, ``unfold`` a per-row result [T*B, ...] back into
-    [B, T, ...]: both move one narrow value a row, where reading the rows
-    as [B, T, V] moves all of them."""
+    The order (a _HoistRows and the group's ``reverse``) is static.  ``fold``
+    puts a [B, T, ...] per-token value into the rows' order (gathering the
+    kept rows' values), ``unfold`` a per-row result back into [B, T, ...]
+    (zero where no token was kept; a fill row past a shard's count is
+    dropped, so it reaches no sum): both move one narrow value a row, where
+    reading the rows as [B, T, V] moves all of them."""
 
-    def __init__(self, rows: jnp.ndarray, order: "_HoistRows", reverse: bool):
+    def __init__(self, rows: jnp.ndarray, order: "_HoistRows", reverse: bool,
+                 pos: Optional[jnp.ndarray] = None):
         self.rows, self.order, self.reverse = rows, order, bool(reverse)
+        self.pos = pos
 
     def tree_flatten(self):
-        return (self.rows,), (self.order, self.reverse)
+        return (self.rows, self.pos), (self.order, self.reverse)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(children[0], *aux)
+        return cls(children[0], *aux, children[1])
 
     def fold(self, d: jnp.ndarray) -> jnp.ndarray:
-        return self.order.fold_batch_major(d, self.reverse)
+        r = self.order.fold_batch_major(d, self.reverse)
+        return r if self.pos is None else self.order.take(r, self.pos)
 
     def unfold(self, r: jnp.ndarray) -> jnp.ndarray:
+        if self.pos is not None:
+            r = self.order.put(r, self.pos)
         return self.order.unfold_batch_major(r, self.reverse)
 
 

@@ -15,7 +15,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
-from paddle_tpu.core.batch import SeqTensor, ladder_len
+from paddle_tpu.core.batch import SeqTensor, ladder_len, row_bound
 from paddle_tpu.core.data_types import InputType, SeqLevel, SlotKind
 
 
@@ -62,6 +62,8 @@ class DataFeeder:
         dtype=np.float32,
         feed_dtypes: Optional[Dict[str, Any]] = None,
         ladder: Optional[Sequence[int]] = None,
+        row_bound_slots: Sequence[str] = (),
+        shards: int = 1,
     ):
         """feed_dtypes: per-slot WIRE dtype override for dense slots (e.g.
         {"image": np.uint8}) — the batch crosses host->device at 1/4 the
@@ -74,8 +76,15 @@ class DataFeeder:
         of the bucket-shape contract: padded lengths come from a small
         geometric set, so the jitted step's shape cache stays bounded over
         any length distribution (pair with reader.bucketing batches that
-        fill a token budget per rung)."""
+        fill a token budget per rung).
+
+        row_bound_slots: sequence slots that get a RowBound on their true
+        tokens over ``shards`` blocks of rows (core.batch.row_bound) — the
+        slots a network's hoisted recurrent_group epilogues scan
+        (CompiledNetwork.row_bound_slots), with the data mesh's size."""
         self.data_types = list(data_types)
+        self.row_bound_slots = frozenset(row_bound_slots)
+        self.shards = shards
         self.feed_dtypes = dict(feed_dtypes or {})
         if feeding is None:
             self.index = {name: i for i, (name, _) in enumerate(self.data_types)}
@@ -105,9 +114,12 @@ class DataFeeder:
         out: Dict[str, SeqTensor] = {}
         for name, itype in self.data_types:
             col = [sample[self.index[name]] for sample in batch_data]
-            out[name] = self._convert_slot(
+            t = self._convert_slot(
                 col, itype, self.feed_dtypes.get(name, self.dtype)
             )
+            if name in self.row_bound_slots and itype.seq == SeqLevel.SEQ:
+                t.row_bound = row_bound(t.lengths, t.data.shape[1], self.shards)
+            out[name] = t
         return out
 
     # ------------------------------------------------------------------

@@ -91,10 +91,21 @@ def copy_cache_tags(src, dst):
 def _permute_rows(batch, perm):
     """Apply ONE row permutation to every slot of a batch (data, lengths,
     sub_lengths all gather the same axis-0 order, so a sample's slots stay
-    aligned).  Runs on device — perm is a device array."""
+    aligned).  Runs on device — perm is a device array.  A slot's RowBound
+    over several shards is dropped: rows that change places change shards,
+    and the bound held for each shard's own rows."""
     import jax
 
-    return jax.tree_util.tree_map(lambda x: x[perm], batch)
+    def drop_shard_bound(t):
+        bound = getattr(t, "row_bound", None)
+        if bound is not None and bound.shards > 1:
+            t.row_bound = None
+        return t
+
+    out = jax.tree_util.tree_map(lambda x: x[perm], batch)
+    return jax.tree_util.tree_map(
+        drop_shard_bound, out, is_leaf=lambda t: hasattr(t, "row_bound")
+    )
 
 
 class PassCache:

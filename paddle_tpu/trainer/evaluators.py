@@ -51,7 +51,10 @@ def default_metrics_fn(topology: Topology) -> Optional[Callable]:
                 # take the argmax over the rows and put the one id a row into
                 # [B, T] order, as the cost layer does (layers/cost.py
                 # cross_entropy_apply): no reader of the [B, T, V] view is
-                # left to keep its copy alive
+                # left to keep its copy alive.  Where the feed bounded the
+                # tokens the rows are those that hold one (the unfold puts
+                # 0 elsewhere and drops the fill rows), and the mask below
+                # counts only them
                 hoisted = outs.get(pred_name + "@logits_rows")
                 if hoisted is not None:
                     top = hoisted.unfold(jnp.argmax(hoisted.rows, axis=-1))

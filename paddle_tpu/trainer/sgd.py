@@ -27,7 +27,7 @@ from paddle_tpu.core.compiler import CompiledNetwork
 from paddle_tpu.core.topology import LayerOutput, Topology
 from paddle_tpu.optimizer import Optimizer
 from paddle_tpu.parameters import Parameters, create_from_network
-from paddle_tpu.parallel.mesh import get_default_mesh, shard_batch
+from paddle_tpu.parallel.mesh import DATA_AXIS, get_default_mesh, shard_batch
 from paddle_tpu.reader.feeder import DataFeeder
 from paddle_tpu.trainer.evaluators import default_metrics_fn
 from paddle_tpu.trainer.step import make_eval_step, make_train_step
@@ -358,6 +358,8 @@ class SGD:
             self.topology.data_types(), feeding,
             feed_dtypes=feed_dtypes_of(self.topology),
             ladder=ladder,
+            row_bound_slots=self.network.row_bound_slots(),
+            shards=1 if self.mesh is None else self.mesh.shape.get(DATA_AXIS, 1),
         )
 
     def _run_train_step(self, params, state, opt_state, batch, rng):

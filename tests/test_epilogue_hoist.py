@@ -617,3 +617,299 @@ def test_inference_returns_batch_major_values_only():
         inferer._params, _mesh_batch(), state=inferer._state, train=False
     )
     assert isinstance(net_outs["mg@logits_rows"], rg.HoistedRows)
+
+
+# ---------------------------------------------------------------------------
+# The rows that hold a token (rg._HoistRows.kept / take / put): where the feed
+# bounds a slot's true tokens (core.batch.RowBound), a group that scans that
+# slot's lengths runs its hoisted epilogue on R rows a shard, not T*B/n.  The
+# cost, every gradient and classification_error are those of all the rows.
+# ---------------------------------------------------------------------------
+
+
+def _bounded(batch, slot, n):
+    """The batch with the feeder's bound on `slot` over n shards."""
+    from paddle_tpu.core.batch import row_bound
+
+    t = batch[slot]
+    bound = row_bound(np.asarray(t.lengths), t.data.shape[1], n or 1)
+    assert bound is not None and bound.shards == (n or 1)
+    out = dict(batch)
+    out[slot] = SeqTensor(t.data, t.lengths, row_bound=bound)
+    return out
+
+
+def _short_batch(slots, b=64, t=32, vocab=29):
+    """Lengths mostly at most t/2 (one at t): under half the rows hold a
+    token, so the bound leaves rows out over 1, 2 and 4 shards."""
+    rng = np.random.RandomState(7)
+    lens = jnp.asarray(rng.randint(1, t // 2 + 1, b), jnp.int32).at[0].set(t)
+    out = {}
+    for name in slots:
+        ids = jnp.asarray(rng.randint(2, vocab, (b, t)), jnp.int32)
+        own = lens if name != "src_word" else jnp.asarray(
+            rng.randint(2, t + 1, b), jnp.int32
+        )
+        out[name] = SeqTensor(ids, own)
+    return out
+
+
+def _seq2seq_cost(vocab=29):
+    from paddle_tpu.models.seq2seq import seq2seq_cost
+
+    reset_auto_names()
+    paddle.init(seed=21)
+    return seq2seq_cost(vocab, vocab, word_dim=6, hidden_dim=5)[0]
+
+
+def _hoist_counts():
+    from paddle_tpu.utils.timers import global_stats
+
+    return tuple(
+        global_stats.count(f"hoisted_rows_{path}_layers")
+        for path in ("compacted", "full")
+    )
+
+
+def _step(cost, batch, n):
+    """(cost, gradients, classification_error) of one jitted step on an
+    n-way data mesh (None: no mesh), the batch an argument sharded as the
+    trainer shards it; and the compiled step's text."""
+    from paddle_tpu.parallel.mesh import shard_batch
+    from paddle_tpu.trainer.evaluators import default_metrics_fn
+
+    net = CompiledNetwork(Topology([cost]))
+    params, state = net.init(jax.random.PRNGKey(0))
+    net.mesh = _mesh(n)
+    batch = shard_batch(batch, net.mesh)
+    metrics = default_metrics_fn(net.topology)
+
+    def step(p, fed):
+        def loss(q):
+            return net.cost(q, fed, state=state, rng=None, train=True)
+
+        (v, (outs, _)), g = jax.value_and_grad(loss, has_aux=True)(p)
+        return v, g, metrics(outs)["classification_error"]
+
+    jitted = jax.jit(step)
+    text = jitted.lower(params, batch).compile().as_text()
+    return jitted(params, batch), text
+
+
+CASES = {
+    "attention_gru_fused": (_seq2seq_cost, ("src_word", "trg_word", "trg_next"),
+                            "trg_word", True),
+    "attention_gru_generic": (_seq2seq_cost, ("src_word", "trg_word", "trg_next"),
+                              "trg_word", False),
+    "generic_forward": (lambda: _mesh_group(False, True), ("x", "y"), "x", True),
+    "generic_reverse": (lambda: _mesh_group(True, True), ("x", "y"), "x", True),
+}
+
+
+@pytest.mark.parametrize("n", MESHES)
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kept_rows_match_all_rows(case, n):
+    """Cost, every gradient leaf and classification_error with the epilogue
+    on the kept rows against the same step on all rows (the batch without
+    its bound), float32; under a data mesh the step gathers nothing."""
+    from paddle_tpu.utils.flags import get_flag, set_flag
+
+    build, slots, slot, fused = CASES[case]
+    old = get_flag("fused_attention_gru")
+    set_flag("fused_attention_gru", fused)
+    try:
+        plain = _short_batch(slots)
+        before = _hoist_counts()
+        (got, got_text) = _step(build(), _bounded(plain, slot, n), n)
+        mid = _hoist_counts()
+        (want, _) = _step(build(), plain, n)
+        after = _hoist_counts()
+    finally:
+        set_flag("fused_attention_gru", old)
+    # one group traced on each path (two traces each: lower, then call)
+    assert (mid[0] - before[0], mid[1] - before[1]) == (1, 0)
+    assert (after[0] - mid[0], after[1] - mid[1]) == (0, 1)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    g_got, g_want = (jax.tree_util.tree_leaves(x[1]) for x in (got, want))
+    assert len(g_got) == len(g_want)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(got[2], want[2], rtol=1e-6)
+    assert 0.0 < float(got[2]) <= 1.0
+    if n:
+        assert " all-reduce" in got_text  # a partitioned step
+        assert "all-gather" not in got_text
+
+
+def test_kept_share_is_observed_at_trace():
+    from paddle_tpu.utils.timers import global_stats
+
+    plain = _short_batch(("x", "y"))
+    fed = _bounded(plain, "x", None)
+    cost = _mesh_group()
+    net = CompiledNetwork(Topology([cost]))
+    params, state = net.init(jax.random.PRNGKey(0))
+    before = global_stats.summary().get("hoisted_rows_kept_share", {}).get("count", 0)
+    outs, _ = net.apply(params, fed, state=state, train=True)
+    share = global_stats.summary()["hoisted_rows_kept_share"]
+    assert share["count"] == before + 1
+    bound = fed["x"].row_bound.rows
+    assert outs["mg@logits_rows"].rows.shape == (bound, 29)
+    # the last observation is this trace's: R / (T*B), in percent
+    assert share["max"] >= 100.0 * bound / (32 * 64)
+    t = global_stats.summary()["hoisted_rows_kept_share"]["total"]
+    net.apply(params, fed, state=state, train=True)
+    assert global_stats.summary()["hoisted_rows_kept_share"]["total"] - t == (
+        pytest.approx(100.0 * bound / (32 * 64))
+    )
+
+
+@pytest.mark.parametrize("n", [1, 2, 4])
+@pytest.mark.parametrize("reverse", [False, True], ids=["forward", "reverse"])
+def test_kept_rows_take_and_put(n, reverse):
+    """take gathers exactly the valid rows, shard by shard, in the rows'
+    order; put is its inverse there and zero elsewhere; HoistedRows with
+    positions crosses jit and folds / unfolds through them."""
+    t, b = 5, 8
+    order = rg._HoistRows(t, b, _mesh(n))
+    lens = jnp.asarray([5, 1, 2, 3, 0, 4, 2, 1], jnp.int32)
+    tpos = jnp.arange(t)[:, None]
+    valid = tpos >= (t - lens[None]) if reverse else tpos < lens[None]
+    # one fill row past the fullest shard's tokens
+    bound = int(np.asarray(lens).reshape(n, -1).sum(1).max()) + 1
+    pos = order.kept(valid, bound)
+    assert pos.shape == (n, bound)
+    x = jnp.arange(t * b * 2, dtype=jnp.float32).reshape(t, b, 2) + 1.0
+    rows = order.fold(x)
+    kept = order.take(rows, pos)
+    m = np.asarray(order.fold(valid)).reshape(n, -1)
+    for s in range(n):
+        want = np.asarray(rows).reshape(n, -1, 2)[s][m[s]]
+        got = np.asarray(kept).reshape(n, -1, 2)[s]
+        np.testing.assert_array_equal(got[: len(want)], want)
+    back = order.put(kept, pos)
+    np.testing.assert_array_equal(
+        back, np.where(np.asarray(order.fold(valid))[:, None], rows, 0.0)
+    )
+    hoisted = rg.HoistedRows(kept, order, reverse, pos)
+    assert len(jax.tree_util.tree_leaves(hoisted)) == 2
+    out = jax.jit(lambda h: h)(hoisted)
+    per_token = jnp.arange(1, b * t + 1, dtype=jnp.int32).reshape(b, t)
+    unfolded = np.asarray(out.unfold(out.fold(per_token)))
+    mask_bt = np.asarray(jnp.swapaxes(jnp.flip(valid, 0) if reverse else valid, 0, 1))
+    np.testing.assert_array_equal(unfolded, np.where(mask_bt, per_token, 0))
+
+
+@pytest.mark.parametrize("shards", [1, 2, 4])
+def test_feeder_bound_covers_every_shard(shards):
+    """The bound covers the largest shard's tokens, is a rung (a multiple of
+    ceil(T*B/n/16), rounded up to 128) and leaves rows out; one (T, B)
+    gives at most 16 bounds."""
+    from paddle_tpu.core.batch import row_bound
+
+    rng = np.random.RandomState(shards)
+    t, b = 64, 256
+    block = t * b // shards
+    unit = -(-block // 16)
+    seen = set()
+    for _ in range(200):
+        lens = rng.randint(0, t + 1, b)
+        lens[rng.rand(b) < rng.rand()] = rng.randint(0, t + 1)
+        bound = row_bound(lens, t, shards)
+        counts = np.minimum(lens, t).reshape(shards, -1).sum(1)
+        if bound is None:
+            rung = -(-max(-(-counts.max() // unit), 1) * unit // 128) * 128
+            assert rung >= block
+            continue
+        assert (bound.batch, bound.shards) == (b, shards)
+        assert counts.max() <= bound.rows < block
+        assert bound.rows % 128 == 0
+        assert bound.rows - 128 < -(-counts.max() // unit) * unit or bound.rows == 128
+        seen.add(bound.rows)
+    assert seen and len(seen) <= 16
+
+
+def test_feeder_bound_of_the_nmt_mix():
+    """512 targets of 8-50 tokens spread evenly and padded to 56: 14.8k
+    tokens in 28,672 rows, bound 16,128 (56.25%); four shards of such
+    batches keep the same bound, and a batch size no shard count divides
+    falls back to one shard."""
+    from paddle_tpu.core.batch import row_bound
+
+    lens = 8 + (np.arange(512) * 43) // 512
+    assert row_bound(lens, 56).rows == 16128
+    rng = np.random.RandomState(0)
+    four = np.concatenate([rng.permutation(lens) for _ in range(4)])
+    assert row_bound(four, 56, 4) == (2048, 4, 16128)
+    assert row_bound(lens[:510], 56, 4).shards == 1
+
+
+def test_trainer_feeds_the_bound_only_where_a_group_reads_it():
+    """The trainer's feeder bounds the slot a hoisted epilogue scans; a
+    network without one gets batches with no bound and the same shape key
+    as before."""
+    from paddle_tpu.core.batch import batch_shape_key
+
+    def feeder(cost, feeding):
+        trainer = paddle.trainer.SGD(
+            cost=cost, parameters=paddle.parameters.create(cost, seed=0),
+            update_equation=paddle.optimizer.Momentum(learning_rate=0.1),
+        )
+        return trainer.network.row_bound_slots(), trainer._make_feeder(feeding)
+
+    rng = np.random.RandomState(2)
+    pairs = [
+        (list(rng.randint(2, 29, 9)), list(rng.randint(2, 29, k)),
+         list(rng.randint(2, 29, k)))
+        for k in [40] + [3] * 63
+    ]
+    slots, feed = feeder(_seq2seq_cost(), {"src_word": 0, "trg_word": 1, "trg_next": 2})
+    assert slots == ("trg_word",)
+    fed = feed(pairs)
+    assert fed["trg_word"].row_bound == (64, 1, 384)
+    assert fed["src_word"].row_bound is None and fed["trg_next"].row_bound is None
+    assert dict((k[0], k) for k in batch_shape_key(fed))["trg_word"][3] == (64, 1, 384)
+
+    reset_auto_names()
+    paddle.init(seed=3)
+    x = L.data("x", paddle.data_type.integer_value_sequence(29))
+    g = paddle.networks.gru_group(L.embedding(x, size=12), size=4, name="cls_gru")
+    out = L.fc(L.last_seq(input=g), size=3, act=A.Softmax())
+    lab = L.data("y", paddle.data_type.integer_value(3))
+    slots, feed = feeder(L.classification_cost(input=out, label=lab), {"x": 0, "y": 1})
+    assert slots == ()
+    fed = feed([(p[1], 1) for p in pairs])
+    assert all(v.row_bound is None for v in fed.values())
+    assert all(len(entry) == 3 for entry in batch_shape_key(fed))
+
+
+def test_inference_takes_all_rows():
+    """Inference feeds no bound: the group runs its epilogue on every row."""
+    from paddle_tpu.inference import Inference
+
+    cost = _mesh_group()
+    group = next(lo for lo in cost.parents if lo.conf.type == "recurrent_group")
+    inferer = Inference(output_layer=group, parameters=paddle.parameters.create(cost))
+    rng = np.random.RandomState(1)
+    before = _hoist_counts()
+    (outs,) = list(inferer.iter_infer(input=[(list(rng.randint(0, 29, k)),) for k in (5, 2)]))
+    after = _hoist_counts()
+    assert after[0] == before[0] and after[1] > before[1]
+    assert outs["mg"].data.shape[0] == 2
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_pass_cache_row_shuffle_keeps_only_a_one_shard_bound(shards):
+    """Rows that change places change shards: a replayed, row-shuffled batch
+    keeps a bound over one shard (the total is the same) and drops one over
+    several."""
+    from paddle_tpu.core.batch import row_bound
+    from paddle_tpu.reader.pass_cache import _permute_rows
+
+    lens = np.asarray([3, 1, 2, 4, 1, 1, 2, 1] * 8, np.int32)
+    bound = row_bound(lens, 64, shards)
+    assert bound is not None
+    batch = {"x": SeqTensor(jnp.zeros((64, 64), jnp.int32), jnp.asarray(lens), row_bound=bound)}
+    out = _permute_rows(batch, jnp.arange(64)[::-1])
+    assert out["x"].row_bound == (bound if shards == 1 else None)
+    np.testing.assert_array_equal(out["x"].lengths, lens[::-1])

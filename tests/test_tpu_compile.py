@@ -279,3 +279,58 @@ def test_the_hybrid_step_compiled_for_a_v5e_takes_the_grouped_kernels_and_lowers
     assert 6 <= kernels <= 12 and two.as_text().count("tpu_custom_call") == kernels
     text = two.compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2 * 2 * 8 and "ragged-dot" not in text
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_the_seq2seq_step_compiled_for_a_v5e_runs_its_output_layer_on_the_bounded_rows(v5e, chips):
+    """With the feed's bound on the scanned slot's tokens (core.batch.RowBound:
+    R rows a shard), the compiled training step holds the output layer's
+    [rows, V] arrays at R rows a chip and none at the T*B/n rows it had, and
+    over a data mesh of four it gathers nothing: each chip keeps its own
+    rows."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+    import paddle_tpu as paddle
+    from paddle_tpu.core.batch import RowBound, SeqTensor
+    from paddle_tpu.core.compiler import CompiledNetwork
+    from paddle_tpu.core.topology import Topology, reset_auto_names
+    from paddle_tpu.models.seq2seq import seq2seq_cost
+    from paddle_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+    from paddle_tpu.trainer.evaluators import default_metrics_fn
+    from paddle_tpu.trainer.step import make_train_step
+    from paddle_tpu.utils.timers import global_stats
+
+    vocab, b, t = 1000, 128, 56
+    block = t * b // chips
+    bound = block // 2  # a multiple of 128 and of ceil(block / 16)
+    reset_auto_names()
+    cost, _ = seq2seq_cost(vocab, vocab, word_dim=64, hidden_dim=64)
+    net = CompiledNetwork(Topology([cost]), compute_dtype=jnp.bfloat16)
+    mesh = None
+    whole = rows = SingleDeviceSharding(v5e.devices[0])
+    if chips > 1:
+        mesh = Mesh(np.array(v5e.devices).reshape(4, 1), (DATA_AXIS, MODEL_AXIS))
+        net.mesh = mesh  # as trainer.SGD sets it
+        whole, rows = NamedSharding(mesh, P()), NamedSharding(mesh, P(DATA_AXIS))
+    opt = paddle.optimizer.Adam(learning_rate=1e-3)
+    params, state = jax.eval_shape(net.init, jax.random.PRNGKey(0))
+
+    def seq(row_bound=None):
+        return SeqTensor(jax.ShapeDtypeStruct((b, t), jnp.int32), jax.ShapeDtypeStruct((b,), jnp.int32),
+                         row_bound=row_bound)
+
+    batch = {"src_word": seq(), "trg_word": seq(RowBound(b, chips, bound)), "trg_next": seq()}
+    args = (params, state, jax.eval_shape(opt.init, params), batch, jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    placed = [jax.tree_util.tree_map(
+        lambda x, sh=(rows if arg is batch else whole): jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh), arg)
+        for arg in args]
+
+    count = lambda: [global_stats.count(f"hoisted_rows_{path}_layers") for path in ("compacted", "full")]
+    before = count()
+    step = make_train_step(net, opt, mesh=mesh, extra_metrics=default_metrics_fn(net.topology))
+    text = step.trace(*placed).lower(lowering_platforms=("tpu",)).compile().as_text()
+    assert [a - b_ for a, b_ in zip(count(), before)] == [1, 0]
+    wide = {tuple(int(d) for d in dims.split(",")) for dims in re.findall(rf"\[([0-9,]+,{vocab})\]", text)}
+    assert (bound, vocab) in wide and (block, vocab) not in wide, sorted(wide)
+    if chips > 1:
+        assert " all-reduce" in text and "all-gather" not in text
