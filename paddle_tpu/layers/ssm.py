@@ -9,10 +9,11 @@ as the `nemotron_h` family configures it.
     y = group_rms_norm(y * silu(z)) * gain      groups of inner / G channels
     out = y W_out
 
-The recurrence is `ops/ssd.ssd_scan`, chunked, under the scope `ssd_scan`;
-everything around it is projections and elementwise work under the layer's
-own scope.  `inner` = H x P is an attribute of its own, not a multiple of the
-input width.  No clamp on dt.  Decay sums and the state are float32; the
+The recurrence is `ops/ssd.ssd_scan`, chunked, under the scope `ssd_scan`:
+the Pallas kernels of `ops/ssd_pallas.py` where `_scan_kernels` finds them
+usable, else XLA's operations; everything around it is projections and
+elementwise work under the layer's own scope.  `inner` = H x P is an
+attribute of its own, not a multiple of the input width.  No clamp on dt.  Decay sums and the state are float32; the
 products run in the compute type.  Causal: a padded tail never reaches a
 true position, so sequence lengths pass through untouched.
 """
@@ -56,6 +57,26 @@ def mamba2_init(conf, in_confs, rng):
     }
 
 
+def _scan_kernels(chunk, h, p, g, n, dtype, mesh):
+    """-> (kernels, why): whether the layer's scan takes the Pallas kernels,
+    and where not, why.  They run where the backend is the TPU, their tiles
+    take the shapes and the program is one device's: XLA partitions no
+    Mosaic kernel.  Counted: `ssd_kernel_layers` / `ssd_xla_layers`, one a
+    layer traced."""
+    from paddle_tpu.utils.timers import global_stats
+
+    if jax.default_backend() != "tpu":
+        why = f"the backend is {jax.default_backend()!r}, not 'tpu'"
+    elif mesh is not None and mesh.size > 1:
+        why = f"the mesh {dict(mesh.shape)} holds {mesh.size} devices"
+    else:
+        from paddle_tpu.ops import ssd_pallas  # Pallas is imported where it is used
+
+        why = ssd_pallas.supported(chunk, h, p, g, n, dtype)
+    global_stats.incr("ssd_xla_layers" if why else "ssd_kernel_layers")
+    return why is None, why
+
+
 def _causal_conv(x, w, bias):
     """Depthwise over time: y_t = sum_k w[k] x_{t-(K-1)+k} + bias, zeros
     before the row's start.  x [B, T, C], w [K, C]."""
@@ -77,18 +98,23 @@ def mamba2_apply(conf, params, inputs, ctx):
     zxbcdt = acc_matmul(u.data, params["w_in"])
     z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * g * n], axis=-1)
     xbc = jax.nn.silu(_causal_conv(xbc, params["conv_w"], params["conv_b"]))
-    x, b, c = jnp.split(xbc, [inner, inner + g * n], axis=-1)
-    x = x.reshape(bsz, t, g, h // g, p)
+    rows, b, c = jnp.split(xbc, [inner, inner + g * n], axis=-1)
+    x = rows.reshape(bsz, t, g, h // g, p)
     b = b.reshape(bsz, t, g, n)
     c = c.reshape(bsz, t, g, n)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"].astype(jnp.float32))
     a = -jnp.exp(params["a_log"].astype(jnp.float32))
+    chunk = conf.attr("chunk_size", 128)
+    kernels, _ = _scan_kernels(chunk, h, p, g, n, x.dtype, ctx.mesh)
     y = ssd_scan(x, dt.reshape(bsz, t, g, h // g), a.reshape(g, h // g), b, c,
-                 chunk=conf.attr("chunk_size", 128))
-    y = y + x * params["d"].reshape(g, h // g, 1).astype(x.dtype)
+                 chunk=chunk, kernels=kernels)
+    # the skip term on the [B, T, H x P] rows: on [B, T, G, R, P] arrays, P
+    # = 64 minor, XLA:TPU lays x and y out with T minor and copies each into
+    # and out of that layout around the scan's kernels
+    y = y.reshape(bsz, t, inner) + rows * jnp.repeat(params["d"], p).astype(x.dtype)
 
     # gate, then RMS norm over each group's channels, float32 statistics
-    y = (y.reshape(bsz, t, inner) * jax.nn.silu(z)).astype(jnp.float32)
+    y = (y * jax.nn.silu(z)).astype(jnp.float32)
     yg = rms_normalize(y.reshape(bsz, t, g, inner // g), eps)
     y = yg.reshape(bsz, t, inner) * params["norm"].astype(jnp.float32)
     out = acc_matmul(y.astype(u.data.dtype), params["w_out"])

@@ -14,10 +14,27 @@ Heads come in groups that share b and c (`x` [B, T, G, R, P]: G groups of R
 heads).  Decay sums and the state are float32; the products run in x's dtype
 with float32 accumulation.
 
-The backward pass is hand-structured (custom VJP): it keeps the inputs and the
+Two paths compute it, each behind a custom VJP that keeps the inputs and the
 states entering each chunk ([B, T/chunk, G, R, P, N] float32), not every step's
-state (P x N float32 a head and token otherwise), recomputes a chunk's
-quadratic form, and carries the state's cotangent back over the chunks.
+state (P x N float32 a head and token otherwise):
+
+  * the Pallas kernels of `ops/ssd_pallas.py` (`kernels=True`), one forward
+    and one backward: a chunk's decay matrix, its quadratic form and the
+    carried state (and on the way back the state's cotangent) stay in VMEM,
+    and only x, b, c, dt, y, their gradients and the kept states cross HBM.
+    `layers/ssm.py` takes them on the TPU backend where
+    `ops/ssd_pallas.supported` says the shapes tile;
+  * XLA's operations (this file): every chunk's decay [Q, Q] is a float32
+    array in HBM, multiplied into the scores and read again for the product
+    with x dt, the states carried by a `scan` over the chunks; the backward
+    recomputes a chunk's quadratic form through `jax.vjp` of the same
+    function and carries the state's cotangent back by a second `scan`.  It
+    stays as the path of the CPU and of a program over several devices,
+    since XLA partitions no Mosaic kernel, and of shapes the kernels do not
+    tile (a chunk no multiple of 128 lanes, heads that do not tile them).
+
+Both are under the scope `ssd_scan`, kernels, decay sums and the cotangent's
+sums over a chunk included: `ssd_scan_roofline` divides by that time.
 """
 
 from __future__ import annotations
@@ -150,8 +167,15 @@ def _ssd_core_bwd(chunk, res, dy):
 _ssd_core.defvjp(_ssd_core_fwd, _ssd_core_bwd)
 
 
-def ssd_scan(x, dt, a, b, c, chunk=128):
+def ssd_scan(x, dt, a, b, c, chunk=128, kernels=False, interpret=False):
     """x [B, T, G, R, P] (G groups of R heads of width P); dt [B, T, G, R]
     float32, the step sizes (>= 0); a [G, R] float32, the decay rates (< 0);
-    b, c [B, T, G, N].  -> y [B, T, G, R, P] in x's dtype."""
-    return _ssd_core(int(chunk), x, dt.astype(jnp.float32), a.astype(jnp.float32), b, c)
+    b, c [B, T, G, N].  -> y [B, T, G, R, P] in x's dtype.  `kernels` takes
+    the Pallas kernels (`ops/ssd_pallas.py`; where `supported` says so, or
+    under `interpret`), else XLA's operations."""
+    dt, a = dt.astype(jnp.float32), a.astype(jnp.float32)
+    if kernels:
+        from paddle_tpu.ops.ssd_pallas import ssd_kernels  # Pallas is imported where it is used
+
+        return ssd_kernels(int(chunk), bool(interpret), x, dt, a, b, c)
+    return _ssd_core(int(chunk), x, dt, a, b, c)

@@ -46,42 +46,141 @@ def ssd_scan_reference(x, dt, a, b, c):
     return jnp.moveaxis(y, 0, 1)
 
 
-@pytest.mark.parametrize("t,chunk,g,r", [(16, 4, 2, 3), (13, 4, 2, 3), (3, 8, 2, 3), (64, 16, 2, 3),
-                                         (37, 16, 2, 3), (512, 256, 1, 64), (300, 256, 1, 64)])
-def test_chunked_scan_matches_a_scan_over_tokens(t, chunk, g, r):
-    """Forward and every gradient; lengths that are and are not multiples of
-    the chunk, and one shorter than a chunk; granite-4.0-h-micro's shapes:
-    one group of B and C for all 64 heads, chunks of 256."""
+_SCAN_SHAPES = [(16, 4, 2, 3), (13, 4, 2, 3), (3, 8, 2, 3), (64, 16, 2, 3),
+                (37, 16, 2, 3), (512, 256, 1, 64), (300, 256, 1, 64)]
+
+
+def _scan_operands(t, g, r, p=4, n=5, dtype=jnp.float32):
     k = jax.random.split(jax.random.PRNGKey(t), 6)
-    b, p, n = 2, 4, 5
-    x = jax.random.normal(k[0], (b, t, g, r, p))
+    b = 2
+    x = jax.random.normal(k[0], (b, t, g, r, p)).astype(dtype)
     dt = jax.nn.softplus(jax.random.normal(k[1], (b, t, g, r)) - 1.0)
     a = -jnp.exp(0.3 * jax.random.normal(k[2], (g, r)))
-    bb = jax.random.normal(k[3], (b, t, g, n))
-    cc = jax.random.normal(k[4], (b, t, g, n))
+    bb = jax.random.normal(k[3], (b, t, g, n)).astype(dtype)
+    cc = jax.random.normal(k[4], (b, t, g, n)).astype(dtype)
     w = jax.random.normal(k[5], (b, t, g, r, p))
+    return (x, dt, a, bb, cc), w
 
-    def both(fn):
-        return jax.value_and_grad(lambda *args: jnp.sum(fn(*args) * w), argnums=(0, 1, 2, 3, 4))(
-            x, dt, a, bb, cc)
 
-    y, grads = both(lambda *args: ssd_scan(*args, chunk=chunk))
-    y_ref, grads_ref = both(ssd_scan_reference)
+def _value_and_grads(fn, args, w):
+    return jax.value_and_grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w), argnums=(0, 1, 2, 3, 4))(*args)
+
+
+def _check_scan_against_reference(t, chunk, g, r, p=4, n=5, **path):
+    args, w = _scan_operands(t, g, r, p, n)
+    y, grads = _value_and_grads(lambda *a: ssd_scan(*a, chunk=chunk, **path), args, w)
+    y_ref, grads_ref = _value_and_grads(ssd_scan_reference, args, w)
     np.testing.assert_allclose(y, y_ref, rtol=1e-5)
     for got, want in zip(grads, grads_ref):
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5 * float(jnp.max(jnp.abs(want))))
 
 
+@pytest.mark.parametrize("t,chunk,g,r", _SCAN_SHAPES)
+def test_chunked_scan_matches_a_scan_over_tokens(t, chunk, g, r):
+    """Forward and every gradient; lengths that are and are not multiples of
+    the chunk, and one shorter than a chunk; granite-4.0-h-micro's shapes:
+    one group of B and C for all 64 heads, chunks of 256."""
+    _check_scan_against_reference(t, chunk, g, r)
+
+
+@pytest.mark.parametrize("t,chunk,g,r,p,n", [s + (4, 5) for s in _SCAN_SHAPES] + [
+    (64, 16, 8, 2, 4, 5),  # nemotron-like: 8 groups of B and C, several heads each
+    (40, 8, 2, 4, 64, 8),  # heads of 64: two heads a lane tile of 128
+])
+def test_chunked_scan_kernels_match_a_scan_over_tokens(t, chunk, g, r, p, n):
+    """The Pallas kernels (`ops/ssd_pallas.py`) under the interpreter: the
+    forward kernel and both kernels' gradients (x, dt, a, b, c) against the
+    scan over tokens at the shapes above, a nemotron-like case of 8 groups,
+    and heads of 64 that share a lane tile."""
+    _check_scan_against_reference(t, chunk, g, r, p, n, kernels=True, interpret=True)
+
+
+def test_chunked_scan_kernels_agree_with_xla_in_bfloat16():
+    """bfloat16 x, b, c as the hybrid cells run them, heads of 64: the two
+    paths round in different places (the kernels keep C B^T in float32), so
+    they agree to bfloat16's precision, not to the bit."""
+    args, w = _scan_operands(40, 2, 4, 64, 16, dtype=jnp.bfloat16)
+    xla = _value_and_grads(lambda *a: ssd_scan(*a, chunk=8), args, w)[1]
+    kernels = _value_and_grads(lambda *a: ssd_scan(*a, chunk=8, kernels=True, interpret=True), args, w)[1]
+    for got, want in zip(kernels, xla):
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        assert got.dtype == want.dtype and np.max(np.abs(got - want)) < 0.03 * np.max(np.abs(want))
+
+
 def test_chunked_scan_keeps_the_states_between_chunks_not_every_steps():
     """What the backward pass keeps: the inputs and one [P, N] state a head
     and CHUNK, not one a token."""
+    _check_the_states_kept()
+
+
+def test_chunked_scan_kernels_keep_the_states_between_chunks_not_every_steps():
+    """The kernels' custom VJP keeps what the XLA path keeps: the inputs and
+    the states entering each chunk, written by the forward kernel."""
+    _check_the_states_kept(kernels=True, interpret=True)
+
+
+def _check_the_states_kept(**path):
     b, t, g, r, p, n, chunk = 1, 32, 1, 2, 4, 8, 8
     args = (jnp.ones((b, t, g, r, p)), jnp.ones((b, t, g, r)), -jnp.ones((g, r)),
             jnp.ones((b, t, g, n)), jnp.ones((b, t, g, n)))
-    _, res = jax.vjp(lambda *a: ssd_scan(*a, chunk=chunk), *args)
+    _, res = jax.vjp(lambda *a: ssd_scan(*a, chunk=chunk, **path), *args)
     sizes = sorted(x.size for x in jax.tree_util.tree_leaves(res))
     assert sizes[-1] == b * (t // chunk) * g * r * p * n  # the states entering each chunk
     assert sum(sizes) < b * t * g * r * p * n  # less than one state a token
+
+
+@pytest.mark.parametrize("chunk,h,p,g,n,dtype,why", [
+    (128, 64, 64, 8, 128, jnp.bfloat16, None),  # nemotron-train-2k
+    (256, 64, 64, 1, 128, jnp.bfloat16, None),  # granite-micro-train-2k
+    (128, 16, 128, 2, 128, jnp.float32, None),
+    (64, 64, 64, 8, 128, jnp.bfloat16, "lanes"),
+    (128, 64, 48, 8, 128, jnp.bfloat16, "width 48"),
+    (128, 64, 64, 8, 64, jnp.bfloat16, "state of 64"),
+    (128, 64, 64, 8, 128, jnp.float16, "float16"),
+    (2048, 64, 64, 1, 128, jnp.bfloat16, "VMEM"),
+])
+def test_the_scan_kernels_say_which_shapes_they_take(chunk, h, p, g, n, dtype, why):
+    from paddle_tpu.ops import ssd_pallas
+
+    got = ssd_pallas.supported(chunk, h, p, g, n, dtype)
+    assert got is None if why is None else why in got
+
+
+@pytest.mark.parametrize("backend,mesh,chunk,kernels", [
+    ("cpu", 1, 128, False), ("tpu", 1, 128, True), ("tpu", 2, 128, False), ("tpu", 1, 64, False),
+])
+def test_the_mamba_layer_takes_the_scan_kernels_where_they_run(monkeypatch, backend, mesh, chunk, kernels):
+    """The path by backend, mesh and shapes, chosen at trace time and counted
+    (`ssd_kernel_layers` / `ssd_xla_layers`, one a layer)."""
+    from paddle_tpu.layers.ssm import _scan_kernels
+    from paddle_tpu.utils.timers import global_stats
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    devices = jax.sharding.Mesh(np.array(jax.devices()[:mesh]), ("data",))
+    count = lambda: [global_stats.count(f"ssd_{path}_layers") for path in ("kernel", "xla")]
+    before = count()
+    took, why = _scan_kernels(chunk, 64, 64, 8, 128, jnp.bfloat16, devices)
+    assert took == kernels and (why is None) == kernels
+    assert [a - b for a, b in zip(count(), before)] == ([1, 0] if kernels else [0, 1])
+
+
+def test_the_hybrid_decoders_mamba_layers_count_as_xla_on_the_cpu():
+    """On the CPU every Mamba-2 layer keeps XLA's scan: 0 / n."""
+    from paddle_tpu.models.hybrid_lm import hybrid_lm_cost
+    from paddle_tpu.utils.timers import global_stats
+
+    cost, _ = hybrid_lm_cost(
+        "MEM*E", 64, 32, mamba_heads=2, mamba_head_dim=8, mamba_groups=1, state_size=8, chunk_size=4,
+        attn_heads=2, attn_kv_heads=1, attn_head_dim=8, num_experts=4, experts_per_token=2,
+        expert_hidden=16, shared_hidden=16, experts_held=(0, 2))
+    net = CompiledNetwork(Topology([cost]))
+    params, state = net.init(jax.random.PRNGKey(0))
+    ids = SeqTensor(jnp.ones((2, 16), jnp.int32), jnp.full((2,), 16, jnp.int32))
+    count = lambda: [global_stats.count(f"ssd_{path}_layers") for path in ("kernel", "xla")]
+    before = count()
+    jax.eval_shape(lambda p: net.apply(p, {"word": ids, "next_word": ids}, state=state, train=True)[0][cost.name].data,
+                   params)
+    assert [a - b for a, b in zip(count(), before)] == [0, 2]
 
 
 # -- experts chosen top-k over a held range -----------------------------------

@@ -334,3 +334,57 @@ def test_the_seq2seq_step_compiled_for_a_v5e_runs_its_output_layer_on_the_bounde
     assert (bound, vocab) in wide and (block, vocab) not in wide, sorted(wide)
     if chips > 1:
         assert " all-reduce" in text and "all-gather" not in text
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"], ids=["kernels", "xla-control"])
+def test_the_hybrid_step_compiled_for_a_v5e_takes_the_scan_kernels_in_every_mamba_layer(v5e, monkeypatch, backend):
+    """Mamba-2 layers at the cells' head widths (heads of 64 in 2 groups,
+    state 128, chunks of 128, 2 rows of 512 tokens) in a training step
+    compiled for one described v5e: every layer takes the chunked scan's
+    two Pallas kernels (`ssd_kernel_layers` n / `ssd_xla_layers` 0), which
+    are jitted functions, so the lowered module holds the same two whatever
+    the number of layers, and the compiled step holds two call sites a layer
+    (forward, backward) and no [.., Q, Q] float32 array outside them.  The
+    control takes the XLA path in the same step (the backend's name says
+    'cpu') and holds such arrays: what the first case checks can fail."""
+    from jax.sharding import SingleDeviceSharding
+
+    import paddle_tpu as paddle
+    from paddle_tpu.core.batch import SeqTensor
+    from paddle_tpu.core.compiler import CompiledNetwork
+    from paddle_tpu.core.topology import Topology, reset_auto_names
+    from paddle_tpu.models.hybrid_lm import hybrid_lm_cost
+    from paddle_tpu.trainer.step import make_train_step
+    from paddle_tpu.utils.timers import global_stats
+
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    count = lambda: [global_stats.count(f"ssd_{path}_layers") for path in ("kernel", "xla")]
+
+    def lowered(pattern):
+        reset_auto_names()
+        cost, _ = hybrid_lm_cost(
+            pattern, 256, 256, mamba_heads=16, mamba_head_dim=64, mamba_groups=2, state_size=128,
+            chunk_size=128, attn_heads=2, attn_kv_heads=1, attn_head_dim=64)
+        net = CompiledNetwork(Topology([cost]), compute_dtype=jnp.bfloat16)
+        opt = paddle.optimizer.Adam(learning_rate=1e-4)
+        params, state = jax.eval_shape(net.init, jax.random.PRNGKey(0))
+        ids = SeqTensor(jax.ShapeDtypeStruct((2, 512), jnp.int32), jax.ShapeDtypeStruct((2,), jnp.int32))
+        args = (params, state, jax.eval_shape(opt.init, params), {"word": ids, "next_word": ids},
+                jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+        placed = jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), args)
+        before = count()
+        low = make_train_step(net, opt).trace(*placed).lower(lowering_platforms=("tpu",))
+        assert [a - b for a, b in zip(count(), before)] == (
+            [len(pattern), 0] if backend == "tpu" else [0, len(pattern)])
+        return low
+
+    two = lowered("MM")
+    text = two.compile().as_text()
+    quadratic = re.findall(r"f32\[(?:\d+,)*128,128\]", text)
+    if backend == "cpu":
+        assert quadratic and "tpu_custom_call" not in text
+        return
+    kernels = lowered("M").as_text().count("tpu_custom_call")
+    assert kernels == 2 and two.as_text().count("tpu_custom_call") == kernels
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * 2 and not quadratic, quadratic[:4]
